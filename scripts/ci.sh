@@ -260,7 +260,7 @@ fi
 
 if [ "${CI_FULLSUITE:-0}" = "1" ]; then
     echo "== full-suite single-process gate (segfault pin, nightly) =="
-    # VERDICT Weak #3 regression pin: the WHOLE suite (slow soaks
+    # review weakness #3 regression pin: the WHOLE suite (slow soaks
     # included) in ONE pytest process, green and segfault-free. Minutes
     # long — nightly only (CI_FULLSUITE=1).
     JAX_PLATFORMS=cpu python scripts/fullsuite_gate.py

@@ -1,6 +1,6 @@
 """Full-suite single-process gate — the executable-accumulation pin.
 
-VERDICT Weak #3: before PR 6, running the WHOLE test suite (slow soaks
+review weakness #3: before PR 6, running the WHOLE test suite (slow soaks
 included) in one process accumulated compiled executables until the
 process SEGFAULTed. PR 6's parameter-lifted program cache flattened the
 exec cache; this gate REGRESSION-PINS that fix by running every test in

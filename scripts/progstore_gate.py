@@ -182,7 +182,7 @@ def main() -> int:
     # persistent cache (a cache-loaded executable does not survive
     # serialize→deserialize, so nothing would land in the store)
     base["YDB_TPU_COMPILE_AHEAD"] = "0"
-    for k in ("YDB_TPU_JIT_CACHE", "YDB_TPU_PROGSTATS",
+    for k in ("JAX_COMPILATION_CACHE_DIR", "YDB_TPU_PROGSTATS",
               "YDB_TPU_SHAPE_BUCKETS", "YDB_TPU_PROGSTORE_DEVICE"):
         base.pop(k, None)
     me = os.path.abspath(__file__)

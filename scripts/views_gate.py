@@ -242,7 +242,7 @@ def main() -> int:
     base["VIEWS_GATE_DATA"] = data_dir
     # deterministic compile accounting, same levers as progstore_gate
     base["YDB_TPU_COMPILE_AHEAD"] = "0"
-    for k in ("YDB_TPU_JIT_CACHE", "YDB_TPU_PROGSTATS",
+    for k in ("JAX_COMPILATION_CACHE_DIR", "YDB_TPU_PROGSTATS",
               "YDB_TPU_SHAPE_BUCKETS", "YDB_TPU_PROGSTORE_DEVICE",
               "YDB_TPU_VIEW_FOLD_BATCH", "YDB_TPU_VIEW_MAX_GROUPS"):
         base.pop(k, None)

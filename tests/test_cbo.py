@@ -1,6 +1,6 @@
 """Cost-based join ordering v1: statistics + selectivity estimates.
 
-VERDICT r3 item 5: join order was a PK-edge spanning tree ranked by RAW
+round-3 review item 5: join order was a PK-edge spanning tree ranked by RAW
 table size. Now `query/stats.py` estimates post-predicate cardinality
 (NDV from dictionaries/spans, range selectivity from portion min/max) and
 the planner ranks fact choice and build attachment by it — EXPLAIN shows

@@ -1,6 +1,6 @@
 """Two-PROCESS cluster: shard router over worker engines via gRPC.
 
-VERDICT r3 item 4 ("a second process"): worker engine processes each own
+round-3 review item 4 ("a second process"): worker engine processes each own
 a shard of `lineitem` (other tables replicated for co-located joins).
 Every SELECT here runs on the DQ path — the router lowers it to a
 `dq.StageGraph` (`ydb_tpu/dq/lower.py`) and `DqTaskRunner` executes one
@@ -73,7 +73,7 @@ def test_join_agg_across_processes(cluster):
 
 
 def test_shuffle_join_sharded_x_sharded(cluster):
-    """VERDICT r4 #3 Done criterion: a 2-process join of two sharded
+    """round-4 review #3 Done criterion: a 2-process join of two sharded
     tables where NEITHER worker holds the other's shard — rows meet
     through the exchange channels, oracle-checked."""
     import pandas as pd

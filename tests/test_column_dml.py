@@ -1,6 +1,6 @@
 """Transactional column-table DML via MVCC delete marks.
 
-VERDICT r3 item 9: column UPDATE/DELETE used to rewrite portions —
+round-3 review item 9: column UPDATE/DELETE used to rewrite portions —
 non-transactional, destroying time travel. Now deletes are versioned
 row-index marks on immutable portions (`storage/portion.py` DeleteMark,
 the per-row delete-version stance of the reference's ColumnShard MVCC):

@@ -1,6 +1,6 @@
 """Concurrent query execution: lock-free readers over MVCC snapshots.
 
-VERDICT r3 item 2: the r3 engine held ONE lock around every statement
+round-3 review item 2: the r3 engine held ONE lock around every statement
 from every front. Now SELECTs run concurrently (the session-actor model —
 `kqp_session_actor.cpp:128` runs thousands of sessions; here a thread per
 session), writers serialize on the engine write lock, and memory

@@ -2,6 +2,7 @@
 
 import numpy as np
 import pandas as pd
+import pytest
 
 from ydb_tpu.core import dtypes as dt
 from ydb_tpu.core.block import HostBlock
@@ -168,3 +169,136 @@ def test_expand_join_null_probe_keys(rng):
     left = to_host(mj.probe_expand(to_device(fact), table, "fk", "left"))
     df = left.to_pandas().sort_values(["qty", "price"]).reset_index(drop=True)
     assert len(df) == 5  # rows 0,2,3 null-extended + two matches for row 1
+
+
+# -- sort_total: the radix lowering equals the one wide lax.sort -----------
+
+
+_F64_EDGES = np.array([
+    1e39, -1e39, 1e100, 3e200, -2e60, 1e300, -1e300, 1e-40, -1e-40,
+    1e-300, np.finfo(np.float64).max, -np.finfo(np.float64).max,
+    np.finfo(np.float64).tiny, -np.finfo(np.float64).tiny,
+    np.nextafter(np.finfo(np.float64).max, 0), np.nextafter(1e39, 0)])
+
+
+def _sort_total_cases():
+    rng = np.random.default_rng(7)
+    n = 1 << 14
+    f64 = rng.normal(size=n) * 10.0 ** rng.integers(-30, 30, size=n)
+    f64[:50], f64[50:80], f64[80:100] = np.nan, 0.0, -0.0
+    f64[100:110], f64[110:120] = np.inf, -np.inf
+    f64[200:1200] = np.repeat(rng.normal(size=10), 100)      # long ties
+    ulp = 1.0 + rng.integers(0, 4, size=n) * 2.0 ** -52     # last-bit keys
+    # every binade of the double range (random bit patterns), the edges
+    # of the float32 range and of the double range, and last-bit
+    # neighbours far below float32's smallest number
+    wide = rng.integers(0, 2 ** 64, size=n, dtype=np.uint64).view(np.float64)
+    wide = wide.copy()
+    wide[:len(_F64_EDGES)] = _F64_EDGES
+    wide[100:1100] = 1e-30 * (1 + rng.integers(0, 4, 1000) * 2.0 ** -52)
+    wide[1100:2100] = 3e200 * (1 + rng.integers(0, 4, 1000) * 2.0 ** -52)
+    wide[2100:2200] = np.repeat(_F64_EDGES[:10], 10)
+    # denormals: XLA reads them as zero in `=` and `<`, so they tie with
+    # zero here (the comparison flushes them the same way)
+    den = f64.copy()
+    den[:3000] = rng.choice([0.0, -0.0, 5e-324, -5e-324, 1e-310, -1e-310,
+                             2e-308], size=3000)
+    i64 = rng.integers(-2 ** 62, 2 ** 62, size=n)
+    i64[:100] = rng.integers(-3, 3, size=100)
+    i64[100], i64[101] = np.iinfo(np.int64).max, np.iinfo(np.int64).min
+    u64 = rng.integers(0, 2 ** 63, size=n).astype(np.uint64) * 2 + 1
+    i32 = rng.integers(-5, 5, size=n).astype(np.int32)
+    f32 = rng.normal(size=n).astype(np.float32)
+    f32[:10], f32[10:20] = np.nan, -0.0
+    u32 = rng.integers(0, 2 ** 32, size=n).astype(np.uint32)
+    return {"f64": [f64], "f64_ulp": [ulp], "f64_desc": [-f64],
+            "f64_wide": [wide], "f64_wide_desc": [-wide],
+            "f64_denormal": [den], "f64_small_n": [wide[:300]],
+            "i64": [i64], "i64_desc": [~i64], "u64": [u64], "f32": [f32],
+            "u32": [u32], "i32": [i32], "mixed": [i32, f64, i64],
+            "mixed_wide": [i32, wide, i64], "mixed32": [i32, i32 % 2, f32, u32],
+            "mixed_small_n": [i32[:100], wide[:100], i64[:100]]}
+
+
+def _flush_denormals(k):
+    if k.dtype != np.float64:
+        return k
+    return np.where(np.abs(k) < np.finfo(np.float64).tiny, 0.0, k)
+
+
+@pytest.mark.parametrize("jit", [False, True], ids=["eager", "jit"])
+@pytest.mark.parametrize("case", list(_sort_total_cases()))
+def test_sort_total_radix_equals_wide_sort(case, jit):
+    """`sort_total` sorts word by word (what the TPU compiler can build
+    in seconds); the permutation and the sorted keys must be exactly
+    those of one stable `lax.sort` over every key — NaNs last and equal,
+    -0 == +0, 64-bit extremes, last-bit doubles, and the whole double
+    range: beyond float32's exponent range on both sides, ±DBL_MAX, the
+    smallest normal. Denormal doubles tie with zero."""
+    import jax
+    import jax.numpy as jnp
+
+    from ydb_tpu.ops import xla_exec as X
+
+    raw = _sort_total_cases()[case]
+    keys = [jnp.asarray(k) for k in raw]
+    n = keys[0].shape[0]
+    iota = jnp.arange(n, dtype=jnp.int32)
+    fn = jax.jit(X.sort_total) if jit else X.sort_total
+    got = fn(keys, iota)
+    want_perm = np.asarray(jax.lax.sort(
+        [jnp.asarray(_flush_denormals(k)) for k in raw] + [iota],
+        num_keys=len(keys) + 1)[-1])
+    assert len(got) == len(keys) + 1
+    np.testing.assert_array_equal(np.asarray(got[-1]), want_perm)
+    for g, k in zip(got, raw):
+        np.testing.assert_array_equal(np.asarray(g), k[want_perm])
+
+
+def test_sort_and_group_by_doubles_beyond_float32_through_sql():
+    """GROUP BY and ORDER BY over a Double column whose values lie
+    outside float32's exponent range, at a size past every small-sort
+    shortcut, through the engine: the groups and the order are pandas'."""
+    import pandas as pd
+
+    from ydb_tpu.query import QueryEngine
+
+    vals = np.array([1e39, 1e100, 3e200, -2e60, -1e39, 1e-40, 1e-300,
+                     -3e-200, 1.5, np.nextafter(3e200, np.inf)])
+    n = 20_000
+    rng = np.random.default_rng(3)
+    df = pd.DataFrame({"id": np.arange(n, dtype=np.int64),
+                       "v": vals[rng.integers(0, len(vals), size=n)]})
+    eng = QueryEngine()
+    eng.execute("create table t (id Int64 not null, v Double, "
+                "primary key (id)) with (store = column)")
+    eng.catalog.table("t").bulk_upsert(df, eng._next_version())
+    got = eng.query("select v, count(*) as n from t group by v order by v")
+    want = (df.groupby("v").size().rename("n").reset_index()
+            .sort_values("v").reset_index(drop=True))
+    assert len(got) == len(vals)
+    np.testing.assert_array_equal(got.v.to_numpy(), want.v.to_numpy())
+    np.testing.assert_array_equal(got.n.to_numpy(), want.n.to_numpy())
+    srt = eng.query("select id, v from t order by v desc, id")
+    ref = df.sort_values(["v", "id"], ascending=[False, True])
+    np.testing.assert_array_equal(srt.id.to_numpy(), ref.id.to_numpy())
+    np.testing.assert_array_equal(srt.v.to_numpy(), ref.v.to_numpy())
+
+
+@pytest.mark.parametrize("n", [1, 7, 512, 513, 5000, (1 << 18) + 3])
+def test_blocked_cumsum_matches_numpy(n):
+    """`xla_exec.cumsum` (the blocked float scan the TPU compiler can
+    build) against numpy's sequential prefix sum; ints pass through."""
+    import jax.numpy as jnp
+
+    from ydb_tpu.ops import xla_exec as X
+
+    rng = np.random.default_rng(n)
+    x = rng.normal(size=n) * 10.0 ** rng.integers(-3, 6, size=n)
+    got = np.asarray(X.cumsum(jnp.asarray(x)))
+    assert got.dtype == np.float64 and got.shape == (n,)
+    np.testing.assert_allclose(got, np.cumsum(x), rtol=1e-10,
+                               atol=1e-6 * np.abs(x).max())
+    ints = rng.integers(-9, 9, size=n)
+    np.testing.assert_array_equal(
+        np.asarray(X.cumsum(jnp.asarray(ints))), np.cumsum(ints))

@@ -40,6 +40,13 @@ def _assert_same(view_df, base_df, keys):
     a, b = _sorted(view_df, keys), _sorted(base_df, keys)
     for c in a.columns:
         va, vb = a[c].to_numpy(), b[c].to_numpy()
+        if any(isinstance(x, str) for v in (va, vb) for x in v):
+            # a string column: pandas 3 hands a NULL back as NaN, which
+            # must not make the column look numeric
+            assert [x if isinstance(x, str) else None for x in va] \
+                == [x if isinstance(x, str) else None for x in vb], \
+                f"column {c}"
+            continue
         floaty = any(k == "f" or (k == "O" and any(
             isinstance(x, float) for x in v if x is not None))
             for v, k in ((va, va.dtype.kind), (vb, vb.dtype.kind)))

@@ -335,3 +335,39 @@ def test_registry_covers_prog_families():
                  "prog/aot_fallbacks", "prog/utilization_pct"):
         assert name in COUNTER_REGISTRY
     assert COUNTER_REGISTRY["prog/utilization_pct"].startswith("[hist]")
+
+
+# -- peak table: published figures, and none invented ----------------------
+
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", "TPU v5e"])
+def test_peaks_v5e_row_is_the_published_figure(monkeypatch, kind):
+    import jax
+    monkeypatch.setattr(progstats, "_PEAKS", {})
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [_FakeDevice("tpu", kind)])
+    pk = progstats.peaks()
+    assert pk == {"gflops": 197_000.0, "gbps": 819.0, "source": "table"}
+
+
+def test_peaks_unknown_device_invents_nothing(monkeypatch):
+    """A non-CPU device that is not in the table is neither probed as if
+    it were a CPU nor given a made-up peak: every roofline against it is
+    the explicit `unavailable` class."""
+    import jax
+    monkeypatch.setattr(progstats, "_PEAKS", {})
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [_FakeDevice("tpu", "TPU v99 mystery")])
+    monkeypatch.setattr(progstats, "_probe_cpu",
+                        lambda: pytest.fail("probed a non-CPU device"))
+    pk = progstats.peaks()
+    assert pk["gflops"] is None and pk["gbps"] is None
+    assert "TPU v99 mystery" in pk["source"]
+    r = progstats.roofline(1e9, 1e9, device_ms=5.0, pk=pk)
+    assert r["bound_class"] == "unavailable"
+    assert r["utilization_pct"] is None and r["roofline_ms"] is None

@@ -41,7 +41,7 @@ def _mk_engine(rows: int = 400):
 def fresh_compiles():
     """Force genuinely fresh compiles for store-write assertions: an
     executable that XLA loaded from its own persistent compilation
-    cache (conftest's YDB_TPU_JIT_CACHE) serializes to a payload with
+    cache (conftest's JAX_COMPILATION_CACHE_DIR) serializes to a payload with
     dangling symbol references, which the save-path round-trip
     validation rejects — correctly, but then nothing lands on disk."""
     import jax

@@ -1,6 +1,6 @@
 """Replication v1: synchronous store mirroring + standby failover.
 
-VERDICT r4 #7 Done criterion: kill the primary, boot from the standby,
+round-4 review #7 Done criterion: kill the primary, boot from the standby,
 recover to the last committed step — tests pin that no committed write
 is lost, across row and column stores, compaction rewrites, delete
 marks, and DDL."""

@@ -70,3 +70,19 @@ def test_cli_embedded_sql(capsys):
     out = capsys.readouterr().out
     assert rc == 0
     assert "q6" in out and "geomean" in out
+
+
+def test_cli_workload_run_fails_when_a_query_fails(capsys, monkeypatch):
+    """A query that fails is printed AND makes the run exit non-zero —
+    a benchmark runner must not report a broken suite as a success."""
+    from ydb_tpu import cli
+    monkeypatch.setattr(
+        cli, "_workload_queries",
+        lambda _w, _n: {"q6": "select count(*) as n from lineitem",
+                        "bad": "select * from no_such_table"})
+    rc = cli.main(["workload", "tpch", "run", "--repeat", "1",
+                   "--sf", "0.002"])
+    cap = capsys.readouterr()
+    assert rc == 1
+    assert "bad: FAILED" in cap.out and "q6" in cap.out
+    assert "1 of 2 queries FAILED: bad" in cap.err

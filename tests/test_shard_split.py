@@ -1,6 +1,6 @@
 """Shard split/merge with virtual-bucket routing.
 
-VERDICT r3 item 10: auto-split a hot/large shard with portions
+round-3 review item 10: auto-split a hot/large shard with portions
 redistributed (`schemeshard__table_stats.cpp` trigger, simplified onto
 hash-bucket routing: 64 virtual buckets map to shards; a split reassigns
 half the hot shard's buckets to a new shard and re-partitions its

@@ -1,6 +1,6 @@
 """String lane at dictionary-degenerate cardinality.
 
-VERDICT r3 item 6: everything string rides host dictionaries — fine at
+round-3 review item 6: everything string rides host dictionaries — fine at
 low cardinality, degenerate for ClickBench URL columns. This pins the
 high-cardinality path: bulk factorize encoding, VECTORIZED dictionary
 predicates (LIKE / startswith / contains via the pandas C str engine,

@@ -1,6 +1,6 @@
 """Tiled fused execution + aggregation-state spill vs pandas oracles.
 
-The scan-bigger-than-HBM discipline (VERDICT r3 item 1): budgets are
+The scan-bigger-than-HBM discipline (round-3 review item 1): budgets are
 forced tiny so the full TPC-H suite streams through the tiled path
 (`executor._execute_fused_tiled`) — multiple stacked-source tiles, one
 dispatch each — and high-cardinality group-bys exercise the host-DRAM

@@ -109,7 +109,7 @@ def test_device_matches_pandas(engines, case):
 
 
 def test_device_lane_zero_host_rows(engines):
-    """The Done criterion (VERDICT r4 #6): a supported window query on
+    """The Done criterion (round-4 review #6): a supported window query on
     the device lane leaves the pandas host-lane counter untouched."""
     dev, _host = engines
     h0 = GLOBAL.get("engine/host_lane/window_rows")

@@ -27,24 +27,20 @@ _jax.config.update("jax_enable_x64", True)
 
 # Persistent compilation cache: SQL engines compile one executable per
 # (program, shape-bucket) and re-create the same shapes across processes
-# (server restarts, CLI runs, benchmarks). On this platform a remote
-# compile costs seconds-to-minutes; a cache hit costs ~0.1s. Opt out with
-# YDB_TPU_JIT_CACHE=0, relocate with YDB_TPU_JIT_CACHE=/path.
-_cache_dir = _os.environ.get("YDB_TPU_JIT_CACHE", "")
-# forced-CPU processes (tests, virtual meshes) skip it BY DEFAULT: CPU
-# compiles are fast, and XLA:CPU AOT entries warn about host-feature
-# mismatches across processes (SIGILL risk) — the cache's value is the
-# remote TPU compiler. An explicit YDB_TPU_JIT_CACHE path still wins.
-if not _cache_dir and _os.environ.get("JAX_PLATFORMS", "") == "cpu":
-    _cache_dir = "0"
-if _cache_dir != "0":
-    if not _cache_dir:
-        _cache_dir = _os.path.join(_os.path.dirname(_os.path.dirname(
-            _os.path.abspath(__file__))), ".jax_cache")
-    try:
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-    except Exception:                    # noqa: BLE001 — cache is optional
-        pass
+# (server restarts, CLI runs, benchmarks), and the TPU compiler takes
+# seconds to minutes per program (PERF.md round 22). The cache is placed
+# from OUTSIDE, by JAX's own variable: where JAX_COMPILATION_CACHE_DIR is
+# set, nothing is set here. Otherwise it lives at the fixed
+# <checkout>/.jax_cache — the path is part of the cache key, so it is
+# never a temporary, per-pid or timed directory. Forced-CPU processes
+# (tests, virtual meshes) run without one unless a directory is given:
+# CPU compiles are fast, and XLA:CPU AOT entries warn about host-feature
+# mismatches across machines.
+if not _os.environ.get("JAX_COMPILATION_CACHE_DIR") \
+        and _os.environ.get("JAX_PLATFORMS", "") != "cpu":
+    _jax.config.update("jax_compilation_cache_dir", _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache"))
 
 # pandas 3 defaults str columns/indexes to pyarrow-backed storage, and
 # ArrowStringArray._from_sequence intermittently SEGFAULTS when a

@@ -70,7 +70,7 @@ def gen_hits(n_rows: int, seed: int = 20260729,
     """`url_cardinality` > 0: URL and Referer gain random path suffixes
     drawn from that many values (distinct combinations multiply with the
     word pools) — the real dataset's URL column is near-unique, the
-    dictionary-degeneracy case the string lane must survive (VERDICT r3
+    dictionary-degeneracy case the string lane must survive (round-3 review
     item 6)."""
     rng = np.random.default_rng(seed)
     n = n_rows

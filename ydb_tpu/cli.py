@@ -144,6 +144,7 @@ def cmd_workload_run(args) -> int:
         runner = eng.query
 
     times = {}
+    failed = []
     for name, q in queries.items():
         try:
             runner(q)                       # warm-up (compile)
@@ -156,6 +157,7 @@ def cmd_workload_run(args) -> int:
             print(f"{name:>5}: {best * 1000:9.1f} ms", flush=True)
         except Exception as e:              # noqa: BLE001 — benchmark runner
             print(f"{name:>5}: FAILED {type(e).__name__}: {e}", flush=True)
+            failed.append(name)
     if times:
         geo = math.exp(sum(math.log(t) for t in times.values())
                        / len(times))
@@ -163,6 +165,10 @@ def cmd_workload_run(args) -> int:
         print(json.dumps({"metric": f"{args.workload}_geomean_ms",
                           "value": round(geo * 1000, 1),
                           "queries": len(times)}))
+    if failed:
+        print(f"{len(failed)} of {len(queries)} queries FAILED: "
+              f"{', '.join(failed)}", file=sys.stderr)
+        return 1
     return 0
 
 

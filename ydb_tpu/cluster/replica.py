@@ -1,6 +1,6 @@
 """Replication v1 — synchronous WAL/manifest shipping to a standby.
 
-The first availability axis (VERDICT r4 #7): the reference keeps data
+The first availability axis (round-4 review #7): the reference keeps data
 alive through erasure/mirror blob groups and re-placement
 (`blobstorage_grouptype.cpp`, DSProxy `base/blobstorage.h:884`, Hive
 `hive_impl.h:158`); the v1 analog here is a MIRROR of the durable

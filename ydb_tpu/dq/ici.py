@@ -202,8 +202,6 @@ def _build_shuffle_fn(mesh, ndev, cap, seg, names, dtypes, quant_names):
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from ydb_tpu.parallel._compat import shard_map
-
     def per_device(arrays, valids, bucket, length):
         env = {n: (arrays[n][0], valids[n][0]) for n in names}
         stacked_d, stacked_v, cnts, ovf = bucket_segments(
@@ -237,7 +235,7 @@ def _build_shuffle_fn(mesh, ndev, cap, seg, names, dtypes, quant_names):
     pspec_in = ({n: P(AXIS, None) for n in names},
                 {n: P(AXIS, None) for n in names},
                 P(AXIS, None), P(AXIS))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         wrapper, mesh=mesh, in_specs=pspec_in,
         out_specs=(P(AXIS, None), P(AXIS, None), P(AXIS), P(AXIS)),
         check_vma=False))
@@ -247,8 +245,6 @@ def _build_broadcast_fn(mesh, ndev, cap, names):
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-
-    from ydb_tpu.parallel._compat import shard_map
 
     def wrapper(arrays, valids, length):
         d = {n: arrays[n][0] for n in names}
@@ -265,7 +261,7 @@ def _build_broadcast_fn(mesh, ndev, cap, names):
     pspec_in = ({n: P(AXIS, None) for n in names},
                 {n: P(AXIS, None) for n in names},
                 P(AXIS))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         wrapper, mesh=mesh, in_specs=pspec_in,
         out_specs=(P(AXIS, None), P(AXIS, None), P(AXIS)),
         check_vma=False))

@@ -1,43 +1,64 @@
 """Native runtime loader.
 
 Builds `blobio.cpp` into a shared library with the system toolchain on
-first import (cached by source mtime) and exposes it through ctypes. The
-reference's storage runtime is native C++ (PDisk/LocalDB); here the
-native layer owns the blob/WAL IO floor while JAX/XLA owns the compute
-plane. Everything degrades gracefully: if no compiler is present (or
+first import and exposes it through ctypes. The built library is keyed on
+a hash of the source (`_blobio_py<ver>_<sha256[:16]>.so`), so what is
+loaded was built from the `blobio.cpp` beside it — file times say nothing
+in a copied or checked-out tree. The reference's storage runtime is
+native C++ (PDisk/LocalDB); here the native layer owns the blob/WAL IO
+floor while JAX/XLA owns the compute plane. If no compiler is present (or
 ``YDB_TPU_NATIVE=0``), callers fall back to the byte-identical numpy
-implementation in `ydb_tpu/storage/blobfile.py`.
+implementation in `ydb_tpu/storage/blobfile.py`; `available()` says which
+one is in use, and `chip_smoke.py` fails when it is the fallback on a
+machine that has `g++`.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import sys
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "blobio.cpp")
-_SO = os.path.join(_DIR, f"_blobio_py{sys.version_info[0]}{sys.version_info[1]}.so")
+_STEM = os.path.join(
+    _DIR, f"_blobio_py{sys.version_info[0]}{sys.version_info[1]}")
 
 _lib = None
 _tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return f"{_STEM}_{digest}.so"
+
+
+def _build() -> str:
+    """Path of the library built from the current source, or "" when it
+    cannot be built."""
     try:
-        if os.path.exists(_SO) and \
-                os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-            return True
-        tmp = f"{_SO}.{os.getpid()}.tmp.so"   # per-pid: concurrent builds
+        so = _so_path()
+        if os.path.exists(so):
+            return so
+        tmp = f"{so}.{os.getpid()}.tmp.so"    # per-pid: concurrent builds
         subprocess.run(                        # must not interleave writes
             ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", tmp,
              _SRC],
             check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return True
-    except Exception:
-        return False
+        os.replace(tmp, so)
+        for old in glob.glob(f"{_STEM}*.so"):  # builds of other sources
+            if old != so and ".tmp." not in old:
+                try:
+                    os.remove(old)
+                except OSError:
+                    pass
+        return so
+    except Exception:                          # noqa: BLE001 — no toolchain
+        return ""
 
 
 def lib():
@@ -48,10 +69,11 @@ def lib():
     _tried = True
     if os.environ.get("YDB_TPU_NATIVE", "1") == "0":
         return None
-    if not _build():
+    so = _build()
+    if not so:
         return None
     try:
-        L = ctypes.CDLL(_SO)
+        L = ctypes.CDLL(so)
         L.ydbt_abi_version.restype = ctypes.c_int
         if L.ydbt_abi_version() != 2:
             return None

@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from ydb_tpu.ops.device import DeviceBlock
-from ydb_tpu.ops.xla_exec import _sort_operand, _zero_like_operand, record_sort
+from ydb_tpu.ops.xla_exec import (
+    _sort_operand, _zero_like_operand, record_sort, sort_total,
+)
 
 
 def sort_env(arrays, valids, length, sel, keys: tuple, names: tuple):
@@ -61,7 +63,7 @@ def _sort_impl(arrays, valids, length, sel, keys: tuple, names: tuple):
     # iota as the final key → deterministic (stable) order; the sorted iota
     # IS the permutation
     record_sort(cap, len(sort_ops) + 1)   # sort/rows_max + operands_max
-    out = jax.lax.sort(sort_ops + [iota], num_keys=len(sort_ops) + 1)
+    out = sort_total(sort_ops, iota)
     perm = out[-1]
     new_arrays, new_valids = {}, {}
     for name in names:

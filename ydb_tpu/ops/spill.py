@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ydb_tpu.core.block import ColumnData, HostBlock
+from ydb_tpu.ops.xla_exec import sort_total
 from ydb_tpu.utils.hashing import hash_combine, splitmix64
 
 # fixed hash slot for NULL keys: every all-NULL key lands in one partition
@@ -53,7 +54,7 @@ def _partition_sort(arrays, valids, length, names: tuple, key_names: tuple,
     pkey = jnp.where(active, part, jnp.int32(nparts))
     # iota as the second key → stable order, and the output IS the
     # permutation (no carried operands — wide sorts explode compile time)
-    _, perm = jax.lax.sort([pkey, iota], num_keys=2)
+    _, perm = sort_total([pkey], iota)
     counts = jnp.sum((pkey[:, None]
                       == jnp.arange(nparts, dtype=jnp.int32)[None, :]),
                      axis=0, dtype=jnp.int32)

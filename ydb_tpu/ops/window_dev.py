@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ydb_tpu.ops.xla_exec import cumsum, sort_total
 from ydb_tpu.utils.hashing import hash_combine, splitmix64
 
 DEVICE_FUNCS = {"row_number", "rank", "dense_rank", "sum", "min", "max",
@@ -196,7 +197,7 @@ def _segmented_scan_minmax(v, boundary, is_min):
 
 def _prefix(v):
     """Exclusive prefix sums of shape (n+1,): P[i] = sum(v[:i])."""
-    return jnp.concatenate([jnp.zeros((1,), v.dtype), jnp.cumsum(v)])
+    return jnp.concatenate([jnp.zeros((1,), v.dtype), cumsum(v)])
 
 
 def _build_window_fn(struct):
@@ -239,9 +240,7 @@ def _build_window_fn(struct):
             operands = [phash]
             for oi in range(grp["n_order"]):
                 operands.append(inputs[f"g{gi}o{oi}"])
-            operands.append(iota)
-            sorted_ops = jax.lax.sort(tuple(operands),
-                                      num_keys=len(operands) - 1)
+            sorted_ops = sort_total(operands, iota)
             perm = sorted_ops[-1]
             s_hash = sorted_ops[0]
             # --- boundaries
@@ -256,7 +255,9 @@ def _build_window_fn(struct):
             del first
             seg_start = _seg_starts(b_part, iota)
             seg_end = _seg_ends(b_part, iota, cap)
-            inv = jax.lax.sort((perm, iota), num_keys=1)[1]
+            # perm is a permutation: no ties, stability buys nothing
+            inv = jax.lax.sort((perm, iota), num_keys=1,
+                               is_stable=False)[1]
 
             def unsort(x):
                 return x[inv]
@@ -399,8 +400,7 @@ def _build_window_fn(struct):
                         else jnp.int64(_I64MAX)
                     enc = jnp.where(vv, enc, sent)
             ops_l.append(enc)
-        ops_l.append(iota)
-        sout = jax.lax.sort(tuple(ops_l), num_keys=len(ops_l) - 1)
+        sout = sort_total(ops_l, iota)
         perm_f = sout[-1][:fin["K"]]
         n_out = jnp.minimum(L, jnp.int64(fin["K"]))
         final_outs = {}

@@ -30,11 +30,11 @@ from ydb_tpu.ops import ir
 from ydb_tpu.ops.device import DeviceBlock, bucket_capacity
 from ydb_tpu.ops.join import _select_and_gather, build as build_table
 from ydb_tpu.ops.xla_exec import _trace_program, compress, groupby_tuning
-from ydb_tpu.parallel._compat import shard_map
 from ydb_tpu.parallel.collective import (AXIS, bucket_of, bucket_segments,
                                          compact_segments,
                                          exchange_segments)
-from ydb_tpu.parallel.shuffle import _fuse_device_blocks
+from ydb_tpu.parallel.shuffle import (_fuse_device_blocks,
+                                      record_exchange_rows)
 from ydb_tpu.utils.hashing import splitmix64
 
 
@@ -207,7 +207,7 @@ class ShuffleJoin:
             {n: P(AXIS, None) for n in pvalid_names},
             {n: P() for n in param_names},
         )
-        fn = jax.jit(shard_map(
+        fn = jax.jit(jax.shard_map(
             wrapper, mesh=self.mesh, in_specs=pspec_in,
             out_specs=(P(AXIS, None), P(AXIS, None), P(AXIS)),
             check_vma=False))
@@ -261,6 +261,10 @@ class ShuffleJoin:
         dev_params = {k: jnp.asarray(v) for k, v in params.items()}
         out_d, out_v, lens = fn(arrays, valids, lengths, bkeys, bns, bpay,
                                 bpv, dev_params)
+        # after the dispatch: the host waits for the scan partials only,
+        # the devices already hold the exchange to run
+        record_exchange_rows("shuffle-join", lengths,
+                             jax.device_get(lengths))
         out_cols = [Column(n, DType(Kind(k), nullable))
                     for (n, k, nullable) in holder["sig"]]
         schema = Schema(out_cols)

@@ -67,7 +67,7 @@ from ydb_tpu.utils.metrics import GLOBAL
 
 # bump whenever the object body layout or the manifest schema changes —
 # old entries then read as version skew and are evicted as corrupt
-FORMAT_VERSION = 1
+FORMAT_VERSION = 2          # 2: the object body names its execution devices
 
 _MU = threading.Lock()
 _STORES: dict = {}                     # guarded-by: _MU — root -> ProgramStore
@@ -108,6 +108,16 @@ def device_fingerprint() -> str:
         return f"{jax.default_backend()}:{kind}:{len(devs)}"
     except Exception:                  # noqa: BLE001 — fingerprint only
         return "unknown:unknown:0"
+
+
+def _devices_by_id(ids):
+    """The local devices an executable was compiled for. Without them
+    jax loads a deserialized executable across EVERY local device, and a
+    single-device program then fails at dispatch on a multi-device host
+    ("expected N shards")."""
+    import jax
+    by_id = {d.id: d for d in jax.local_devices()}
+    return [by_id[i] for i in ids]
 
 
 # --------------------------------------------------------------------------
@@ -297,7 +307,8 @@ class ProgramStore:
             rec = pickle.loads(body)
             from jax.experimental import serialize_executable
             compiled = serialize_executable.deserialize_and_load(
-                rec["payload"], rec["in_tree"], rec["out_tree"])
+                rec["payload"], rec["in_tree"], rec["out_tree"],
+                execution_devices=_devices_by_id(rec["devices"]))
         except Exception:              # noqa: BLE001 — corrupt payload
             self._evict_corrupt(kd, ent)
             return None
@@ -328,11 +339,13 @@ class ProgramStore:
             # ("Symbols not found" at deserialize) — such a payload
             # must never reach the manifest, where every future restart
             # would evict it as corrupt and recompile anyway
+            devices = compiled.runtime_executable().local_devices()
             serialize_executable.deserialize_and_load(
-                payload, in_tree, out_tree)
+                payload, in_tree, out_tree, execution_devices=devices)
             buf = io.BytesIO()
             pickle.dump({"payload": payload, "in_tree": in_tree,
-                         "out_tree": out_tree, "extra": extra or {}},
+                         "out_tree": out_tree, "extra": extra or {},
+                         "devices": [d.id for d in devices]},
                         buf, protocol=pickle.HIGHEST_PROTOCOL)
             body = buf.getvalue()
             digest = _body_digest(body)

@@ -54,7 +54,7 @@ class QueryEngine:
         # WRITE lock: mutations (DML, DDL, tx control, topic ops) from any
         # front serialize here; SELECTs run lock-free over MVCC snapshots
         # (the r3 design held this around EVERY statement — concurrency
-        # item of VERDICT r3). RLock: DML bodies re-enter execute() for
+        # item of round-3 review). RLock: DML bodies re-enter execute() for
         # their SELECT subflows. Network fronts must NOT wrap execute()
         # in this themselves anymore — the engine takes it internally.
         self.lock = threading.RLock()

@@ -225,6 +225,9 @@ COUNTER_REGISTRY = {
     "executor/fused_plans": "(derived) live fused-plan cache entries",
     "executor/tiled_queries": "queries run through the tiled path",
     "executor/shuffle_joins": "mesh shuffle-join executions",
+    "mesh/exchange_rows/*":
+        "(dynamic) rows fed to a mesh exchange, by exchange kind and the "
+        "device that held them (mesh/exchange_rows/<kind>/dev<id>)",
     "executor/spilled_rows": "rows spilled by the partition store",
     "executor/spilled_bytes": "bytes spilled by the partition store",
     # -- concurrent pipeline ------------------------------------------------

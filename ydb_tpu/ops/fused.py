@@ -23,6 +23,8 @@ state, versus O(portions × operators) dispatches for the unfused path.
 
 from __future__ import annotations
 
+import hashlib
+import re
 from typing import Optional
 
 import jax
@@ -52,6 +54,56 @@ def apply_join_schema(schema: Schema, payload_cols: list) -> Schema:
 
 
 LM_POS = "__lmpos"                       # deferred-scan row-position column
+
+
+def program_name(pipe, final_program: Optional[ir.Program],
+                 join_metas: list, rank_assigns: list, sort_spec: tuple,
+                 limit: Optional[int], keep: tuple,
+                 compact: bool = False, lane: str = "") -> str:
+    """What the fused program is CALLED: the jitted function's
+    `__name__`, so XLA's module (`jit_<name>`), the profiler's
+    `XLA Modules` line, `.sys/compiled_programs` and EXPLAIN ANALYZE all
+    show it — `lineitem_j2_gsc_ab12cd`: root table, joins, marks (g
+    group-by, s sort, l limit, c compact; b batched lane, t tile), and a
+    digest of the plan's shape (`ir.shape_text`: command kinds, column
+    names, kernel ops). At most 40 characters of [a-z0-9_].
+
+    A function of the plan's SHAPE only — no literal, no capacity, no
+    row count, no `hash()` or `id()`: the persistent compile cache keys
+    on the module's name, so a name that differed between two processes
+    would compile again in every one."""
+    progs = [pipe.pre_program]
+    joins = []
+    for kind, step in pipe.steps:
+        if kind == "join":
+            m = join_metas[len(joins)]
+            joins.append(f"join {m['probe_key']} {m['kind']} "
+                         f"{','.join(m['payload_names'])}")
+        else:
+            progs.append(step)
+    progs += [pipe.partial, final_program,
+              ir.Program(list(rank_assigns)) if rank_assigns else None]
+    grouped = any(isinstance(c, ir.GroupBy) for p in progs
+                  if p is not None for c in p.commands)
+    shape = "\n".join(
+        [ir.shape_text(p) for p in progs] + joins
+        + [",".join(f"{n}{'+' if asc else '-'}{'f' if nf else 'l'}"
+                    for (n, asc, nf) in sort_spec), ",".join(keep)])
+    marks = (("g" if grouped else "") + ("s" if sort_spec else "")
+             + ("l" if limit is not None else "")
+             + ("c" if compact else "") + lane)
+    digest = hashlib.blake2s(shape.encode(), digest_size=3).hexdigest()
+    # a transient table (the `__` namespace) holds a per-query id
+    table = "tmp" if pipe.scan.table.startswith("__") else \
+        re.sub(r"[^a-z0-9_]", "_", pipe.scan.table.lower())[:20]
+    parts = [table] + ([f"j{len(joins)}"] if joins else []) \
+        + ([marks] if marks else []) + [digest]
+    return "_".join(parts)
+
+
+def _named(fn, name: str):
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 def _prog_refs(prog: ir.Program) -> set:
@@ -114,19 +166,23 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
         env = {}
         deferred: dict = {}              # out name -> ("scan", src) |
         #                                  ("join", join_idx, src)
-        for c in scan_cols:
-            if c.name in late_scan:
-                deferred[c.name] = ("scan", c.name)
-            else:
-                d = sb[c.name].reshape(cap0)
-                v = sbv[c.name].reshape(cap0) \
-                    if c.name in sb_valid_names else None
-                env[c.name] = (d, v)
-        if deferred:
-            env[LM_POS] = (jnp.arange(cap0, dtype=jnp.int32), None)
-        sel = (jnp.arange(CAP, dtype=jnp.int32)[None, :]
-               < lengths[:, None]).reshape(cap0)
-        length = jnp.int32(cap0)
+        # `jax.named_scope`s below are HLO metadata only (`op_name`): a
+        # device operation then says which step of the plan it came
+        # from; names hold command kinds and column names, no literal
+        with jax.named_scope("scan"):
+            for c in scan_cols:
+                if c.name in late_scan:
+                    deferred[c.name] = ("scan", c.name)
+                else:
+                    d = sb[c.name].reshape(cap0)
+                    v = sbv[c.name].reshape(cap0) \
+                        if c.name in sb_valid_names else None
+                    env[c.name] = (d, v)
+            if deferred:
+                env[LM_POS] = (jnp.arange(cap0, dtype=jnp.int32), None)
+            sel = (jnp.arange(CAP, dtype=jnp.int32)[None, :]
+                   < lengths[:, None]).reshape(cap0)
+            length = jnp.int32(cap0)
         schema = Schema(list(scan_cols))
 
         def helper_names() -> tuple:
@@ -152,19 +208,21 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
                 if src is None:
                     continue
                 if src[0] == "scan":
-                    pos = env[LM_POS][0]
-                    d = sb[src[1]].reshape(cap0)[pos]
-                    v = (sbv[src[1]].reshape(cap0)[pos]
-                         if src[1] in sb_valid_names else None)
+                    with jax.named_scope(f"latemat[{nm}]"):
+                        pos = env[LM_POS][0]
+                        d = sb[src[1]].reshape(cap0)[pos]
+                        v = (sbv[src[1]].reshape(cap0)[pos]
+                             if src[1] in sb_valid_names else None)
                     env[nm] = (d, v)
                 else:
                     _k, j, s = src
-                    m = join_metas[j]
-                    row = env[m["row_col"]][0]
-                    ok = env[m["found_col"]][0]
-                    pv = builds[j]["pvalid"].get(s)
-                    d = builds[j]["payload"][s][row]
-                    v = ok if pv is None else (ok & pv[row])
+                    with jax.named_scope(f"join{j}.payload[{nm}]"):
+                        m = join_metas[j]
+                        row = env[m["row_col"]][0]
+                        ok = env[m["found_col"]][0]
+                        pv = builds[j]["pvalid"].get(s)
+                        d = builds[j]["payload"][s][row]
+                        v = ok if pv is None else (ok & pv[row])
                     env[nm] = (d, v)
             gc()
 
@@ -194,7 +252,8 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
                 meta = join_metas[bi]
                 if meta["probe_key"] in deferred:
                     materialize([meta["probe_key"]])
-                env, sel = probe_lut_traced(env, sel, builds[bi], meta)
+                with jax.named_scope(f"join{bi}.probe"):
+                    env, sel = probe_lut_traced(env, sel, builds[bi], meta)
                 if meta.get("late") and meta["kind"] in ("inner", "left"):
                     for src, out in zip(meta["src_names"],
                                         meta["payload_names"]):
@@ -211,7 +270,8 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
         if final_program is not None:
             run(final_program)
         if sel is not None:
-            env, length = compress(env, length, sel, cap)
+            with jax.named_scope("compress"):
+                env, length = compress(env, length, sel, cap)
             sel = None
 
         need: set = set()
@@ -219,21 +279,26 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
             ir.expr_columns(a.expr, need)
         need.update(n for (n, _asc, _nf) in sort_spec)
         materialize(sorted(need & set(deferred)))
-        for a in rank_assigns:
-            env[a.name] = _eval(a.expr, env, params, cap)
         if sort_spec:
-            arrays = {n: d for n, (d, _v) in env.items()}
-            valids = {n: v for n, (d, v) in env.items() if v is not None}
-            arrays2, valids2, length = sort_env(
-                arrays, valids, length, None, sort_spec,
-                tuple(arrays.keys()))
-            env = {n: (arrays2[n], valids2.get(n)) for n in arrays2}
+            with jax.named_scope("sort"):
+                for a in rank_assigns:
+                    env[a.name] = _eval(a.expr, env, params, cap)
+                arrays = {n: d for n, (d, _v) in env.items()}
+                valids = {n: v for n, (d, v) in env.items()
+                          if v is not None}
+                arrays2, valids2, length = sort_env(
+                    arrays, valids, length, None, sort_spec,
+                    tuple(arrays.keys()))
+                env = {n: (arrays2[n], valids2.get(n)) for n in arrays2}
         if lim2 is not None:
-            bound = params[LIMIT_PARAM] if lift_limit else jnp.int32(lim2)
-            length = jnp.minimum(length, bound)
-            out_cap = min(bucket_capacity(lim2, minimum=128), cap)
-            env = {n: (d[:out_cap], v[:out_cap] if v is not None else None)
-                   for n, (d, v) in env.items()}
+            with jax.named_scope("limit"):
+                bound = params[LIMIT_PARAM] if lift_limit \
+                    else jnp.int32(lim2)
+                length = jnp.minimum(length, bound)
+                out_cap = min(bucket_capacity(lim2, minimum=128), cap)
+                env = {n: (d[:out_cap],
+                           v[:out_cap] if v is not None else None)
+                       for n, (d, v) in env.items()}
         # the tail gather: whatever is still deferred materializes HERE,
         # at the post-limit capacity — a LIMIT-K plan gathers its payload
         # widths for K-bucket rows, not scan capacity
@@ -254,9 +319,10 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
         valid_names = [n for n in out_names if env[n][1] is not None]
         layout_box["data"] = data_layout
         layout_box["valids"] = valid_names
-        data_stacks = {k: jnp.stack(v) for k, v in groups.items()}
-        valid_stack = (jnp.stack([env[n][1] for n in valid_names])
-                       if valid_names else None)
+        with jax.named_scope("output"):
+            data_stacks = {k: jnp.stack(v) for k, v in groups.items()}
+            valid_stack = (jnp.stack([env[n][1] for n in valid_names])
+                           if valid_names else None)
         return data_stacks, valid_stack, length, aux
 
     return fn, layout_box
@@ -293,7 +359,10 @@ def build_fused_fn(pipe, final_program: Optional[ir.Program],
                                  lift_limit=lift_limit,
                                  late_scan=late_scan,
                                  compact_prog=compact_prog)
-    return jax.jit(fn), layout_box
+    name = program_name(pipe, final_program, join_metas, rank_assigns,
+                        sort_spec, limit, keep,
+                        compact=compact_prog is not None)
+    return jax.jit(_named(fn, name)), layout_box
 
 
 def build_fused_batched_fn(pipe, final_program: Optional[ir.Program],
@@ -321,7 +390,9 @@ def build_fused_batched_fn(pipe, final_program: Optional[ir.Program],
                                  lift_limit=lift_limit, late_scan=late_scan)
     batched = jax.vmap(fn, in_axes=(None, None, None, None, param_axes),
                        axis_size=axis_size)
-    return jax.jit(batched), layout_box
+    name = program_name(pipe, final_program, join_metas, rank_assigns,
+                        sort_spec, limit, keep, lane="b")
+    return jax.jit(_named(batched, name)), layout_box
 
 
 def _unpack_fused_host(host_stacks, host_valids, n: int, layout_box: dict,
@@ -456,17 +527,18 @@ def build_tile_fn(pipe, scan_cols: list, K: int, CAP: int,
     stripped here (a row-id crossing a tile boundary would dangle)."""
     join_metas = [{**m, "late": False} for m in join_metas]
 
-    @jax.jit
     def fn(sb, sbv, lengths, builds, params):
         cap = K * CAP
         env = {}
-        for c in scan_cols:
-            d = sb[c.name].reshape(cap)
-            v = sbv[c.name].reshape(cap) if c.name in sb_valid_names else None
-            env[c.name] = (d, v)
-        sel = (jnp.arange(CAP, dtype=jnp.int32)[None, :]
-               < lengths[:, None]).reshape(cap)
-        length = jnp.int32(cap)
+        with jax.named_scope("scan"):
+            for c in scan_cols:
+                d = sb[c.name].reshape(cap)
+                v = sbv[c.name].reshape(cap) \
+                    if c.name in sb_valid_names else None
+                env[c.name] = (d, v)
+            sel = (jnp.arange(CAP, dtype=jnp.int32)[None, :]
+                   < lengths[:, None]).reshape(cap)
+            length = jnp.int32(cap)
         schema = Schema(list(scan_cols))
 
         def run(prog, env, length, sel, schema, cap):
@@ -483,7 +555,8 @@ def build_tile_fn(pipe, scan_cols: list, K: int, CAP: int,
         for kind, step in pipe.steps:
             if kind == "join":
                 meta = join_metas[bi]
-                env, sel = probe_lut_traced(env, sel, builds[bi], meta)
+                with jax.named_scope(f"join{bi}.probe"):
+                    env, sel = probe_lut_traced(env, sel, builds[bi], meta)
                 bi += 1
                 schema = apply_join_schema(schema, meta["payload_cols"])
             else:
@@ -493,12 +566,14 @@ def build_tile_fn(pipe, scan_cols: list, K: int, CAP: int,
             env, length, sel, schema, cap = run(pipe.partial, env, length,
                                                 sel, schema, cap)
         if sel is not None:
-            env, length = compress(env, length, sel, cap)
+            with jax.named_scope("compress"):
+                env, length = compress(env, length, sel, cap)
         out_d = {n: d for n, (d, _v) in env.items()}
         out_v = {n: v for n, (d, v) in env.items() if v is not None}
         return out_d, out_v, length
 
-    return fn
+    name = program_name(pipe, None, join_metas, (), (), None, (), lane="t")
+    return jax.jit(_named(fn, name))
 
 
 def tile_cache_key(pipe, scan_cols, K, CAP, sb_valid_names, builds_sig,

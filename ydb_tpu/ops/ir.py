@@ -192,6 +192,44 @@ class Program:
         return f"Program({self.commands!r})"
 
 
+def _expr_shape(e: Expr) -> str:
+    if isinstance(e, Col):
+        return e.name
+    if isinstance(e, Param):
+        return "$" + e.name
+    if isinstance(e, Call):
+        return f"{e.op}({','.join(_expr_shape(a) for a in e.args)})"
+    return "?"                           # a literal: its value is left out
+
+
+def shape_text(program: Optional[Program]) -> str:
+    """The program's SHAPE as text: command kinds, column names, kernel
+    ops. No literal, no kernel static, no key domain, bound or capacity
+    — nothing that follows the data or a statement's constants, so two
+    literal sets of one statement read the same (where `fingerprint`
+    differs). What a device program is NAMED from, never a cache key."""
+    if program is None:
+        return "-"
+    out = []
+    for cmd in program.commands:
+        if isinstance(cmd, Assign):
+            out.append(f"assign {cmd.name}={_expr_shape(cmd.expr)}")
+        elif isinstance(cmd, Filter):
+            out.append(f"filter {_expr_shape(cmd.pred)}")
+        elif isinstance(cmd, GroupBy):
+            aggs = ",".join(f"{a.out}={a.func}({a.arg or ''})"
+                            for a in cmd.aggs)
+            # keys and carried keys as one set: which side of the split
+            # a key falls on is decided from the data (bounds rewrite)
+            keys = ",".join(sorted(cmd.keys + cmd.carry_keys))
+            out.append(f"groupby {keys}|{aggs}")
+        elif isinstance(cmd, Projection):
+            out.append(f"projection {','.join(cmd.names)}")
+        else:
+            out.append(type(cmd).__name__.lower())
+    return ";".join(out)
+
+
 # --------------------------------------------------------------------------
 # Type inference
 # --------------------------------------------------------------------------

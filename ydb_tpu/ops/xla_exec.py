@@ -1130,43 +1130,51 @@ def _trace_program(program: ir.Program, in_schema_cols, cap, env, length,
     keep the strict behavior."""
     schema = Schema(list(in_schema_cols))
     for cmd in program.commands:
-        if isinstance(cmd, ir.Assign):
-            data, valid = _eval(cmd.expr, env, params, cap)
-            env[cmd.name] = (data, valid)
-            dt = ir.infer_expr(cmd.expr, schema)
-            schema = Schema([c for c in schema.columns if c.name != cmd.name]
-                            + [Column(cmd.name, dt)])
-        elif isinstance(cmd, ir.Filter):
-            data, valid = _eval(cmd.pred, env, params, cap)
-            mask = data if valid is None else (data & valid)
-            sel = mask if sel is None else (sel & mask)
-        elif isinstance(cmd, ir.GroupBy):
-            env, length = _trace_group_by(cmd, env, schema, sel, length, cap)
-            # the scatter path shrinks the working capacity to a small
-            # bucket; subsequent commands trace at the new size
-            if env:
-                cap = next(iter(env.values()))[0].shape[0]
-            schema = ir.infer_schema(ir.Program([cmd]), schema)
-            sel = None
-        elif isinstance(cmd, ir.Projection):
-            schema = schema.select(list(cmd.names))
-            if passthrough:
-                new_env = {nm: env[nm] for nm in cmd.names if nm in env}
-                for h in passthrough:
-                    if h in env:
-                        new_env[h] = env[h]
-                env = new_env
+        # HLO metadata only (`op_name`): a device operation then says which
+        # IR command it came from — a kind and a column name, no literal
+        scope = f"assign[{cmd.name}]" if isinstance(cmd, ir.Assign) \
+            else type(cmd).__name__.lower()
+        with jax.named_scope(scope):
+            if isinstance(cmd, ir.Assign):
+                data, valid = _eval(cmd.expr, env, params, cap)
+                env[cmd.name] = (data, valid)
+                dt = ir.infer_expr(cmd.expr, schema)
+                schema = Schema([c for c in schema.columns
+                                 if c.name != cmd.name]
+                                + [Column(cmd.name, dt)])
+            elif isinstance(cmd, ir.Filter):
+                data, valid = _eval(cmd.pred, env, params, cap)
+                mask = data if valid is None else (data & valid)
+                sel = mask if sel is None else (sel & mask)
+            elif isinstance(cmd, ir.GroupBy):
+                env, length = _trace_group_by(cmd, env, schema, sel, length,
+                                              cap)
+                # the scatter path shrinks the working capacity to a small
+                # bucket; subsequent commands trace at the new size
+                if env:
+                    cap = next(iter(env.values()))[0].shape[0]
+                schema = ir.infer_schema(ir.Program([cmd]), schema)
+                sel = None
+            elif isinstance(cmd, ir.Projection):
+                schema = schema.select(list(cmd.names))
+                if passthrough:
+                    new_env = {nm: env[nm] for nm in cmd.names
+                               if nm in env}
+                    for h in passthrough:
+                        if h in env:
+                            new_env[h] = env[h]
+                    env = new_env
+                else:
+                    env = {nm: env[nm] for nm in cmd.names}
+            elif isinstance(cmd, ir.Compact):
+                env, length, sel, live, ovf = compact_env(env, length, sel,
+                                                          cap, cmd.cap)
+                cap = cmd.cap
+                if aux is not None:
+                    aux["compact_live"] = live
+                    aux["compact_ovf"] = ovf
             else:
-                env = {nm: env[nm] for nm in cmd.names}
-        elif isinstance(cmd, ir.Compact):
-            env, length, sel, live, ovf = compact_env(env, length, sel,
-                                                      cap, cmd.cap)
-            cap = cmd.cap
-            if aux is not None:
-                aux["compact_live"] = live
-                aux["compact_ovf"] = ovf
-        else:
-            raise TypeError(f"bad command {cmd!r}")
+                raise TypeError(f"bad command {cmd!r}")
     return env, length, sel, schema
 
 

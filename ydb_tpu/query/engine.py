@@ -10,6 +10,7 @@ concurrent sessions over the shared engine.
 
 from __future__ import annotations
 
+import logging
 import os
 from contextlib import contextmanager as _contextmanager
 from typing import Optional
@@ -25,6 +26,13 @@ from ydb_tpu.scheme.catalog import Catalog
 from ydb_tpu.sql import ast, parse
 from ydb_tpu.storage.mvcc import Snapshot, WriteVersion
 from ydb_tpu.core.schema import Column, Schema
+
+
+_LOG = logging.getLogger("ydb_tpu.slow_query")
+
+# host share of a statement's wall (wall less device queue and run) past
+# which it logs its phases; steady state is a few ms
+HOST_SLOW_MS = 100.0
 
 
 class QueryError(Exception):
@@ -904,32 +912,26 @@ class QueryEngine:
         # slot wait itself is BOUNDED by the same deadline, so a window
         # saturated by admission-queued queries sheds instead of
         # head-of-line blocking every later SELECT indefinitely.
+        from contextlib import ExitStack
+
         from ydb_tpu.query.admission import AdmissionTimeout
         from ydb_tpu.utils.metrics import GLOBAL
-        if not self._pipe_sem.acquire(timeout=self.admission.timeout_s):
-            GLOBAL.inc("pipeline/window_timeouts")
-            raise AdmissionTimeout(
-                f"pipeline window saturated: {self.pipeline_window} "
-                "queries dispatched-or-queued for longer than the "
-                "admission deadline")
-        try:
-            import time as _time
-            t_adm = _time.perf_counter()
-            with self.admission.admit(est):
-                wait_ms = (_time.perf_counter() - t_adm) * 1000.0
-                if wait_ms >= 1.0:
-                    # the statement QUEUED behind the byte budget:
-                    # record the wait as its own (already-elapsed) span
-                    # so critical-path extraction can class it
-                    # admission_wait instead of burying it in a gap
-                    sp = self.tracer.attach_span(
-                        "admission-wait", admitted_mb=est >> 20)
-                    if sp is not None:
-                        sp.start_ms = round(sp.start_ms - wait_ms, 3)
-                        sp.dur_ms = round(wait_ms, 3)
-                return self._dispatch_drain_admitted(plan, snap, est)
-        finally:
-            self._pipe_sem.release()
+        with ExitStack() as held:
+            # both waits under ONE span (critical-path extraction
+            # classes it admission_wait; `phases["admission_ms"]`), so a
+            # statement that queued here says so instead of leaving a gap
+            with self.tracer.span("admission-wait", admitted_mb=est >> 20):
+                if not self._pipe_sem.acquire(
+                        timeout=self.admission.timeout_s):
+                    GLOBAL.inc("pipeline/window_timeouts")
+                    raise AdmissionTimeout(
+                        f"pipeline window saturated: "
+                        f"{self.pipeline_window} queries "
+                        "dispatched-or-queued for longer than the "
+                        "admission deadline")
+                held.callback(self._pipe_sem.release)
+                held.enter_context(self.admission.admit(est))
+            return self._dispatch_drain_admitted(plan, snap, est)
 
     def _dispatch_drain_admitted(self, plan, snap, est: int) -> HostBlock:
         """Body of the pipeline once the window slot + byte reservation
@@ -1115,6 +1117,7 @@ class QueryEngine:
             # never match a future run — remembering them would churn
             # the bounded forced-trace set and inflate slow_query/*
             self._note_slow(stats.sql, stats.total_ms, stats.kind)
+            self._note_host_slow(stats)
         GLOBAL.inc("engine/rows_out", block.length)
         GLOBAL.inc("engine/queries")
         self.query_history.append(stats)
@@ -1136,6 +1139,31 @@ class QueryEngine:
                 del self._slow_sqls[victim]
             self._slow_sqls[sql] = max(self._slow_sqls.get(sql, 0.0),
                                        total_ms)
+
+    def _note_host_slow(self, stats) -> None:
+        """A statement the HOST held up says where: one log line (text
+        prefix, wall, phases, the wall no span covers) and
+        `slow_query/host_slow` when its wall less the device's share
+        (`queue_ms`, `device_ms`) passes `HOST_SLOW_MS`. Not at
+        `slow_query_ms`: a statement whose program runs a second is not
+        slow on the host. Sampled statements only: an unsampled one has
+        no phases to take the device's share from."""
+        ph = stats.phases
+        if not ph:
+            return
+        host_ms = stats.total_ms - ph.get("queue_ms", 0.0) \
+            - ph.get("device_ms", 0.0)
+        if host_ms < HOST_SLOW_MS:
+            return
+        from ydb_tpu.utils.metrics import GLOBAL
+        GLOBAL.inc("slow_query/host_slow")
+        unspanned = stats.total_ms - stats.parse_ms - stats.plan_ms \
+            - sum(ph.values())
+        _LOG.warning(
+            "host-slow statement %r: wall %.1f ms, host %.1f ms (parse "
+            "%.1f, plan %.1f, phases %s), unspanned %.1f ms",
+            stats.sql[:60], stats.total_ms, host_ms, stats.parse_ms,
+            stats.plan_ms, ph, unspanned)
 
     def counters(self) -> dict:
         """Live counter snapshot (the /counters endpoint payload)."""

@@ -40,19 +40,21 @@ from ydb_tpu.utils import progstats
 DEFAULT_BLOCK_ROWS = 1 << 20
 
 
-def _xla_scope(name: str):
-    """`jax.profiler.TraceAnnotation`-compatible named scope around the
-    device phases of fused/batched dispatch, named IDENTICALLY to our
-    tracer spans — an on-chip XLA profile (Perfetto from
-    `jax.profiler.trace`) lines its slices up with the engine's own
-    span names. Effectively a no-op on CPU (and a nullcontext wherever
-    the profiler API is absent); never allowed to fail a query."""
-    try:
-        from jax.profiler import TraceAnnotation
-        return TraceAnnotation(name)
-    except Exception:                    # noqa: BLE001 — observability
-        from contextlib import nullcontext
-        return nullcontext()
+def split_device_wait(t_enqueued: float, t_wait: float, t_done: float,
+                      last_done: float) -> tuple:
+    """Split one wait for the device, [t_wait, t_done], into the part
+    spent behind another statement's program and this program's own run:
+    -> (queue_ms, run_ms), disjoint, their sum the wait.
+
+    The device runs one program at a time in the order they were
+    enqueued, so this one started when it was enqueued or when the
+    program before it completed, whichever came later. `last_done` is
+    the last completion the executor observed before this one. A
+    host-clock estimate: exact to a thread wake-up while statements
+    drain promptly; a result drained long after its program completed
+    reads as wait none, run none (the start is clamped into the wait)."""
+    run_start = min(max(t_enqueued, last_done, t_wait), t_done)
+    return (run_start - t_wait) * 1000.0, (t_done - run_start) * 1000.0
 
 
 def _fused_evict_hook(key) -> None:
@@ -97,10 +99,14 @@ class Executor:
         self.enable_fused = True
         # engine-provided tracer (utils/tracing.Tracer) — None = no spans
         self.tracer = None
+        # when the last program completion was observed (perf_counter):
+        # what `_await_device` splits a statement's device wait by
+        import threading as _threading
+        self._done_mu = _threading.Lock()
+        self._last_done = 0.0             # guarded-by: _done_mu
         # which path the last execute() took (THREAD-LOCAL — concurrent
         # sessions each observe their own):
         # fused | fused-tiled[...] | portioned | distributed | literal
-        import threading as _threading
         self._tls = _threading.local()
         # build sides above this estimate hash-partition into a GraceJoin
         # (host-DRAM partitions probed one at a time — the spill budget)
@@ -208,6 +214,30 @@ class Executor:
             return self.tracer.span(name, **attrs)
         from ydb_tpu.utils.tracing import _NullSpanCtx
         return _NullSpanCtx()   # yields a throwaway span (attrs writable)
+
+    def _await_device(self, outputs, t_enqueued: float, prog_kid,
+                      fresh: bool) -> None:
+        """The `device-execute` span: block until the dispatched program's
+        outputs are ready, and split the wait where it ends into queue
+        (behind another statement's program) and run — the span's
+        `queue_ms` / `run_ms` attrs, `prog/queue_ms`, and the run joined
+        to the program's compiler-reported flops/bytes (roofline)."""
+        import time as _time
+
+        from ydb_tpu.utils.metrics import GLOBAL
+        with self._span("device-execute") as sp:
+            t_wait = _time.perf_counter()
+            jax.block_until_ready(outputs)
+            t_done = _time.perf_counter()
+            with self._done_mu:
+                last_done = self._last_done
+                self._last_done = max(last_done, t_done)
+            queue_ms, run_ms = split_device_wait(t_enqueued, t_wait,
+                                                 t_done, last_done)
+            sp.attrs["queue_ms"] = round(queue_ms, 3)
+            sp.attrs["run_ms"] = round(run_ms, 3)
+        GLOBAL.inc("prog/queue_ms", queue_ms)
+        progstats.record_exec(prog_kid, run_ms, fresh=fresh)
 
     # -- cache warmup ------------------------------------------------------
 
@@ -470,8 +500,7 @@ class Executor:
         dev_params = {k: (jnp.asarray(v) if isinstance(v, np.ndarray) else v)
                       for k, v in all_params.items()}
         build_inputs = [F.build_traced_inputs(bt) for bt in builds]
-        with self._span("device-dispatch", k=K, cap=CAP) as dsp, \
-                _xla_scope("device-dispatch"):
+        with self._span("device-dispatch", k=K, cap=CAP) as dsp:
             import time as _time
             t_disp = _time.perf_counter()
             fill_wait_ms = 0.0
@@ -494,6 +523,7 @@ class Executor:
                 fill_wait_ms = (_time.perf_counter() - t_disp) * 1000.0
             data_stacks, valid_stack, length, aux = fn(
                 arrays, valids, lengths, build_inputs, dev_params)
+            t_enqueued = _time.perf_counter()
             if fresh_compile:
                 # jit compiles synchronously inside the first call of a
                 # fresh shape (AOT: in capture above); steady-state
@@ -543,15 +573,8 @@ class Executor:
             # delta — the program is still running when the future is
             # consumed promptly) and the D2H transfer + host unpack, so
             # the trace attributes device time separately from link time
-            with self._span("device-execute"), \
-                    _xla_scope("device-execute"):
-                import time as _time
-                t_exec = _time.perf_counter()
-                jax.block_until_ready((data_stacks, valid_stack, length))
-                exec_ms = (_time.perf_counter() - t_exec) * 1000.0
-            # roofline join: the measured device-execute delta against
-            # this program's compiler-reported flops/bytes
-            progstats.record_exec(prog_kid, exec_ms, fresh=fresh_compile)
+            self._await_device((data_stacks, valid_stack, length),
+                               t_enqueued, prog_kid, fresh_compile)
             if aux:
                 # compact live/overflow: 8 bytes of plan metadata the
                 # loud-rerun decision needs host-side. The program is
@@ -1259,8 +1282,7 @@ class Executor:
         build_inputs = [F.build_traced_inputs(bt) for bt in builds]
         try:
             with self._span("device-dispatch-batched", k=K, cap=CAP,
-                            b=Bb) as dsp, \
-                    _xla_scope("device-dispatch-batched"):
+                            b=Bb) as dsp:
                 import time as _time
                 t_disp = _time.perf_counter()
                 if fn is None:
@@ -1279,6 +1301,7 @@ class Executor:
                 # — `_fused_plan_setup` never hands it a compact_prog)
                 data_stacks, valid_stack, length, _aux = fn(
                     arrays, valids, lengths, build_inputs, dev_params)
+                t_enqueued = _time.perf_counter()
                 if fresh_compile:
                     dsp.attrs["compile_ms"] = round(
                         (_time.perf_counter() - t_disp) * 1000.0, 3)
@@ -1303,13 +1326,8 @@ class Executor:
         out_dicts = {n2: d for n2, d in dicts.items() if out_schema.has(n2)}
         out_dicts.update({n2: d for n2, d in plan.result_dicts.items()
                           if out_schema.has(n2)})
-        with self._span("device-execute"), _xla_scope("device-execute"):
-            import time as _time
-            t_exec = _time.perf_counter()
-            jax.block_until_ready((data_stacks, valid_stack, length))
-            exec_ms = (_time.perf_counter() - t_exec) * 1000.0
-        progstats.record_exec(getattr(fn, "key_id", None), exec_ms,
-                              fresh=fresh_compile)
+        self._await_device((data_stacks, valid_stack, length), t_enqueued,
+                           getattr(fn, "key_id", None), fresh_compile)
         with self._span("readout-transfer", b=len(members)):
             blocks = F.fetch_fused_batch(data_stacks, valid_stack, length,
                                          layout_box, out_schema, out_dicts,

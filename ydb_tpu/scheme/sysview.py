@@ -253,13 +253,17 @@ def sysview_block(engine, name: str) -> HostBlock:
         # wide like device_transfers): one row per captured executable —
         # cache hit/miss/eviction counts, compile wall, the XLA cost +
         # memory analysis, cumulative measured device ms and the
-        # roofline verdict. Evicted entries persist marked `evicted`;
+        # roofline verdict. `name` is the XLA module's name, as a device
+        # trace shows it (`jit_lineitem_g_ab12cd`); `device_ms` is run
+        # WITHOUT wait: time queued behind another statement's program
+        # is `prog/queue_ms`. Evicted entries persist marked `evicted`;
         # `cost` is an explicit 'unavailable' where the backend
         # withholds analysis (never fabricated zeros). Empty under
         # YDB_TPU_PROGSTATS=0.
         from ydb_tpu.utils.progstats import inventory_rows
         rows = [{
-            "program": r["program"], "kind": r["kind"],
+            "program": r["program"], "name": r["name"],
+            "kind": r["kind"],
             "state": r["state"], "source": r["source"],
             "hits": int(r["hits"]),
             "misses": int(r["misses"]),
@@ -285,7 +289,8 @@ def sysview_block(engine, name: str) -> HostBlock:
             "utilization_pct": float(r["utilization_pct"]),
             "bound_class": r["bound_class"],
         } for r in inventory_rows()]
-        return _block(rows, [("program", str), ("kind", str),
+        return _block(rows, [("program", str), ("name", str),
+                             ("kind", str),
                              ("state", str), ("source", str),
                              ("hits", "int64"),
                              ("misses", "int64"),

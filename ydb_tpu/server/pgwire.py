@@ -27,6 +27,10 @@ import socket
 import socketserver
 import struct
 import threading
+import time
+from contextlib import contextmanager, nullcontext
+
+from ydb_tpu.utils.metrics import GLOBAL
 
 _SSL_REQUEST = 80877103
 _CANCEL_REQUEST = 80877102
@@ -190,6 +194,7 @@ class _Handler(socketserver.BaseRequestHandler):
 
             session = srv.engine.session()
             self._aborted = False      # PG aborted-transaction state
+            self._sent_rows = False    # the last reply built held rows
             self._stmts: dict = {}     # name -> (sql, [oid])
             self._portals: dict = {}   # name -> bound sql
             pending = b""              # extended-flow replies batch to Sync
@@ -209,7 +214,8 @@ class _Handler(socketserver.BaseRequestHandler):
                 payload = read_exact(length - 4)
                 if tag == b"Q":
                     sql = payload.rstrip(b"\0").decode()
-                    sock.sendall(self._run(srv, session, sql))
+                    self._sent_rows = False
+                    self._flush(srv, sock, self._run(srv, session, sql))
                 elif tag == b"S":                       # Sync
                     if session.tx is None:
                         # portals survive Sync inside a tx block (spec)
@@ -440,7 +446,7 @@ class _Handler(socketserver.BaseRequestHandler):
             block = srv.engine.execute(sql, session=session)
             kind = srv.engine.last_stats.kind
             if kind in ("select", "setop", "explain"):
-                return self._rows(block) \
+                return self._rows(srv, block) \
                     + _ready(self._status(session))
             n = getattr(srv.engine, "last_rows_affected", 0)
         except Exception as e:               # noqa: BLE001 — wire boundary
@@ -499,11 +505,33 @@ class _Handler(socketserver.BaseRequestHandler):
             chunks.append(_msg(b"D", b"".join(body)))
         return b"".join(chunks)
 
-    @classmethod
-    def _rows(cls, block) -> bytes:
-        """Simple-query result: RowDescription + DataRows + tag."""
-        return cls._row_desc(block) + cls._data_rows(block) \
-            + _msg(b"C", _cstr(f"SELECT {block.length}"))
+    def _rows(self, srv, block) -> bytes:
+        """Simple-query result: RowDescription + DataRows + tag, counted
+        where the work is done (`front/pg/*`)."""
+        with _encoding(srv):
+            out = self._row_desc(block) + self._data_rows(block) \
+                + _msg(b"C", _cstr(f"SELECT {block.length}"))
+        GLOBAL.inc("front/pg/statements")
+        GLOBAL.inc("front/pg/rows", block.length)
+        GLOBAL.inc("front/pg/bytes", len(out))
+        self._sent_rows = True
+        return out
+
+    def _flush(self, srv, sock, reply: bytes) -> None:
+        """Put a simple query's answer on the wire; the flush of one
+        that holds rows is the last part of `front/pg/encode_ms`."""
+        with _encoding(srv) if self._sent_rows else nullcontext():
+            sock.sendall(reply)
+
+
+@contextmanager
+def _encoding(srv):
+    """The front's own work on an answer with rows: `front/pg/encode_ms`
+    and a `pg-encode` annotation in the profiler's trace."""
+    t0 = time.perf_counter()
+    with srv.engine.tracer.annotate("pg-encode"):
+        yield
+    GLOBAL.inc("front/pg/encode_ms", (time.perf_counter() - t0) * 1000.0)
 
 
 class PgServer:

@@ -389,7 +389,12 @@ COUNTER_REGISTRY = {
     "prog/compile_ms": "[viz] cumulative AOT lower+compile wall",
     "prog/executions":
         "[viz] measured device executions joined to a program",
-    "prog/device_ms": "[viz] cumulative measured device-execute wall",
+    "prog/device_ms":
+        "[viz] cumulative measured device run (the device-execute wait "
+        "less prog/queue_ms)",
+    "prog/queue_ms":
+        "[viz] device-execute wait spent behind another statement's "
+        "program",
     "prog/evicted": "[viz] inventory entries marked evicted (LRU)",
     "prog/recompiled":
         "[viz] evicted keys compiled again (a MISS, never a hit)",
@@ -452,6 +457,9 @@ COUNTER_REGISTRY = {
     "slow_query/count": "[viz] over-threshold statements",
     "slow_query/worst_ms": "worst statement wall seen",
     "slow_query/*": "over-threshold statements by kind",
+    "slow_query/host_slow":
+        "[viz] statements whose wall less device queue and run passed "
+        "100 ms (each logs its phases)",
     # -- materialized views (ydb_tpu/views/): continuous queries folding
     # CDC deltas into device-maintained aggregate state ----------------------
     "view/registered": "(dynamic) materialized views currently defined",
@@ -475,6 +483,11 @@ COUNTER_REGISTRY = {
         "behind state, or degraded view)",
     # -- servers ------------------------------------------------------------
     "server/http_queries": "HTTP front statements",
+    "front/pg/statements": "[viz] pgwire statements answered with rows",
+    "front/pg/rows": "[viz] rows those answers held",
+    "front/pg/bytes": "[viz] bytes those answers put on the wire",
+    "front/pg/encode_ms":
+        "[viz] row description + data rows + flush, cumulative",
     "server/rpc_in_flight": "(dynamic) gRPC handler gauge",
     "coordinator/plan_step": "(derived) last 2PC plan step",
 }
@@ -517,9 +530,11 @@ class QueryStats:
     # execution); empty when the lane is off or the shape was ineligible
     batching: dict = field(default_factory=dict)
     # device-timeline attribution (`utils/tracing.phase_breakdown` over
-    # this statement's spans): {build_ms, upload_ms, dispatch_ms,
-    # device_ms, readout_ms, compile_ms} — empty when the statement was
-    # unsampled or never touched the device
+    # this statement's spans): {admission_ms, build_ms, upload_ms,
+    # dispatch_ms, queue_ms, device_ms, readout_ms, compile_ms},
+    # disjoint; device_ms is the program's run, queue_ms the wait behind
+    # another statement's — empty when the statement was unsampled or
+    # never touched the device
     phases: dict = field(default_factory=dict)
     # resource-ledger rollup (`utils/memledger.MemLedger.summary`):
     # peak/alloc device bytes, padding live-vs-padded account, host
@@ -583,8 +598,9 @@ class QueryStats:
             p = self.phases
             out += ("\n-- phases: " + " | ".join(
                 f"{k.removesuffix('_ms')} {p[k]:.1f}ms"
-                for k in ("compile_ms", "build_ms", "upload_ms",
-                          "dispatch_ms", "device_ms", "readout_ms")
+                for k in ("admission_ms", "compile_ms", "build_ms",
+                          "upload_ms", "dispatch_ms", "queue_ms",
+                          "device_ms", "readout_ms")
                 if k in p))
         if self.memory and (self.memory.get("peak_bytes")
                             or self.memory.get("transfers")):
@@ -632,7 +648,8 @@ class QueryStats:
                 tag = (" [fresh]" if pr.get("fresh")
                        else f" [{src.replace('_', '-')}]"
                        if src != "fresh" else "")
-                line = f"\n--   {pr['key']}{tag}: "
+                name = f" {pr['name']}" if pr.get("name") else ""
+                line = f"\n--   {pr['key']}{name}{tag}: "
                 if pr.get("bound_class") == "unavailable" \
                         or pr.get("flops") is None:
                     line += ("cost unavailable (backend withheld "

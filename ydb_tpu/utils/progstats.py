@@ -57,6 +57,7 @@ from __future__ import annotations
 import os
 import threading
 import time
+import weakref
 from collections import OrderedDict
 
 from ydb_tpu.utils.metrics import GLOBAL, GLOBAL_HIST
@@ -64,6 +65,8 @@ from ydb_tpu.utils.metrics import GLOBAL, GLOBAL_HIST
 _MU = threading.RLock()
 _INVENTORY: OrderedDict = OrderedDict()   # guarded-by: _MU — key_id -> entry
 _PEAKS: dict = {}                         # guarded-by: _MU — probe/table cache
+# guarded-by: _MU — key_id -> live handle (gone with its cache entry)
+_HANDLES: weakref.WeakValueDictionary = weakref.WeakValueDictionary()
 _TLS = threading.local()
 
 # roofline work below this is dispatch/launch overhead territory — the
@@ -227,6 +230,14 @@ def key_id(kind: str, key) -> str:
     return f"{kind}:{h}"
 
 
+def _module_name(jit_fn) -> str:
+    """The name XLA gives the program's module (`jit_<__name__>`): what
+    a device trace's `XLA Modules` line calls it. The fused builders name
+    theirs from the plan (`ops/fused.program_name`)."""
+    name = getattr(jit_fn, "__name__", "") or ""
+    return f"jit_{name}" if name else ""
+
+
 def _cost_dict(compiled):
     """Normalized cost analysis, or None when the backend withholds it
     (raises, empty, or all-zero — zeros would fabricate a free
@@ -320,13 +331,16 @@ class ProgramHandle:
     without AOT. `clear_cache` drops the executable AND clears the jit
     cache, so the exec-cache release-on-evict lifecycle holds."""
 
-    __slots__ = ("key_id", "compile_ms", "_jit", "_compiled")
+    __slots__ = ("key_id", "compile_ms", "_jit", "_compiled",
+                 "__weakref__")
 
     def __init__(self, kid: str, jit_fn, compiled, compile_ms: float):
         self.key_id = kid
         self.compile_ms = compile_ms
         self._jit = jit_fn
         self._compiled = compiled
+        with _MU:
+            _HANDLES[kid] = self
 
     def __call__(self, *args):
         c = self._compiled
@@ -380,7 +394,8 @@ def capture(kind: str, key, jit_fn, args, consult_store: bool = True,
         if rec is not None:
             compiled = rec["compiled"]
             cost, mem, hlo = _analysis_triple(compiled, rec["extra"])
-            _register(kid, kind, 0.0, cost, mem, hlo, source="store")
+            _register(kid, kind, 0.0, cost, mem, hlo, source="store",
+                      name=_module_name(jit_fn))
             return ProgramHandle(kid, jit_fn, compiled, 0.0)
     t0 = time.perf_counter()
     try:
@@ -391,11 +406,13 @@ def capture(kind: str, key, jit_fn, args, consult_store: bool = True,
     ms = (time.perf_counter() - t0) * 1000.0
     cost, mem, hlo = (_cost_dict(compiled), _memory_dict(compiled),
                       _hlo_op_count(compiled))
-    _register(kid, kind, ms, cost, mem, hlo, source=source)
+    name = _module_name(jit_fn)
+    _register(kid, kind, ms, cost, mem, hlo, source=source, name=name)
     pstore = _store()
     if pstore is not None:
         extra = dict(store_extra or {})
-        extra.update({"cost": cost, "memory": mem, "hlo_ops": hlo})
+        extra.update({"cost": cost, "memory": mem, "hlo_ops": hlo,
+                      "name": name})
         pstore.save(kind, key, compiled, extra=extra)
     return ProgramHandle(kid, jit_fn, compiled, round(ms, 3))
 
@@ -419,7 +436,8 @@ def store_load(kind: str, key, rebuild):
     kid = key_id(kind, key)
     compiled = rec["compiled"]
     cost, mem, hlo = _analysis_triple(compiled, rec["extra"])
-    _register(kid, kind, 0.0, cost, mem, hlo, source="store")
+    _register(kid, kind, 0.0, cost, mem, hlo, source="store",
+              name=(rec["extra"] or {}).get("name", ""))
     return ProgramHandle(kid, LazyJit(rebuild), compiled, 0.0), rec["extra"]
 
 
@@ -435,7 +453,7 @@ def store_save(kind: str, key, handle, extra=None) -> None:
         return
     ent = inventory_entry(handle.key_id) or {}
     full = {"cost": ent.get("cost"), "memory": ent.get("memory"),
-            "hlo_ops": ent.get("hlo_ops", 0)}
+            "hlo_ops": ent.get("hlo_ops", 0), "name": ent.get("name", "")}
     full.update(extra or {})
     pstore.save(kind, key, compiled, extra=full)
 
@@ -450,7 +468,7 @@ def _store():
 
 
 def _register(kid: str, kind: str, compile_ms, cost, mem,
-              hlo_ops: int, source: str = "fresh") -> None:
+              hlo_ops: int, source: str = "fresh", name: str = "") -> None:
     GLOBAL.inc("prog/registered")
     if compile_ms:
         GLOBAL.inc("prog/compile_ms", compile_ms)
@@ -460,7 +478,7 @@ def _register(kid: str, kind: str, compile_ms, cost, mem,
         ent = _INVENTORY.get(kid)
         if ent is None:
             ent = _INVENTORY[kid] = {
-                "key": kid, "kind": kind, "state": "live",
+                "key": kid, "kind": kind, "name": "", "state": "live",
                 "hits": 0, "misses": 0, "evictions": 0, "compiles": 0,
                 "compile_ms": 0.0, "cost": None, "memory": None,
                 "hlo_ops": 0, "execs": 0, "device_ms": 0.0,
@@ -475,6 +493,7 @@ def _register(kid: str, kind: str, compile_ms, cost, mem,
         ent["memory"] = mem
         ent["hlo_ops"] = int(hlo_ops)
         ent["source"] = source
+        ent["name"] = name or ent["name"]
         _INVENTORY.move_to_end(kid)
         while len(_INVENTORY) > ring_len():
             _INVENTORY.popitem(last=False)
@@ -511,10 +530,11 @@ def mark_evicted(kind: str, key) -> None:
 
 
 def record_exec(kid, device_ms: float, fresh: bool = False) -> None:
-    """Join one measured device-execute span (the block_until_ready
-    delta of a fused/batched dispatch) to its program: cumulative
-    device ms, the roofline utilization histogram, and the statement
-    accumulator feeding `QueryStats.programs`."""
+    """Join one measured device run (`Executor._await_device`: the
+    block_until_ready wait of a fused/batched dispatch LESS the part
+    spent behind another statement's program) to its program:
+    cumulative device ms, the roofline utilization histogram, and the
+    statement accumulator feeding `QueryStats.programs`."""
     if kid is None or not enabled():
         return
     device_ms = max(float(device_ms), 0.0)
@@ -529,6 +549,7 @@ def record_exec(kid, device_ms: float, fresh: bool = False) -> None:
         ent["device_ms_max"] = max(ent["device_ms_max"], device_ms)
         cost = dict(ent["cost"]) if ent["cost"] else None
         kind = ent["kind"]
+        name = ent.get("name", "")
         source = ent.get("source", "fresh")
     GLOBAL.inc("prog/executions")
     GLOBAL.inc("prog/device_ms", device_ms)
@@ -539,7 +560,7 @@ def record_exec(kid, device_ms: float, fresh: bool = False) -> None:
         GLOBAL_HIST.observe("prog/utilization_pct", rf["utilization_pct"])
     st = current()
     if st is not None:
-        st.add({"key": kid, "kind": kind, "source": source,
+        st.add({"key": kid, "kind": kind, "name": name, "source": source,
                 "device_ms": round(device_ms, 3), "fresh": bool(fresh),
                 "flops": cost.get("flops") if cost else None,
                 "bytes_accessed":
@@ -643,7 +664,8 @@ def inventory_rows() -> list:
         rf = roofline(cost.get("flops"), cost.get("bytes_accessed"),
                       e["device_ms_max"] or None, pk=pk)
         rows.append({
-            "program": e["key"], "kind": e["kind"], "state": e["state"],
+            "program": e["key"], "name": e.get("name", ""),
+            "kind": e["kind"], "state": e["state"],
             "source": e.get("source", "fresh"),
             "hits": e["hits"], "misses": e["misses"],
             "evictions": e["evictions"], "compiles": e["compiles"],
@@ -675,6 +697,17 @@ def inventory_entry(kid: str):
     with _MU:
         e = _INVENTORY.get(kid)
         return dict(e) if e is not None else None
+
+
+def hlo_text(kid: str) -> str:
+    """Tooling hook: the optimized HLO text of a program that is still
+    live — each instruction with its `op_name`, the `jax.named_scope`
+    path of the IR command it came from (what maps a device trace's
+    `fusion.N` to a line of the plan). '' once the entry was evicted."""
+    with _MU:
+        handle = _HANDLES.get(kid)
+    compiled = getattr(handle, "_compiled", None)
+    return compiled.as_text() if compiled is not None else ""
 
 
 def reset_for_tests() -> None:

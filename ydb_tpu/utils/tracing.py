@@ -17,6 +17,13 @@ Sampling is decided ONCE at statement admission (`begin_trace(sampled=
 False)`): an unsampled statement records nothing — `span()` hands back
 throwaway contexts, so the hot path costs one TLS read and one object
 allocation per phase, and the output is byte-identical to tracing off.
+
+ONE mechanism, two clocks: a sampled `span(name)` also opens a
+`jax.profiler.TraceAnnotation(name)`, so under `jax.profiler.trace` every
+span is an event of `/host:CPU` on the device trace's clock (an idle gap
+of the device is then named by the span that covers it); an unsampled
+one opens neither. With no profiler session the annotation is a flag
+test inside the profiler's library.
 """
 
 from __future__ import annotations
@@ -24,8 +31,15 @@ from __future__ import annotations
 import itertools
 import os
 import time
+from contextlib import nullcontext
 from dataclasses import dataclass, field
 from typing import Optional
+
+try:
+    from jax.profiler import TraceAnnotation as _Annotation
+except ImportError:                      # a jax without the profiler
+    def _Annotation(_name):              # noqa: N802 — stands in for a class
+        return nullcontext()
 
 # span/trace ids draw from one per-process counter salted per process:
 # two worker processes contributing spans to the same assembled trace
@@ -68,6 +82,7 @@ def span_from_dict(d: dict) -> Span:
 # `.phases`, the bench artifact, `.sys/query_profiles` columns): every
 # device-timeline segment of a fused/batched/DQ execution
 PHASE_SPANS = {
+    "admission-wait": "admission_ms",
     "join-builds": "build_ms",
     "superblock-upload": "upload_ms",
     "device-dispatch": "dispatch_ms",
@@ -87,12 +102,22 @@ def phase_breakdown(spans) -> dict:
     then carries `compile_wait_ms` (the portion of the build the
     dispatch actually blocked on), and only that much is pulled out —
     subtracting the full off-thread build would eat the real enqueue
-    time the span also covers."""
+    time the span also covers.
+
+    A `device-execute` span is the host's wait for the device; the
+    executor splits it where the wait ends (`queue_ms` / `run_ms`
+    attrs: behind another statement's program, then this one's own run),
+    so `device_ms` is run without wait and `queue_ms + device_ms` is the
+    span. A span without the attrs (an older worker's) counts whole."""
     out: dict = {}
     in_dispatch = 0.0
     for s in spans:
         key = PHASE_SPANS.get(s.name)
-        if key is not None:
+        if key == "device_ms" and "run_ms" in s.attrs:
+            out[key] = out.get(key, 0.0) + float(s.attrs["run_ms"])
+            out["queue_ms"] = out.get("queue_ms", 0.0) \
+                + float(s.attrs.get("queue_ms", 0.0))
+        elif key is not None:
             out[key] = out.get(key, 0.0) + s.dur_ms
         c = s.attrs.get("compile_ms")
         if c:
@@ -126,6 +151,7 @@ class Tracer:
         if not hasattr(s, "spans"):
             s.spans, s.stack, s.trace_id, s.depth = [], [], 0, 0
             s.sampled, s.root_parent = True, None
+            s.last_sampled = True
         return s
 
     @property
@@ -183,6 +209,13 @@ class Tracer:
         if s.depth > 0 and not s.sampled:
             return _NullSpanCtx()
         return _SpanCtx(self, name, attrs)
+
+    def annotate(self, name: str):
+        """A profiler annotation and no span: for work a front does for
+        the thread's LAST statement after its trace closed (encoding the
+        answer). Follows that statement's sampling decision."""
+        return _Annotation(name) if self._state().last_sampled \
+            else nullcontext()
 
     def attach_span(self, name: str, parent_id: int = None,
                     **attrs) -> Optional[Span]:
@@ -269,6 +302,7 @@ class Tracer:
                 sp.dur_ms = self._now() - sp.start_ms
         out = s.spans
         s.spans = []
+        s.last_sampled = bool(s.sampled)
         s.trace_id, s.root_parent, s.sampled = 0, None, True
         if self.sink is not None and out:
             try:
@@ -322,9 +356,12 @@ class _SpanCtx:
                       t._now(), attrs=dict(self.attrs))
         st.spans.append(self.s)
         st.stack.append(self.s)
+        self.ann = _Annotation(self.name)
+        self.ann.__enter__()
         return self.s
 
     def __exit__(self, exc_type, exc, _tb):
+        self.ann.__exit__(exc_type, exc, _tb)
         self.s.dur_ms = self.tracer._now() - self.s.start_ms
         if exc_type is not None:
             self.s.attrs.setdefault("error", exc_type.__name__)

@@ -154,8 +154,12 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
     their payload widths. `compact_prog` (an `ir.Compact` wrapper built
     by the executor) shrinks the working capacity to a ladder-quantized
     bound after the joins, so deferred gathers and the partial group-by
-    run at the small shape; its live/overflow scalars come back in the
-    4th return element (the executor's loud-rerun input)."""
+    run at the small shape: one int32 sort of the live positions, then
+    one bound-sized gather per column still in the env
+    (`xla_exec.compact_env`; deferred columns are not in it and gather
+    from the superblock through the compacted `__lmpos`). Its
+    live/overflow scalars come back in the 4th return element (the
+    executor's loud-rerun input)."""
     lim2 = None if limit is None else limit + (offset or 0)
     layout_box: dict = {}
 

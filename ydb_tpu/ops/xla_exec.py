@@ -29,7 +29,12 @@ installation that no longer exists (a large fixed latency per dispatch
 after the first readout, a surcharge per *scatter* op); neither cost is
 measured on the current chip yet. What IS measured there (PERF.md round
 22) is the TPU compiler's time: sorts and float64 prefix sums dominate it,
-hence `sort_total` and `cumsum` below.
+hence `sort_total` and `cumsum` below. And (PERF.md round 27) that a
+scatter is priced by its updates, 67 ns each for a float64 column, a
+gather by its indices, 9-12 ns each a 32-bit stream, and a one-word
+6.29 M-row sort at about 10 ms: so `ir.Compact` (`compact_env`) sorts the
+live positions once and gathers each column at the bound, and writes
+nothing of scan width per column.
 """
 
 from __future__ import annotations
@@ -1196,29 +1201,32 @@ def compress(env, length, sel, cap):
 def compact_env(env, length, sel, cap, new_cap: int):
     """`ir.Compact` lowering: stable-compress selected rows to the front
     of a `new_cap`-sized buffer — downstream operators compile at the
-    small shape. O(cap) prefix-sum + dropping scatter, NOT an argsort:
-    each live row's target slot is its rank among live rows
-    (`cumsum - 1`), dropped/overflow rows scatter out of bounds
-    (`mode="drop"`), so the compact costs one pass over the wide
-    capacity instead of a sort of it. Returns (env', length', sel',
-    live, overflow): `live` is the true selected count and
-    `overflow = live > new_cap` — the host-side loud-rerun signal; rows
-    beyond `new_cap` ARE dropped from env', so a result produced under
-    overflow must be discarded, never served."""
+    small shape. The source row of every kept slot is found ONCE, by
+    one single-operand int32 sort: a live row's key is its own position,
+    a dead row's is `cap`, so the first `new_cap` sorted keys ARE the
+    source rows, in scan order (live keys are distinct: the order is
+    the stable one whatever the sort does with ties). Each column and
+    validity plane is then one gather of `new_cap` indices; nothing of
+    width `cap` is written per column (a scatter is priced by its
+    updates: `cap` of them cost 417-548 ms a float64 column at SF1, the
+    sort 10 ms; PERF.md round 27). Returns (env', length', sel', live,
+    overflow): `live` is the true selected count and `overflow = live >
+    new_cap` — the host-side loud-rerun signal; rows beyond `new_cap`
+    ARE dropped from env', so a result produced under overflow must be
+    discarded, never served. Slots at or past `length'` are masked by
+    `sel'` and hold a copy of row `cap - 1` (the clamped key of a dead
+    row)."""
+    if new_cap > cap:
+        raise ValueError(f"Compact to {new_cap} rows from {cap}: it shrinks")
     iota = jnp.arange(cap, dtype=jnp.int32)
     active = (iota < length) if sel is None else ((iota < length) & sel)
-    rank = jnp.cumsum(active.astype(jnp.int32)) - 1
-    tgt = jnp.where(active, rank, jnp.int32(new_cap))   # inactive → OOB
     live = jnp.sum(active.astype(jnp.int32))
     ovf = live > jnp.int32(new_cap)
-
-    def _scatter(a):
-        return jnp.zeros((new_cap,), a.dtype).at[tgt].set(a, mode="drop")
-
+    keys = jnp.where(active, iota, jnp.int32(cap))
+    src = jnp.minimum(jax.lax.sort(keys)[:new_cap], jnp.int32(cap - 1))
     new_env = {}
     for name, (d, v) in env.items():
-        new_env[name] = (_scatter(d),
-                         _scatter(v) if v is not None else None)
+        new_env[name] = (d[src], v[src] if v is not None else None)
     new_len = jnp.minimum(live, jnp.int32(new_cap))
     new_sel = jnp.arange(new_cap, dtype=jnp.int32) < new_len
     return new_env, new_len, new_sel, live, ovf

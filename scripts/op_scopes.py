@@ -10,9 +10,12 @@ gather) the instructions inside `%fusion.3` came from.
 Builds the cell's engine the way `benchmark/run.py` does (its loader, its
 configuration, its statements from `--seed`, two passes, so the programs
 are the ones the cell runs and come out of the same compile cache), then
-writes for every live fused program its module name and, per fusion /
-sort / while instruction, its own `op_name` and the scopes of what it
-fuses. `--result`: a traced run's result line; its `breakdown.device_ops`
+writes for every live fused program, and for the `shard_map` programs of
+the mesh lanes (`jit_mesh_sj_<table>_<digest>`, `jit_mesh_merge_...`), its
+module name and, per fusion / sort / while / collective instruction, its
+own `op_name` and the scopes of what it fuses (`exchange/bucket`,
+`exchange/all_to_all`, `exchange/compact`, `shuffle.probe`, `rest/...`,
+`partial/...`, `merge/...` in a mesh program). `--result`: a traced run's result line; its `breakdown.device_ops`
 are printed with their scopes beside them.
 
 Tooling, not a measurement: reads the benchmark's files, edits none, and
@@ -38,10 +41,20 @@ _INSTR = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
 _OPCODE = re.compile(r"[\]})] ([a-z][\w\-]*)\(")
 _CALLS = re.compile(r"(?:calls|body|to_apply)=%?([\w.\-]+)")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
-_JIT = re.compile(r"^jit\([\w.\-]+\)/")
+# `jit(name)/`, and inside a mesh program `jit(name)/shard_map/`
+_JIT = re.compile(r"^jit\([\w.\-]+\)/(?:(?:jit\()?shard_map\)?/)?")
 # what a reader of a breakdown asks about; the rest is elementwise
 HEAVY = ("gather", "scatter", "sort", "reduce", "reduce-window",
          "dynamic-slice", "dynamic-update-slice", "while")
+# a collective may be split into `-start` / `-done` by the TPU compiler
+COLLECTIVES = ("all-to-all", "all-gather", "all-reduce",
+               "collective-permute")
+# the kinds `utils/progstats` inventories the named programs under
+KINDS = ("fused", "mesh-sj", "mesh-merge")
+
+
+def _heavy(opcode: str) -> bool:
+    return opcode in HEAVY or opcode.startswith(COLLECTIVES)
 
 
 def _scope(line: str) -> str:
@@ -68,12 +81,12 @@ def parse_hlo(text: str) -> dict:
     out: dict = {}
     for instrs in bodies.values():
         for name, opcode, line in instrs:
-            if opcode != "fusion" and opcode not in HEAVY:
+            if opcode != "fusion" and not _heavy(opcode):
                 continue
             inner: dict = {}
             for callee in _CALLS.findall(line):
                 for _n, op2, line2 in bodies.get(callee, ()):
-                    if op2 in HEAVY:
+                    if _heavy(op2):
                         scopes = inner.setdefault(op2, [])
                         s = _scope(line2)
                         if s and s not in scopes:
@@ -141,7 +154,7 @@ def main(argv=None) -> int:
     programs = {}
     for row in progstats.inventory_rows():
         text = progstats.hlo_text(row["program"])
-        if row["kind"] != "fused" or not text:
+        if row["kind"] not in KINDS or not text:
             continue
         if args.hlo_dir:
             import gzip

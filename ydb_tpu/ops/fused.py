@@ -92,13 +92,33 @@ def program_name(pipe, final_program: Optional[ir.Program],
     marks = (("g" if grouped else "") + ("s" if sort_spec else "")
              + ("l" if limit is not None else "")
              + ("c" if compact else "") + lane)
-    digest = hashlib.blake2s(shape.encode(), digest_size=3).hexdigest()
-    # a transient table (the `__` namespace) holds a per-query id
-    table = "tmp" if pipe.scan.table.startswith("__") else \
-        re.sub(r"[^a-z0-9_]", "_", pipe.scan.table.lower())[:20]
-    parts = [table] + ([f"j{len(joins)}"] if joins else []) \
-        + ([marks] if marks else []) + [digest]
+    parts = [_table_tag(pipe.scan.table)] \
+        + ([f"j{len(joins)}"] if joins else []) \
+        + ([marks] if marks else []) + [_shape_digest(shape)]
     return "_".join(parts)
+
+
+def _table_tag(table: str) -> str:
+    # a transient table (the `__` namespace) holds a per-query id
+    return "tmp" if table.startswith("__") else \
+        re.sub(r"[^a-z0-9_]", "_", table.lower())[:20]
+
+
+def _shape_digest(shape: str) -> str:
+    return hashlib.blake2s(shape.encode(), digest_size=3).hexdigest()
+
+
+def mesh_program_name(lane: str, table: str, progs, extra=()) -> str:
+    """`program_name`'s sibling for a mesh lane's `shard_map` program:
+    `mesh_sj_lineitem_ab12cd` — the lane (`sj` the shuffle join's
+    exchange, `merge` the partials' merge), the root table, and the same
+    digest over the shape of the IR programs it traces and `extra` lines
+    (join key and kind, column names). Shape only, as there: no literal,
+    no capacity, no device count."""
+    shape = "\n".join([ir.shape_text(p) for p in progs] + list(extra))
+    tag = _table_tag(table)
+    return "_".join(["mesh", lane] + ([tag] if tag else [])
+                    + [_shape_digest(shape)])
 
 
 def _named(fn, name: str):

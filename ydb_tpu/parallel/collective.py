@@ -35,9 +35,39 @@ QUANT_BLOCK = 128
 _NAN_CODE = -128                     # outside the symmetric quant range
 
 
+def record_exchange_bytes(kind: str, ndev: int, seg: int, row_bytes: int,
+                          crossing_rows: int) -> None:
+    """Book one exchange's bytes, from shapes and row counts the host
+    already holds: `mesh/exchange_bytes/<kind>` is what the fixed-capacity
+    segments put on the wire — `ndev`² segments of `seg` rows of
+    `row_bytes` (summed column and validity widths), less the segment a
+    device keeps for itself, (ndev-1)/ndev — and
+    `mesh/exchange_live_bytes/<kind>` the part of it that was
+    `crossing_rows` live rows bound for another device. The difference
+    is padding."""
+    from ydb_tpu.utils.metrics import GLOBAL
+    # lint: allow-counters(mesh/exchange_bytes/* registered)
+    GLOBAL.inc(f"mesh/exchange_bytes/{kind}",
+               ndev * seg * row_bytes * (ndev - 1))
+    # lint: allow-counters(mesh/exchange_live_bytes/* registered)
+    GLOBAL.inc(f"mesh/exchange_live_bytes/{kind}",
+               crossing_rows * row_bytes)
+
+
+def env_row_bytes(env, names) -> int:
+    """Bytes one row of `env` takes in an exchange: every column's data
+    width plus its validity plane's byte."""
+    return sum(env[n][0].dtype.itemsize + 1 for n in names)
+
+
 def bucket_of(env, key_names, ndev):
     """Hash-partition bucket id per row (device-side, same hash family
     as host shard routing — `ydb_tpu/utils/hashing.py`)."""
+    with jax.named_scope("exchange/bucket"):
+        return _bucket_of(env, key_names, ndev)
+
+
+def _bucket_of(env, key_names, ndev):
     h = None
     for k in key_names:
         d, v = env[k]
@@ -62,6 +92,11 @@ def bucket_segments(env, bucket, length, cap, seg, ndev, names):
     counts `[ndev]` (clamped to `seg`), and the overflow flag (any
     target bucket held more than `seg` rows — caller reruns with
     full-capacity segments, which cannot overflow)."""
+    with jax.named_scope("exchange/bucket"):
+        return _bucket_segments(env, bucket, length, cap, seg, ndev, names)
+
+
+def _bucket_segments(env, bucket, length, cap, seg, ndev, names):
     from ydb_tpu.ops.xla_exec import compress
     iota = jnp.arange(cap, dtype=jnp.int32)
     active = iota < length
@@ -87,26 +122,37 @@ def bucket_segments(env, bucket, length, cap, seg, ndev, names):
 def exchange_segments(stacked_d, stacked_v, cnts, names, axis=AXIS):
     """The ICI hop: segment d of device s → device d segment s, for
     every column's data + valid stacks plus the row counts."""
-    recv_d = {n: jax.lax.all_to_all(stacked_d[n], axis, 0, 0,
-                                    tiled=False) for n in names}
-    recv_v = {n: jax.lax.all_to_all(stacked_v[n], axis, 0, 0,
-                                    tiled=False) for n in names}
-    recv_c = jax.lax.all_to_all(cnts[:, None], axis, 0, 0,
-                                tiled=False)[:, 0]              # (D,)
+    with jax.named_scope("exchange/all_to_all"):
+        recv_d = {n: jax.lax.all_to_all(stacked_d[n], axis, 0, 0,
+                                        tiled=False) for n in names}
+        recv_v = {n: jax.lax.all_to_all(stacked_v[n], axis, 0, 0,
+                                        tiled=False) for n in names}
+        recv_c = jax.lax.all_to_all(cnts[:, None], axis, 0, 0,
+                                    tiled=False)[:, 0]          # (D,)
     return recv_d, recv_v, recv_c
 
 
-def compact_segments(recv_d, recv_v, recv_c, seg, ndev, names):
+def compact_segments(recv_d, recv_v, recv_c, seg, ndev, names,
+                     out_cap=None):
     """Flatten the received `[ndev, seg]` segment stacks and compact the
     live rows to the front. Returns `(env, total)` over `[ndev * seg]`
-    buffers."""
-    from ydb_tpu.ops.xla_exec import compress
+    buffers — or over `[out_cap]` where the caller KNOWS no device
+    receives more rows than that (it counted them): each kept slot's
+    source row is found once and every column gathered at `out_cap`
+    (`xla_exec.compact_env`), so nothing downstream works on ndev
+    segments' worth of padding."""
+    from ydb_tpu.ops.xla_exec import compact_env, compress
     flat = ndev * seg
-    jrow = jnp.arange(seg, dtype=jnp.int32)
-    seg_mask = (jrow[None, :] < recv_c[:, None]).reshape(-1)
-    env = {n: (recv_d[n].reshape(-1), recv_v[n].reshape(-1))
-           for n in names}
-    return compress(env, jnp.int32(flat), seg_mask, flat)
+    with jax.named_scope("exchange/compact"):
+        jrow = jnp.arange(seg, dtype=jnp.int32)
+        seg_mask = (jrow[None, :] < recv_c[:, None]).reshape(-1)
+        env = {n: (recv_d[n].reshape(-1), recv_v[n].reshape(-1))
+               for n in names}
+        if out_cap is None or out_cap >= flat:
+            return compress(env, jnp.int32(flat), seg_mask, flat)
+        env, total, _sel, _live, _ovf = compact_env(
+            env, jnp.int32(flat), seg_mask, flat, out_cap)
+        return env, total
 
 
 def gather_all(stacked_d, stacked_v, cnts, seg, ndev, names, axis=AXIS):
@@ -114,16 +160,11 @@ def gather_all(stacked_d, stacked_v, cnts, seg, ndev, names, axis=AXIS):
     device's `[seg]` buffer (all-gather over ICI) and compacts the live
     rows. Inputs are per-device `[seg]` buffers (not per-target stacks).
     Returns `(env, total)` over `[ndev * seg]`."""
-    from ydb_tpu.ops.xla_exec import compress
-    recv_d = {n: jax.lax.all_gather(stacked_d[n], axis) for n in names}
-    recv_v = {n: jax.lax.all_gather(stacked_v[n], axis) for n in names}
-    recv_c = jax.lax.all_gather(cnts, axis)                     # (D,)
-    flat = ndev * seg
-    jrow = jnp.arange(seg, dtype=jnp.int32)
-    seg_mask = (jrow[None, :] < recv_c[:, None]).reshape(-1)
-    env = {n: (recv_d[n].reshape(-1), recv_v[n].reshape(-1))
-           for n in names}
-    return compress(env, jnp.int32(flat), seg_mask, flat)
+    with jax.named_scope("exchange/all_gather"):
+        recv_d = {n: jax.lax.all_gather(stacked_d[n], axis) for n in names}
+        recv_v = {n: jax.lax.all_gather(stacked_v[n], axis) for n in names}
+        recv_c = jax.lax.all_gather(cnts, axis)                 # (D,)
+    return compact_segments(recv_d, recv_v, recv_c, seg, ndev, names)
 
 
 # -- padding-waste accounting ----------------------------------------------

@@ -25,6 +25,7 @@ overflow — and reruns the batch, the analog of DQ channel spilling
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
@@ -39,14 +40,13 @@ from ydb_tpu.core.dtypes import DType, Kind
 from ydb_tpu.core.schema import Column, Schema
 from ydb_tpu.ops import ir
 from ydb_tpu.ops.device import bucket_capacity
+from ydb_tpu.ops.fused import _named, mesh_program_name
 from ydb_tpu.ops.xla_exec import _trace_program, compress, groupby_tuning
 from ydb_tpu.parallel.collective import (AXIS, bucket_of, bucket_segments,
-                                         compact_segments,
-                                         exchange_segments)
-
-# back-compat alias: callers historically imported the bucketizer from
-# here; the one implementation lives in parallel/collective.py now
-_bucket_of = bucket_of
+                                         compact_segments, env_row_bytes,
+                                         exchange_segments, gather_all,
+                                         record_exchange_bytes)
+from ydb_tpu.utils import progstats
 
 
 def make_mesh(n_devices: Optional[int] = None) -> Mesh:
@@ -89,6 +89,19 @@ def _fuse_device_blocks(blocks, caps, pcap, names):
     return out_d, out_v, cnt
 
 
+def live_capacity(per_dev_blocks: list) -> int:
+    """Capacity of one device's fused buffer: the most LIVE rows any
+    device holds in its blocks, rounded up a power of two — not the sum
+    of the blocks' capacities, which after an exchange is ndev times the
+    rows that can be live and multiplied again at the next one. One small
+    transfer of the blocks' row counts; their programs have run by now,
+    or the wait for them falls here."""
+    lens = jax.device_get([[b.length for b in blks]
+                           for blks in per_dev_blocks])
+    return bucket_capacity(max(max(int(sum(ls)) for ls in lens), 1),
+                           minimum=128)
+
+
 def record_exchange_rows(kind: str, lengths, rows) -> None:
     """Book the rows a mesh exchange was fed, per device that held them:
     `mesh/exchange_rows/<kind>/dev<id>`. `lengths` is the exchange's
@@ -111,12 +124,16 @@ class DistributedAgg:
     in_schema: Schema
     mesh: Mesh
     seg_rows: int = 0        # per-edge segment capacity (0: = capacity)
+    table: str = ""          # the plan's root table: names the program
 
     def __post_init__(self):
         # sig -> (shard_fn, out-schema holder): alternating signatures
         # (capacity buckets, valid sets, param sets) each keep their
         # compiled fn instead of thrashing a single slot
         self._fns: dict = {}
+        self.name = mesh_program_name(
+            "merge", self.table, [self.partial, self.final],
+            [",".join(self.in_schema.names)])
 
     # -- compile ----------------------------------------------------------
 
@@ -135,47 +152,48 @@ class DistributedAgg:
                 env[c.name] = (arrays[c.name][0], valids.get(c.name))
             env = {k: (d, v[0] if v is not None else None)
                    for k, (d, v) in env.items()}
-            env, glen, sel, schema = _trace_program(
-                partial_prog, in_cols, cap, env, length[0], params)
+            with jax.named_scope("partial"):
+                env, glen, sel, schema = _trace_program(
+                    partial_prog, in_cols, cap, env, length[0], params)
             assert sel is None  # partial ends in GroupBy
             names = list(schema.names)
             # the scatter group-by path shrinks the working capacity
             pcap = next(iter(env.values()))[0].shape[0] if env else cap
             seg = min(self.seg_rows or pcap, pcap)
+            row_bytes = env_row_bytes(env, names)
 
             if not key_names or ndev == 1:
-                # global agg: no shuffle, merge via all_gather
-                datas = {n: jax.lax.all_gather(env[n][0], AXIS) for n in names}
-                valid_g = {n: jax.lax.all_gather(
-                    env[n][1] if env[n][1] is not None
-                    else jnp.ones((pcap,), jnp.bool_), AXIS) for n in names}
-                lens = jax.lax.all_gather(glen, AXIS)
-                iota = jnp.arange(pcap, dtype=jnp.int32)
-                seg_mask = (iota[None, :] < lens[:, None]).reshape(-1)
-                env2 = {n: (datas[n].reshape(-1), valid_g[n].reshape(-1))
-                        for n in names}
-                env2, tot = compress(env2, jnp.int32(ndev * pcap), seg_mask,
-                                     ndev * pcap)
-                fenv, flen, fsel, fschema = _trace_program(
-                    final_prog, list(schema.columns), ndev * pcap, env2, tot,
-                    params)
-                if fsel is not None:
-                    fcap = next(iter(fenv.values()))[0].shape[0] if fenv \
-                        else ndev * pcap
-                    fenv, flen = compress(fenv, flen, fsel, fcap)
+                # global agg: no shuffle, merge via all_gather (every
+                # device receives the other devices' `pcap` rows: the
+                # wire of ndev² segments of `pcap`, less a device's own)
+                wire["shape"] = (pcap, row_bytes)
+                env2, tot = gather_all(
+                    {n: env[n][0] for n in names},
+                    {n: env[n][1] if env[n][1] is not None
+                     else jnp.ones((pcap,), jnp.bool_) for n in names},
+                    glen, pcap, ndev, names)
+                with jax.named_scope("merge"):
+                    fenv, flen, fsel, fschema = _trace_program(
+                        final_prog, list(schema.columns), ndev * pcap, env2,
+                        tot, params)
+                    if fsel is not None:
+                        fcap = next(iter(fenv.values()))[0].shape[0] \
+                            if fenv else ndev * pcap
+                        fenv, flen = compress(fenv, flen, fsel, fcap)
                 # merged result is identical on every device — report once
                 flen = jnp.where(jax.lax.axis_index(AXIS) == 0, flen, 0)
                 out_d = {n: fenv[n][0] for n in fschema.names}
                 out_v = {n: (fenv[n][1] if fenv[n][1] is not None
                              else jnp.ones_like(out_d[n], dtype=jnp.bool_))
                          for n in fschema.names}
-                return out_d, out_v, flen, jnp.bool_(False), tuple(
+                return out_d, out_v, flen, jnp.bool_(False), glen, tuple(
                     (c.name, c.dtype.kind.value, c.dtype.nullable)
                     for c in fschema.columns)
 
             # hash shuffle: build ndev segments of seg rows each, swap
             # them over ICI, compact (shared with shuffle_join + the DQ
             # ICI channel plane — parallel/collective.py)
+            wire["shape"] = (seg, row_bytes)
             bucket = bucket_of(env, key_names, ndev)
             stacked_d, stacked_v, cnts, overflow = bucket_segments(
                 env, bucket, glen, pcap, seg, ndev, names)
@@ -184,23 +202,28 @@ class DistributedAgg:
             flat = ndev * seg
             env2, tot = compact_segments(recv_d, recv_v, recv_c, seg,
                                          ndev, names)
-            fenv, flen, fsel, fschema = _trace_program(
-                final_prog, list(schema.columns), flat, env2, tot, params)
-            if fsel is not None:
-                fcap = next(iter(fenv.values()))[0].shape[0] if fenv else flat
-                fenv, flen = compress(fenv, flen, fsel, fcap)
+            with jax.named_scope("merge"):
+                fenv, flen, fsel, fschema = _trace_program(
+                    final_prog, list(schema.columns), flat, env2, tot,
+                    params)
+                if fsel is not None:
+                    fcap = next(iter(fenv.values()))[0].shape[0] \
+                        if fenv else flat
+                    fenv, flen = compress(fenv, flen, fsel, fcap)
             out_d = {n: fenv[n][0] for n in fschema.names}
             out_v = {n: (fenv[n][1] if fenv[n][1] is not None
                          else jnp.ones_like(out_d[n], dtype=jnp.bool_))
                      for n in fschema.names}
-            return out_d, out_v, flen, overflow, tuple(
+            return out_d, out_v, flen, overflow, cnts.sum(), tuple(
                 (c.name, c.dtype.kind.value, c.dtype.nullable)
                 for c in fschema.columns)
 
-        out_schema_holder = {}
+        # filled at trace time: the out schema, and the exchange's
+        # (segment rows, bytes a row) for the byte counters
+        out_schema_holder = wire = {}
 
         def wrapper(arrays, valids, lengths, params):
-            out_d, out_v, flen, overflow, out_sig = per_device(
+            out_d, out_v, flen, overflow, sent, out_sig = per_device(
                 arrays, valids, lengths, params)
             out_schema_holder["sig"] = out_sig
             return (
@@ -208,6 +231,7 @@ class DistributedAgg:
                 {n: x[None] for n, x in out_v.items()},
                 flen[None],
                 overflow[None],
+                sent[None],
             )
 
         pspec_in = (
@@ -217,11 +241,43 @@ class DistributedAgg:
             {n: P() for n in param_names},
         )
         shard_fn = jax.jit(jax.shard_map(
-            wrapper, mesh=self.mesh, in_specs=pspec_in,
-            out_specs=(P(AXIS, None), P(AXIS, None), P(AXIS), P(AXIS)),
+            _named(wrapper, self.name), mesh=self.mesh, in_specs=pspec_in,
+            out_specs=(P(AXIS, None), P(AXIS, None), P(AXIS), P(AXIS),
+                       P(AXIS)),
             check_vma=False,
         ))
         return shard_fn, out_schema_holder
+
+    def _filled(self, sig, build_args: tuple, call_args: tuple):
+        """The compiled program of `sig`, built on a miss and captured
+        through the program inventory (`utils/progstats.capture`: one
+        trace, one compile, `prog/registered`, its cost analysis):
+        -> (fn, holder, fresh)."""
+        entry = self._fns.get(sig)
+        if entry is not None:
+            progstats.record_hit(getattr(entry[0], "key_id", None))
+            return entry + (False,)
+        fn, holder = self._build(*build_args)
+        key = (self.name, self.partial.fingerprint(),
+               self.final.fingerprint(),
+               tuple((c.name, c.dtype.kind.value, c.dtype.nullable)
+                     for c in self.in_schema.columns),
+               self.mesh.devices.size, sig)
+        # the out schema is a product of the trace: never from the store
+        fn = progstats.capture("mesh-merge", key, fn, call_args,
+                               consult_store=False)
+        self._fns[sig] = (fn, holder)
+        return fn, holder, True
+
+    def _book_exchange(self, holder: dict, sent) -> None:
+        """`mesh/exchange_bytes/merge` and its live part: `sent` is the
+        rows each device put into segments (its groups after the local
+        combine), read in a transfer the caller makes anyway; of a hash
+        over the group keys (ndev-1)/ndev of them leave their device."""
+        seg, row_bytes = holder["shape"]
+        ndev = self.mesh.devices.size
+        record_exchange_bytes("merge", ndev, seg, row_bytes,
+                              int(sent.sum()) * (ndev - 1) // ndev)
 
     # -- run ---------------------------------------------------------------
 
@@ -257,21 +313,18 @@ class DistributedAgg:
         # and this instance can outlive a knob flip (tests construct
         # DistributedAgg directly; the executor's outer cache already
         # keys on the tuning, this inner cache must agree)
-        sig = (cap, tuple(sorted(valid_names)), tuple(sorted(params)),
-               self.seg_rows, groupby_tuning())
-        entry = self._fns.get(sig)
-        if entry is None:
-            entry = self._build(cap, tuple(sorted(valid_names)),
-                                tuple(sorted(params)))
-            self._fns[sig] = entry
-        fn, holder = entry
-
+        sig = ("host", cap, tuple(sorted(valid_names)),
+               tuple(sorted(params)), self.seg_rows, groupby_tuning())
         dev_params = {k: jnp.asarray(v) for k, v in params.items()}
-        out_d, out_v, flens, overflow = fn(arrays, valids, lengths,
-                                           dev_params)
+        fn, holder, _fresh = self._filled(
+            sig, (cap, tuple(sorted(valid_names)), tuple(sorted(params))),
+            (arrays, valids, lengths, dev_params))
+        out_d, out_v, flens, overflow, sent = fn(arrays, valids, lengths,
+                                                 dev_params)
         # ONE batched device_get for the overflow verdict (was a
         # per-flag np.asarray sync — a baselined host-sync debt)
-        if jax.device_get(overflow).any():
+        overflow, sent = jax.device_get((overflow, sent))
+        if overflow.any():
             # overflowed rows were clamped on device, so that result is
             # partial — discard it, rebuild with full-capacity segments
             # (seg = pcap ≥ any per-bucket count: cannot overflow) and rerun
@@ -279,6 +332,7 @@ class DistributedAgg:
             self.seg_rows = 0
             return self.run(blocks_per_device, params)
         self._holder = holder
+        self._book_exchange(holder, sent)
         # padding-waste account of the shuffle's fixed-capacity segments
         from ydb_tpu.parallel.collective import segment_pad_account
         segment_pad_account(
@@ -294,7 +348,8 @@ class DistributedAgg:
         return self._finish(out_d, out_v, flens, dicts)
 
     def run_device_blocks(self, per_dev_blocks: list,
-                          params: Optional[dict] = None) -> HostBlock:
+                          params: Optional[dict] = None,
+                          await_device=None) -> HostBlock:
         """Distributed merge over ALREADY device-resident partials.
 
         ``per_dev_blocks[d]`` is a list of DeviceBlocks committed to mesh
@@ -303,6 +358,11 @@ class DistributedAgg:
         compress, jit'd on that device), the fused buffers are assembled
         into one globally-sharded array — no host round-trip — and the
         shard-mapped shuffle+merge runs over it.
+
+        `await_device(outputs, t_enqueued, program key id, fresh)`: the
+        executor's accounting of the wait for the devices
+        (`Executor._await_device`); without it the wait falls into the
+        first transfer below.
         """
         ndev = self.mesh.devices.size
         assert len(per_dev_blocks) == ndev
@@ -310,9 +370,7 @@ class DistributedAgg:
             "every device needs at least one (possibly empty) partial block"
         params = params or {}
         names = tuple(self.in_schema.names)
-        total_caps = [sum(b.capacity for b in blks)
-                      for blks in per_dev_blocks]
-        pcap = bucket_capacity(max(total_caps), minimum=128)
+        pcap = live_capacity(per_dev_blocks)
         fused = []
         for blks in per_dev_blocks:
             blocks_in = tuple((b.arrays, b.valids, b.length) for b in blks)
@@ -330,27 +388,28 @@ class DistributedAgg:
         lengths = jax.make_array_from_single_device_arrays(
             (ndev,), sh1, [fused[d][2][None] for d in range(ndev)])
 
-        sig = (pcap, tuple(sorted(names)), tuple(sorted(params)),
+        sig = ("device", pcap, tuple(sorted(names)), tuple(sorted(params)),
                self.seg_rows, groupby_tuning())
-        entry = self._fns.get(sig)
-        if entry is None:
-            entry = self._build(pcap, tuple(sorted(names)),
-                                tuple(sorted(params)))
-            self._fns[sig] = entry
-        fn, self._holder = entry
         dev_params = {k: jnp.asarray(v) for k, v in params.items()}
-        out_d, out_v, flens, overflow = fn(arrays, valids, lengths,
-                                           dev_params)
+        fn, self._holder, fresh = self._filled(
+            sig, (pcap, tuple(sorted(names)), tuple(sorted(params))),
+            (arrays, valids, lengths, dev_params))
+        out_d, out_v, flens, overflow, sent = fn(arrays, valids, lengths,
+                                                 dev_params)
+        if await_device is not None:
+            await_device((out_d, out_v, flens), time.perf_counter(),
+                         getattr(fn, "key_id", None), fresh)
         # seg_rows here is 0 (full capacity) or a PROVEN merge-GroupBy
         # bound (each producer's partial holds ≤ out_bound groups, so a
         # bound-bucket segment cannot overflow) — either way overflow is
         # impossible; keep the invariant checked LOUDLY (an understated
         # bound must crash, never silently clamp rows). Batched
         # device_get, not a per-flag np.asarray sync.
-        over, in_rows = jax.device_get((overflow, lengths))
+        over, in_rows, sent = jax.device_get((overflow, lengths, sent))
         assert not over.any(), \
             "proven segment bound overflowed — bound source is wrong"
         record_exchange_rows("merge", lengths, in_rows)
+        self._book_exchange(self._holder, sent)
         # NO pad record here: the partials' live row counts are
         # device-resident scalars, and the ledger must never force a
         # sync to measure — the host-input `run` path carries the gauge

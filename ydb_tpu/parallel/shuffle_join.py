@@ -14,9 +14,22 @@ exchanges segments via `jax.lax.all_to_all` over ICI, compacts, probes
 the LOCAL build partition with a vectorized searchsorted, and runs the
 rest of the pipeline (post-join programs + partial aggregation) without
 leaving the device.
+
+The segments are sized from COUNTED rows: each device counts its live
+rows per target with the program's own bucket function, the host reads
+the ndev x ndev counts (where it read the row counts before), a segment
+holds the largest of them and the buffer after the exchange the most rows
+any device receives, each rounded up a power of two. Segments of the whole
+capacity made everything after the exchange work on ndev times the probe
+side's capacity (8 Mi slots a chip for 0.8 M live rows of TPC-H Q3 at
+SF1, and the partials' merge on 32 Mi: 52 s of device a statement,
+PERF.md round 28).
 """
 
 from __future__ import annotations
+
+import time
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -28,13 +41,16 @@ from ydb_tpu.core.dtypes import DType, Kind
 from ydb_tpu.core.schema import Column, Schema
 from ydb_tpu.ops import ir
 from ydb_tpu.ops.device import DeviceBlock, bucket_capacity
+from ydb_tpu.ops.fused import _named, mesh_program_name
 from ydb_tpu.ops.join import _select_and_gather, build as build_table
 from ydb_tpu.ops.xla_exec import _trace_program, compress, groupby_tuning
 from ydb_tpu.parallel.collective import (AXIS, bucket_of, bucket_segments,
                                          compact_segments,
-                                         exchange_segments)
-from ydb_tpu.parallel.shuffle import (_fuse_device_blocks,
+                                         exchange_segments,
+                                         record_exchange_bytes)
+from ydb_tpu.parallel.shuffle import (_fuse_device_blocks, live_capacity,
                                       record_exchange_rows)
+from ydb_tpu.utils import progstats
 from ydb_tpu.utils.hashing import splitmix64
 
 
@@ -90,12 +106,23 @@ def partition_build(built: HostBlock, key: str, payload: list, ndev: int):
             tables[0].schema if tables else Schema([]), dicts, cap)
 
 
+@partial(jax.jit, static_argnames=("ndev",))
+def _target_counts(key, valid, length, ndev):
+    """[ndev] live rows of one device's fused probe buffer per target
+    device: the exchange program's own `bucket_of` over the same key
+    column, so a segment sized from these counts cannot overflow."""
+    bucket = bucket_of({"k": (key, valid)}, ["k"], ndev)
+    active = jnp.arange(key.shape[0], dtype=jnp.int32) < length
+    return jnp.stack([jnp.sum(active & (bucket == t), dtype=jnp.int32)
+                      for t in range(ndev)])
+
+
 class ShuffleJoin:
     """Compiled probe-row exchange + local probe + post-join pipeline."""
 
     def __init__(self, mesh, in_schema: Schema, probe_key: str, kind: str,
                  payload_cols: list, mark_col: str, not_in: bool,
-                 rest_programs: list, partial):
+                 rest_programs: list, partial, table: str = ""):
         self.mesh = mesh
         self.in_schema = in_schema
         self.probe_key = probe_key
@@ -106,9 +133,18 @@ class ShuffleJoin:
         self.rest_programs = rest_programs     # [ir.Program] after the join
         self.partial = partial                 # ir.Program | None
         self._fns: dict = {}
+        self.name = mesh_program_name(
+            "sj", table, list(rest_programs) + [partial],
+            [f"join {probe_key} {kind} "
+             f"{','.join(c.name for c in payload_cols)}",
+             ",".join(in_schema.names)])
 
-    def _build(self, pcap: int, bcap: int, payload_names: tuple,
-               pvalid_names: tuple, param_names: tuple):
+    def _build(self, pcap: int, seg: int, rcap: int, bcap: int,
+               payload_names: tuple, pvalid_names: tuple,
+               param_names: tuple):
+        """The program for `pcap` probe slots a device, segments of `seg`
+        rows, `rcap` slots after the exchange (both from counted rows:
+        neither can overflow) and build partitions of `bcap` keys."""
         ndev = self.mesh.devices.size
         in_cols = list(self.in_schema.columns)
         names = [c.name for c in in_cols]
@@ -124,62 +160,66 @@ class ShuffleJoin:
             glen = length[0]
             # --- route probe rows to their key's owner (ICI all_to_all;
             # shared segment machinery — parallel/collective.py).
-            # seg = pcap: full-capacity segments cannot overflow
+            # `seg` holds the largest COUNTED bucket: cannot overflow
             bucket = bucket_of(env, [probe_key], ndev)
             stacked_d, stacked_v, cnts, _ovf = bucket_segments(
-                env, bucket, glen, pcap, pcap, ndev, names)
+                env, bucket, glen, pcap, seg, ndev, names)
             recv_d, recv_v, recv_c = exchange_segments(
                 stacked_d, stacked_v, cnts, names)
-            flat = ndev * pcap
-            env2, tot = compact_segments(recv_d, recv_v, recv_c, pcap,
-                                         ndev, names)
+            flat = min(rcap, ndev * seg)
+            env2, tot = compact_segments(recv_d, recv_v, recv_c, seg,
+                                         ndev, names, out_cap=flat)
 
             # --- probe the LOCAL build partition (vectorized binsearch)
-            d, v = env2[probe_key]
-            enc = d.astype(jnp.int64)
-            iota2 = jnp.arange(flat, dtype=jnp.int32)
-            act2 = iota2 < tot
-            matchable = act2 if v is None else (act2 & v)
-            keys_local = bkeys[0]
-            n_local = bns[0]
-            pos = jnp.searchsorted(keys_local, enc).astype(jnp.int32)
-            safe = jnp.clip(pos, 0, bcap - 1)
-            found = (keys_local[safe] == enc) & matchable \
-                & (safe < n_local)
-            payload_local = {n: bpay[n][0] for n in payload_names}
-            pvalid_local = {n: bpv[n][0] for n in pvalid_names}
-            out_sel, gathered, gathered_valid = _select_and_gather(
-                found, safe, act2, v, n_local, kind, not_in,
-                payload_local, pvalid_local, payload_names)
+            with jax.named_scope("shuffle.probe"):
+                d, v = env2[probe_key]
+                enc = d.astype(jnp.int64)
+                iota2 = jnp.arange(flat, dtype=jnp.int32)
+                act2 = iota2 < tot
+                matchable = act2 if v is None else (act2 & v)
+                keys_local = bkeys[0]
+                n_local = bns[0]
+                pos = jnp.searchsorted(keys_local, enc).astype(jnp.int32)
+                safe = jnp.clip(pos, 0, bcap - 1)
+                found = (keys_local[safe] == enc) & matchable \
+                    & (safe < n_local)
+                payload_local = {n: bpay[n][0] for n in payload_names}
+                pvalid_local = {n: bpv[n][0] for n in pvalid_names}
+                out_sel, gathered, gathered_valid = _select_and_gather(
+                    found, safe, act2, v, n_local, kind, not_in,
+                    payload_local, pvalid_local, payload_names)
 
-            schema = Schema(list(in_cols))
-            for c in payload_cols:
-                if c.name == mark_col:
-                    env2[c.name] = (found, None)
-                elif c.name in gathered:
-                    env2[c.name] = (gathered[c.name],
-                                    gathered_valid[c.name])
-                schema = Schema([x for x in schema.columns
-                                 if x.name != c.name] + [c])
-            if kind != "mark":
-                env2, tot = compress(env2, tot, out_sel, flat)
+                schema = Schema(list(in_cols))
+                for c in payload_cols:
+                    if c.name == mark_col:
+                        env2[c.name] = (found, None)
+                    elif c.name in gathered:
+                        env2[c.name] = (gathered[c.name],
+                                        gathered_valid[c.name])
+                    schema = Schema([x for x in schema.columns
+                                     if x.name != c.name] + [c])
+                if kind != "mark":
+                    env2, tot = compress(env2, tot, out_sel, flat)
 
             # --- rest of the pipeline + partial, all on-device
             cap2 = flat
             sel = None
-            for prog in rest:
-                env2, tot, sel, schema = _trace_program(
-                    prog, schema.columns, cap2, env2, tot, params, sel=sel)
-                if env2:
-                    cap2 = next(iter(env2.values()))[0].shape[0]
-            if partial is not None:
-                env2, tot, sel, schema = _trace_program(
-                    partial, schema.columns, cap2, env2, tot, params,
-                    sel=sel)
-                if env2:
-                    cap2 = next(iter(env2.values()))[0].shape[0]
-            if sel is not None:
-                env2, tot = compress(env2, tot, sel, cap2)
+            with jax.named_scope("rest"):
+                for prog in rest:
+                    env2, tot, sel, schema = _trace_program(
+                        prog, schema.columns, cap2, env2, tot, params,
+                        sel=sel)
+                    if env2:
+                        cap2 = next(iter(env2.values()))[0].shape[0]
+            with jax.named_scope("partial"):
+                if partial is not None:
+                    env2, tot, sel, schema = _trace_program(
+                        partial, schema.columns, cap2, env2, tot, params,
+                        sel=sel)
+                    if env2:
+                        cap2 = next(iter(env2.values()))[0].shape[0]
+                if sel is not None:
+                    env2, tot = compress(env2, tot, sel, cap2)
             out_d = {n: env2[n][0] for n in schema.names}
             out_v = {n: (env2[n][1] if env2[n][1] is not None
                          else jnp.ones_like(out_d[n], dtype=jnp.bool_))
@@ -208,20 +248,37 @@ class ShuffleJoin:
             {n: P() for n in param_names},
         )
         fn = jax.jit(jax.shard_map(
-            wrapper, mesh=self.mesh, in_specs=pspec_in,
+            _named(wrapper, self.name), mesh=self.mesh, in_specs=pspec_in,
             out_specs=(P(AXIS, None), P(AXIS, None), P(AXIS)),
             check_vma=False))
         return fn, holder
 
+    def identity(self) -> tuple:
+        """What tells this join from another: the executor's cache key,
+        and with a run's shapes a program's id in the inventory (its
+        name holds shape only)."""
+        sig = lambda cols: tuple(                         # noqa: E731
+            (c.name, c.dtype.kind.value, c.dtype.nullable) for c in cols)
+        return (sig(self.in_schema.columns), self.probe_key, self.kind,
+                sig(self.payload_cols), self.mark_col, self.not_in,
+                self.mesh.devices.size,
+                tuple(p.fingerprint() for p in self.rest_programs),
+                self.partial.fingerprint() if self.partial else "")
+
     def run(self, per_dev_blocks: list, build_arrays: dict, bcap: int,
-            params: dict, dicts: dict) -> list:
+            params: dict, dicts: dict, await_device=None,
+            min_segment_rows: int = 128) -> list:
         """per_dev_blocks[d]: stage-A DeviceBlocks on device d. Returns one
-        post-join (post-partial) DeviceBlock per device."""
+        post-join (post-partial) DeviceBlock per device.
+
+        `await_device(outputs, t_enqueued, program key id, fresh)`: the
+        executor's accounting of the wait for the devices
+        (`Executor._await_device`); without it nothing here waits.
+        `min_segment_rows`: the floor of a counted segment
+        (`Executor.mesh_min_segment_rows`)."""
         ndev = self.mesh.devices.size
         names = tuple(self.in_schema.names)
-        total_caps = [sum(b.capacity for b in blks)
-                      for blks in per_dev_blocks]
-        pcap = bucket_capacity(max(total_caps), minimum=128)
+        pcap = live_capacity(per_dev_blocks)
         fused = []
         for blks in per_dev_blocks:
             blocks_in = tuple((b.arrays, b.valids, b.length) for b in blks)
@@ -237,6 +294,16 @@ class ShuffleJoin:
             for n in names}
         lengths = jax.make_array_from_single_device_arrays(
             (ndev,), sh1, [fused[d][2][None] for d in range(ndev)])
+        # rows per (source, target): the one transfer before the exchange
+        # (it waits for the scan partials, as the row counts' did)
+        counts = np.stack(jax.device_get(
+            [_target_counts(fused[d][0][self.probe_key],
+                            fused[d][1][self.probe_key], fused[d][2],
+                            ndev=ndev) for d in range(ndev)]))
+        seg = min(pcap, bucket_capacity(max(int(counts.max()), 1),
+                                        minimum=min_segment_rows))
+        rcap = bucket_capacity(max(int(counts.sum(axis=0).max()), 1),
+                               minimum=min_segment_rows)
 
         bkeys = jax.device_put(build_arrays["keys"], sh2)
         bns = jax.device_put(build_arrays["ns"], sh1)
@@ -250,21 +317,37 @@ class ShuffleJoin:
         # groupby_tuning: _build traces rest_programs/partial (GroupBy
         # lowerings read the tile/batch/legacy knobs) — same identity
         # rule as every other compiled-program cache key
-        key = (pcap, bcap, payload_names, pvalid_names,
+        key = (pcap, seg, rcap, bcap, payload_names, pvalid_names,
                tuple(sorted(params)), groupby_tuning())
-        entry = self._fns.get(key)
-        if entry is None:
-            entry = self._build(pcap, bcap, payload_names, pvalid_names,
-                                tuple(sorted(params)))
-            self._fns[key] = entry
-        fn, holder = entry
         dev_params = {k: jnp.asarray(v) for k, v in params.items()}
-        out_d, out_v, lens = fn(arrays, valids, lengths, bkeys, bns, bpay,
-                                bpv, dev_params)
-        # after the dispatch: the host waits for the scan partials only,
-        # the devices already hold the exchange to run
-        record_exchange_rows("shuffle-join", lengths,
-                             jax.device_get(lengths))
+        args = (arrays, valids, lengths, bkeys, bns, bpay, bpv, dev_params)
+        entry = self._fns.get(key)
+        fresh = entry is None
+        if fresh:
+            fn, holder = self._build(pcap, seg, rcap, bcap, payload_names,
+                                     pvalid_names, tuple(sorted(params)))
+            # captured through the program inventory: one trace, one
+            # compile, `prog/registered`, its cost analysis. The out
+            # schema is a product of the trace: never from the store
+            fn = progstats.capture(
+                "mesh-sj", (self.name, self.identity(), key), fn, args,
+                consult_store=False)
+            entry = self._fns[key] = (fn, holder)
+        else:
+            progstats.record_hit(getattr(entry[0], "key_id", None))
+        fn, holder = entry
+        out_d, out_v, lens = fn(*args)
+        t_enqueued = time.perf_counter()
+        record_exchange_rows("shuffle-join", lengths, counts.sum(axis=1))
+        # every column travels with its validity plane; the diagonal of
+        # `counts` stays on its device
+        record_exchange_bytes(
+            "shuffle-join", ndev, seg,
+            sum(arrays[n].dtype.itemsize + 1 for n in names),
+            int(counts.sum() - np.trace(counts)))
+        if await_device is not None:
+            await_device((out_d, out_v, lens), t_enqueued,
+                         getattr(fn, "key_id", None), fresh)
         out_cols = [Column(n, DType(Kind(k), nullable))
                     for (n, k, nullable) in holder["sig"]]
         schema = Schema(out_cols)

@@ -1046,8 +1046,9 @@ class QueryEngine:
         stats.execute_ms = t.lap()
         stats.total_ms = stats.parse_ms + stats.plan_ms + stats.execute_ms
         stats.rows_out = block.length
-        stats.fused = self.executor.last_path == "fused"
-        stats.distributed = self.executor.last_path == "distributed"
+        stats.path = self.executor.last_path
+        stats.fused = stats.path == "fused"
+        stats.distributed = stats.path.startswith("distributed")
         stats.view_serving = getattr(self._view_tls, "notes", None) or []
         delta = groupby_trace_delta(getattr(stats, "_gb_mark", {}))
         # the bounds-lattice gauges ride the same trace window under a

@@ -128,6 +128,12 @@ class Executor:
         # devices (shuffle join) instead of replicating to every device
         self.dist_broadcast_budget_bytes = int(
             _os.environ.get("YDB_TPU_DIST_BROADCAST_BUDGET", 256 << 20))
+        # mesh exchanges (the shuffle join's probe rows, the partials'
+        # merge): a segment is sized from the rows COUNTED or PROVEN for
+        # it, rounded up a power of two and never under this many rows
+        # (the floor bounds the shapes, so the compiles, of small
+        # exchanges)
+        self.mesh_min_segment_rows = 128
         # fused-program complexity cap: plans with more join steps than
         # this stream portioned — a 7-join whole-query program has been
         # observed to SIGSEGV the platform's TPU compiler service
@@ -239,6 +245,24 @@ class Executor:
         GLOBAL.inc("prog/queue_ms", queue_ms)
         progstats.record_exec(prog_kid, run_ms, fresh=fresh)
 
+    def _await_stage(self, blocks: list, t_enqueued: float) -> None:
+        """The wait for a mesh lane's per-device prefix programs, which
+        run on every device at once: ONE `device-execute` for the stage,
+        enqueued when its first program was. They are the per-block
+        programs of `ops/xla_exec.ProgramCache`, many to a stage, so the
+        run is joined to no single program's inventory entry."""
+        self._await_device([(b.arrays, b.valids, b.length) for b in blocks],
+                           t_enqueued, None, False)
+
+    def _took_mesh_lane(self, lane: str) -> None:
+        """A statement finished on a mesh lane: `last_path` (set last, a
+        build side's own statement has set it before) and the lane's
+        counter."""
+        from ydb_tpu.utils.metrics import GLOBAL
+        self.last_path = lane
+        # lint: allow-counters(mesh/statements/* registered)
+        GLOBAL.inc(f"mesh/statements/{lane}")
+
     # -- cache warmup ------------------------------------------------------
 
     def prewarm(self, tables=None, snapshot: Snapshot = MAX_SNAPSHOT) -> int:
@@ -317,18 +341,18 @@ class Executor:
                 sj = self._try_execute_shuffle_join(plan, params, snapshot,
                                                     prebuilt)
                 if sj is not None:
-                    self.last_path = "distributed-shuffle-join"
+                    self._took_mesh_lane("distributed-shuffle-join")
                     return DeviceResultFuture.completed(
                         self._project_output(sj, plan.output))
-                self.last_path = "distributed"
                 merged = self._execute_distributed(plan, params, snapshot,
                                                    prebuilt)
+                self._took_mesh_lane("distributed")
                 return DeviceResultFuture.completed(
                     self._project_output(merged, plan.output))
             if self._can_distribute_map(plan, snapshot):
-                self.last_path = "distributed-map"
                 merged = self._execute_distributed_map(plan, params,
                                                        snapshot)
+                self._took_mesh_lane("distributed-map")
                 return DeviceResultFuture.completed(
                     self._project_output(merged, plan.output))
 
@@ -1807,19 +1831,30 @@ class Executor:
 
         Guarded by `_can_distribute_map` (>1 scan source), so at least two
         per-device results always arrive."""
+        import time as _time
         nsrc = self._scan_source_count(plan, snapshot)
         # no point replicating builds onto devices that get no blocks
         devs = list(self.mesh.devices.flat)[:max(2, min(
             self.mesh.devices.size, nsrc))]
-        builds = self._prepare_builds(plan.pipeline, params, snapshot)
-        builds_by_dev = [[J.place(b, d) for b in builds] for d in devs]
-        # dispatch every device's pipeline first; transfers afterwards —
-        # to_host blocks, and fetching inside the loop would serialize the
-        # fan-out this path exists for
-        pending = [self._run_block(plan.pipeline, dblock,
-                                   builds_by_dev[di], params)
-                   for di, dblock in self._scan_device_blocks(
-                       plan.pipeline, snapshot, devices=devs)]
+        with self._span("mesh-stage", ndev=len(devs)):
+            builds = self._prepare_builds(plan.pipeline, params, snapshot)
+            builds_by_dev = [[J.place(b, d) for b in builds] for d in devs]
+            # dispatch every device's pipeline first; transfers afterwards
+            # — to_host blocks, and fetching inside the loop would
+            # serialize the fan-out this path exists for
+            t_enq = _time.perf_counter()
+            pending = [self._run_block(plan.pipeline, dblock,
+                                       builds_by_dev[di], params)
+                       for di, dblock in self._scan_device_blocks(
+                           plan.pipeline, snapshot, devices=devs)]
+            self._await_stage(pending, t_enq)
+        with self._span("mesh-merge"):
+            return self._union_distributed_map(plan, pending, params)
+
+    def _union_distributed_map(self, plan: QueryPlan, pending: list,
+                               params: dict) -> HostBlock:
+        """Tail of the map lane: the per-device results unioned on the
+        host, the final stage on one device."""
         lim = None if plan.limit is None else plan.limit + (plan.offset or 0)
         if plan.sort and lim is not None and lim <= (1 << 17):
             # sort-limit queries: per-device partial top-k BEFORE the
@@ -1842,10 +1877,10 @@ class Executor:
             union = HostBlock.concat(outs) if len(outs) > 1 else outs[0]
             plan_merge = dataclasses.replace(
                 plan, final_program=None, output=plan.output + extra)
-            return self._finalize(plan_merge, [to_device(union)], params)
+            return self._finalize_read(plan_merge, union, params)
         outs = [to_host(d) for d in pending]
         union = HostBlock.concat(outs) if len(outs) > 1 else outs[0]
-        return self._finalize(plan, [to_device(union)], params)
+        return self._finalize_read(plan, union, params)
 
     def _execute_distributed(self, plan: QueryPlan, params: dict,
                              snapshot: Snapshot,
@@ -1855,29 +1890,42 @@ class Executor:
         device, hash-shuffle the partials over the mesh, merge, then run
         the remaining final program + sort/limit single-device (post-agg
         tails are small)."""
-        pipe = plan.pipeline
-        devs = list(self.mesh.devices.flat)
-        ndev = len(devs)
-        builds = self._prepare_builds(pipe, params, snapshot,
-                                      prebuilt=prebuilt)
-        builds_by_dev = [[J.place(b, d) for b in builds] for d in devs]
-
-        per_dev = [[] for _ in range(ndev)]
-        for di, dblock in self._scan_device_blocks(pipe, snapshot,
-                                                   devices=devs):
-            per_dev[di].append(
-                self._run_block(pipe, dblock, builds_by_dev[di], params))
-        for di in range(ndev):
-            if not per_dev[di]:
-                empty = to_device(self._empty_scan_block(pipe),
-                                  device=devs[di])
-                per_dev[di].append(
-                    self._run_block(pipe, empty, builds_by_dev[di], params))
-
+        per_dev = self._run_mesh_stage(plan.pipeline, params, snapshot,
+                                       prebuilt=prebuilt)
         # merge GroupBy runs twice (pre-shuffle local combine + post-shuffle
         # final merge) — merge aggregation is associative, so this is the
         # BlockCombineHashed → BlockMergeFinalizeHashed split
         return self._merge_distributed_partials(plan, per_dev, params)
+
+    def _run_mesh_stage(self, pipe: Pipeline, params: dict,
+                        snapshot: Snapshot, until: Optional[int] = None,
+                        prebuilt: Optional[dict] = None) -> list:
+        """The `mesh-stage` span: broadcast the builds of the joins among
+        steps[:until] (all, if None) to every mesh device, run each scan
+        block through those steps on the device that holds it (and, with
+        no `until`, the partial aggregation), and wait for the devices.
+        Returns the output DeviceBlocks per device, at least one each."""
+        import time as _time
+        devs = list(self.mesh.devices.flat)
+        with self._span("mesh-stage", ndev=len(devs)):
+            builds = self._prepare_builds(pipe, params, snapshot,
+                                          until=until, prebuilt=prebuilt)
+            builds_by_dev = [[J.place(b, d) for b in builds] for d in devs]
+            per_dev = [[] for _ in devs]
+            t_enq = _time.perf_counter()
+            for di, dblock in self._scan_device_blocks(pipe, snapshot,
+                                                       devices=devs):
+                per_dev[di].extend(self._run_block_multi(
+                    pipe, dblock, builds_by_dev[di], params, until=until))
+            for di, dev in enumerate(devs):
+                if not per_dev[di]:
+                    empty = to_device(self._empty_scan_block(pipe),
+                                      device=dev)
+                    per_dev[di].extend(self._run_block_multi(
+                        pipe, empty, builds_by_dev[di], params,
+                        until=until))
+            self._await_stage([b for blks in per_dev for b in blks], t_enq)
+        return per_dev
 
     # -- distributed shuffle join ------------------------------------------
 
@@ -1921,9 +1969,75 @@ class Executor:
         if est <= self.dist_broadcast_budget_bytes:
             return None
 
-        # materialize the build side (host) and check key shape; every
-        # decline below hands the block to the broadcast path via
-        # `prebuilt` so it is never executed twice
+        from ydb_tpu.parallel import shuffle_join as SJ
+        from ydb_tpu.utils.metrics import GLOBAL
+        devs = list(self.mesh.devices.flat)
+        ndev = len(devs)
+        with self._span("shuffle-join", ndev=ndev) as lane:
+            with self._span("mesh-build"):
+                parts = self._partition_shuffle_build(
+                    pipe, j, step, params, snapshot, prebuilt, ndev)
+            if parts is None:
+                lane.attrs["declined"] = True
+                return None
+            barrays, pschema, bdicts, bcap, build_rows = parts
+            lane.attrs["build_rows"] = build_rows
+            # stage A: pipeline prefix per device (earlier joins broadcast)
+            per_dev = self._run_mesh_stage(pipe, params, snapshot, until=j)
+
+            with self._span("mesh-exchange"):
+                in_schema = per_dev[0][0].schema
+                payload_cols = []
+                for name in pschema.names:
+                    payload_cols.append(
+                        Column(name, pschema.dtype(name).with_nullable(True)))
+                if step.kind == "mark":
+                    payload_cols.append(Column(step.mark_col or "__mark",
+                                               DType(_K.BOOL, False)))
+                rest = [s for (k, s) in pipe.steps[j + 1:]]
+                # cheap to make (it compiles on its first run): an equal
+                # one made before holds the compiled programs.
+                # groupby_tuning in the key: the ShuffleJoin traces `rest`
+                # and `pipe.partial` (GroupBy lowerings read the tile/
+                # batch/legacy levers at trace time) — a knob flip must
+                # build a fresh join, not reuse a program tiled under old
+                # settings
+                sj = SJ.ShuffleJoin(self.mesh, in_schema, step.probe_key,
+                                    step.kind, payload_cols,
+                                    step.mark_col or "__mark", step.not_in,
+                                    rest, pipe.partial,
+                                    table=pipe.scan.table)
+                key = (sj.identity(), groupby_tuning())
+                cached = self._shuffle_joins.get(key)
+                if cached is None:
+                    self._shuffle_joins[key] = sj
+                else:
+                    sj = cached
+                dicts = {}
+                for blks in per_dev:
+                    for b in blks:
+                        dicts.update(b.dictionaries)
+                dicts.update(bdicts)
+                post_blocks = sj.run(
+                    per_dev, barrays, bcap, params, dicts,
+                    await_device=self._await_device,
+                    min_segment_rows=self.mesh_min_segment_rows)
+
+            GLOBAL.inc("executor/shuffle_joins")
+            return self._merge_distributed_partials(
+                plan, [[b] for b in post_blocks], params)
+
+    def _partition_shuffle_build(self, pipe: Pipeline, j: int, step,
+                                 params: dict, snapshot: Snapshot,
+                                 prebuilt: Optional[dict], ndev: int):
+        """The `mesh-build` step of the shuffle join: materialize the
+        build side of step `j` on the HOST, check its key's shape, and
+        hash-partition it `ndev` ways (`shuffle_join.partition_build`).
+        Returns (stacked arrays, payload schema, dictionaries, partition
+        capacity, build rows), or None where the exchange does not cover
+        the shape; every decline hands the block to the broadcast path
+        via `prebuilt` so it is never executed twice."""
+        from ydb_tpu.parallel import shuffle_join as SJ
         if isinstance(step.build, QueryPlan):
             built = self.execute(step.build, snapshot)
         else:
@@ -1984,77 +2098,21 @@ class Executor:
             enc = built.columns[step.build_key].data
             if len(enc) > 1 and len(np.unique(enc)) != len(enc):
                 return None
-        from ydb_tpu.parallel import shuffle_join as SJ
-        devs = list(self.mesh.devices.flat)
-        ndev = len(devs)
         barrays, pschema, bdicts, bcap = SJ.partition_build(
             built, step.build_key, list(step.payload), ndev)
         if not pschema.names and step.payload:
             return None
-
-        with self._span("shuffle-join", ndev=ndev, build_rows=built.length):
-            # stage A: pipeline prefix per device (earlier joins broadcast)
-            prefix_builds = self._prepare_builds(pipe, params, snapshot,
-                                                 until=j)
-            builds_by_dev = [[J.place(b, d) for b in prefix_builds]
-                             for d in devs]
-            per_dev = [[] for _ in range(ndev)]
-            for di, dblock in self._scan_device_blocks(pipe, snapshot,
-                                                       devices=devs):
-                per_dev[di].extend(self._run_block_multi(
-                    pipe, dblock, builds_by_dev[di], params, until=j))
-            for di in range(ndev):
-                if not per_dev[di]:
-                    empty = to_device(self._empty_scan_block(pipe),
-                                      device=devs[di])
-                    per_dev[di].extend(self._run_block_multi(
-                        pipe, empty, builds_by_dev[di], params, until=j))
-
-            in_schema = per_dev[0][0].schema
-            payload_cols = []
-            for name in pschema.names:
-                payload_cols.append(
-                    Column(name, pschema.dtype(name).with_nullable(True)))
-            if step.kind == "mark":
-                payload_cols.append(Column(step.mark_col or "__mark",
-                                           DType(_K.BOOL, False)))
-            rest = [s for (k, s) in pipe.steps[j + 1:]]
-            # groupby_tuning in the key: the ShuffleJoin traces `rest`
-            # and `pipe.partial` (GroupBy lowerings read the tile/batch/
-            # legacy levers at trace time) — a knob flip must build a
-            # fresh join, not reuse a program tiled under old settings
-            key = (tuple((c.name, c.dtype.kind.value, c.dtype.nullable)
-                         for c in in_schema.columns),
-                   step.probe_key, step.kind,
-                   tuple((c.name, c.dtype.kind.value, c.dtype.nullable)
-                         for c in payload_cols),
-                   ndev,
-                   tuple(p.fingerprint() for p in rest),
-                   pipe.partial.fingerprint() if pipe.partial else "",
-                   groupby_tuning())
-            sj = self._shuffle_joins.get(key)
-            if sj is None:
-                sj = SJ.ShuffleJoin(self.mesh, in_schema, step.probe_key,
-                                    step.kind, payload_cols,
-                                    step.mark_col or "__mark", step.not_in,
-                                    rest, pipe.partial)
-                self._shuffle_joins[key] = sj
-            dicts = {}
-            for blks in per_dev:
-                for b in blks:
-                    dicts.update(b.dictionaries)
-            dicts.update(bdicts)
-            post_blocks = sj.run(per_dev, barrays, bcap, params, dicts)
-
-        from ydb_tpu.utils.metrics import GLOBAL
-        GLOBAL.inc("executor/shuffle_joins")
-        return self._merge_distributed_partials(plan, [[b] for b in
-                                                       post_blocks], params)
+        return barrays, pschema, bdicts, bcap, built.length
 
     def _merge_distributed_partials(self, plan: QueryPlan, per_dev: list,
                                     params: dict) -> HostBlock:
         """Shared tail of the mesh paths: hash-shuffle merge of per-device
         partial-agg blocks + the rest of the final program."""
+        with self._span("mesh-merge"):
+            return self._merge_partials(plan, per_dev, params)
+
+    def _merge_partials(self, plan: QueryPlan, per_dev: list,
+                        params: dict) -> HostBlock:
         import dataclasses
 
         from ydb_tpu.parallel.shuffle import DistributedAgg
@@ -2071,7 +2129,7 @@ class Executor:
         if gb.out_bound:
             from ydb_tpu.utils.metrics import GLOBAL
             seg_rows = bucket_capacity(max(int(gb.out_bound), 1),
-                                       minimum=128)
+                                       minimum=self.mesh_min_segment_rows)
             GLOBAL.inc("bounds/seg_bounded_shuffles")
         key = (merge_prog.fingerprint(),
                tuple((c.name, c.dtype.kind.value, c.dtype.nullable)
@@ -2080,13 +2138,24 @@ class Executor:
         dag = self._dist_aggs.get(key)
         if dag is None:
             dag = DistributedAgg(merge_prog, merge_prog, in_schema,
-                                 self.mesh, seg_rows=seg_rows)
+                                 self.mesh, seg_rows=seg_rows,
+                                 table=plan.pipeline.scan.table)
             self._dist_aggs[key] = dag
-        merged = dag.run_device_blocks(per_dev, params)
+        merged = dag.run_device_blocks(per_dev, params,
+                                       await_device=self._await_device)
         rest = list(plan.final_program.commands[1:])
         plan2 = dataclasses.replace(
             plan, final_program=ir.Program(rest) if rest else None)
-        return self._finalize(plan2, [to_device(merged)], params)
+        return self._finalize_read(plan2, merged, params)
+
+    def _finalize_read(self, plan: QueryPlan, union: HostBlock,
+                       params: dict) -> HostBlock:
+        """Single-device tail of a mesh lane: the rest of the final
+        program, sort and limit over the merged rows, and the result's
+        read-back under the `readout-transfer` span the fused lane has."""
+        fut = self._finalize(plan, [to_device(union)], params, defer=True)
+        with self._span("readout-transfer"):
+            return fut.result()
 
     # -- pipelines ---------------------------------------------------------
 

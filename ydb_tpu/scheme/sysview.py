@@ -73,8 +73,7 @@ def sysview_block(engine, name: str) -> HostBlock:
             "total_ms": st.total_ms, "parse_ms": st.parse_ms,
             "plan_ms": st.plan_ms, "execute_ms": st.execute_ms,
             "rows_out": int(st.rows_out),
-            "path": ("distributed" if st.distributed
-                     else "fused" if st.fused else "portioned"),
+            "path": st.path or "portioned",
             "cache_hit": bool(st.plan_cache_hit),
         } for st in hist]
         return _block(rows, [("sql", str), ("kind", str),

@@ -71,8 +71,7 @@ def _result_payload(block, stats) -> dict:
             "total_ms": stats.total_ms,
             "rows_out": stats.rows_out,
             "plan_cache_hit": stats.plan_cache_hit,
-            "path": ("distributed" if stats.distributed
-                     else "fused" if stats.fused else "portioned"),
+            "path": stats.path or "portioned",
         } if stats is not None else {},
     }
 

@@ -228,6 +228,17 @@ COUNTER_REGISTRY = {
     "mesh/exchange_rows/*":
         "(dynamic) rows fed to a mesh exchange, by exchange kind and the "
         "device that held them (mesh/exchange_rows/<kind>/dev<id>)",
+    "mesh/exchange_bytes/*":
+        "(dynamic) bytes a mesh exchange put on the wire, by kind "
+        "(shuffle-join | merge): segments x segment rows x summed "
+        "column and validity widths x (ndev-1)/ndev, from shapes",
+    "mesh/exchange_live_bytes/*":
+        "(dynamic) the part of mesh/exchange_bytes/<kind> that was live "
+        "rows bound for another device (shuffle-join: counted; merge: "
+        "(ndev-1)/ndev of the rows sent); the rest is padding",
+    "mesh/statements/*":
+        "(dynamic) statements finished on a mesh lane, by lane "
+        "(distributed | distributed-shuffle-join | distributed-map)",
     "executor/spilled_rows": "rows spilled by the partition store",
     "executor/spilled_bytes": "bytes spilled by the partition store",
     # -- concurrent pipeline ------------------------------------------------
@@ -514,7 +525,11 @@ class QueryStats:
     rows_out: int = 0
     plan_cache_hit: bool = False
     fused: bool = False            # whole-query single-dispatch path
-    distributed: bool = False      # mesh hash-shuffle path
+    distributed: bool = False      # one of the three mesh lanes
+    # the executor's lane by name (`Executor.last_path`): fused |
+    # portioned | distributed | distributed-shuffle-join |
+    # distributed-map | literal | fused-tiled[...]
+    path: str = ""
     tables: list = field(default_factory=list)
     # sorted group-by trace breakdown (tiles/gather_ops/…, the
     # `xla_exec.groupby_trace_delta` window for this statement) —
@@ -531,10 +546,11 @@ class QueryStats:
     batching: dict = field(default_factory=dict)
     # device-timeline attribution (`utils/tracing.phase_breakdown` over
     # this statement's spans): {admission_ms, build_ms, upload_ms,
-    # dispatch_ms, queue_ms, device_ms, readout_ms, compile_ms},
-    # disjoint; device_ms is the program's run, queue_ms the wait behind
-    # another statement's — empty when the statement was unsampled or
-    # never touched the device
+    # dispatch_ms, queue_ms, device_ms, readout_ms, compile_ms}, on a
+    # mesh lane also {mesh_build_ms, stage_ms, exchange_ms, merge_ms}
+    # (the host's own time in each step), disjoint; device_ms is the
+    # programs' run, queue_ms the wait behind another statement's —
+    # empty when the statement was unsampled or never touched the device
     phases: dict = field(default_factory=dict)
     # resource-ledger rollup (`utils/memledger.MemLedger.summary`):
     # peak/alloc device bytes, padding live-vs-padded account, host
@@ -557,9 +573,9 @@ class QueryStats:
     view_serving: list = field(default_factory=list)
 
     def render(self) -> str:
-        path = ("mesh-distributed" if self.distributed
+        path = (f"mesh {self.path or 'distributed'}" if self.distributed
                 else "fused single-dispatch" if self.fused
-                else "portioned")
+                else self.path or "portioned")
         out = (f"-- stats: total {self.total_ms:.1f}ms "
                f"(parse {self.parse_ms:.1f}, plan {self.plan_ms:.1f}"
                f"{' [cache hit]' if self.plan_cache_hit else ''}, "

@@ -5,7 +5,8 @@ phases in `TSpan`s uploaded via OTLP (`ydb/library/actors/wilson/
 wilson_span.h`, `wilson_uploader.cpp`), with per-request sampling decided
 at admission (`ydb/core/jaeger_tracing/`). Here the span tree covers a
 statement's phases (parse → plan → execute, with executor sub-spans for
-build/upload/dispatch/device-execute/readout), and the SAME tree spans
+build/upload/dispatch/device-execute/readout, and on a mesh lane
+mesh-build/mesh-stage/mesh-exchange/mesh-merge), and the SAME tree spans
 processes: a DQ task runner forwards `(trace_id, parent_span_id,
 sampled)` over the `DqRunTask` RPC, workers record their task spans
 against the adopted trace id, and the runner `ingest()`s them back —
@@ -89,6 +90,13 @@ PHASE_SPANS = {
     "device-dispatch-batched": "dispatch_ms",
     "device-execute": "device_ms",
     "readout-transfer": "readout_ms",
+    # the mesh lanes (`query/executor.py`): host-side build partitioning,
+    # the per-device prefix programs, the shard_map program that holds
+    # the probe rows' all_to_all, the partials' merge exchange and tail
+    "mesh-build": "mesh_build_ms",
+    "mesh-stage": "stage_ms",
+    "mesh-exchange": "exchange_ms",
+    "mesh-merge": "merge_ms",
 }
 
 
@@ -108,9 +116,25 @@ def phase_breakdown(spans) -> dict:
     executor splits it where the wait ends (`queue_ms` / `run_ms`
     attrs: behind another statement's program, then this one's own run),
     so `device_ms` is run without wait and `queue_ms + device_ms` is the
-    span. A span without the attrs (an older worker's) counts whole."""
+    span. A span without the attrs (an older worker's) counts whole.
+
+    Phase spans NEST (a `mesh-build` holds the build statement's own
+    dispatch, device wait and read-back; a `join-builds` a nested fused
+    program): a phase is its span's OWN time, the span less the phase
+    spans directly under it, so the sum never counts a millisecond
+    twice and stays within the statement's wall."""
     out: dict = {}
     in_dispatch = 0.0
+    by_id = {s.span_id: s for s in spans}
+    own = {s.span_id: s.dur_ms for s in spans if s.name in PHASE_SPANS}
+    for s in spans:
+        if s.span_id not in own:
+            continue
+        up = by_id.get(s.parent_id)
+        while up is not None and up.span_id not in own:
+            up = by_id.get(up.parent_id)
+        if up is not None:
+            own[up.span_id] -= s.dur_ms
     for s in spans:
         key = PHASE_SPANS.get(s.name)
         if key == "device_ms" and "run_ms" in s.attrs:
@@ -118,7 +142,7 @@ def phase_breakdown(spans) -> dict:
             out["queue_ms"] = out.get("queue_ms", 0.0) \
                 + float(s.attrs.get("queue_ms", 0.0))
         elif key is not None:
-            out[key] = out.get(key, 0.0) + s.dur_ms
+            out[key] = out.get(key, 0.0) + max(0.0, own[s.span_id])
         c = s.attrs.get("compile_ms")
         if c:
             out["compile_ms"] = out.get("compile_ms", 0.0) + float(c)

@@ -329,3 +329,54 @@ def test_batch_explain_analyze_block(monkeypatch):
     df = eng.query("explain analyze select a, b from t where k = 5")
     text = "\n".join(df["plan"])
     assert "batching: coalesced" in text
+
+
+def test_batch_q1_reads_deferred_columns_in_place(monkeypatch):
+    """Two same-shape TPC-H Q1 statements with different DELTA literals
+    through `build_fused_batched_fn` answer as two single statements do,
+    and the vmapped body reads Q1's deferred columns in place (nothing
+    moves a row before the group-by references them): the batched
+    program holds no `latemat[` gather."""
+    import re
+
+    from ydb_tpu.bench.tpch_gen import load_tpch
+    from ydb_tpu.query import QueryEngine
+    from ydb_tpu.utils import progstats
+
+    from tests.tpch_util import QUERIES
+    texts = [QUERIES["q1"], QUERIES["q1"].replace("'90'", "'75'")]
+
+    def engine():
+        e = QueryEngine()
+        load_tpch(e.catalog, sf=0.002)
+        return e
+
+    monkeypatch.setenv("YDB_TPU_BATCH_WINDOW", "0")
+    base = engine()
+    want = [base.query(q) for q in texts]
+    monkeypatch.setenv("YDB_TPU_BATCH_WINDOW", "500")
+    monkeypatch.setenv("YDB_TPU_BATCH_MAX", "2")
+    eng = engine()
+    eng.query(texts[0])                    # warm per-query path
+    c0 = eng.counters()
+    got = _storm(eng, texts)
+    c1 = eng.counters()
+    assert c1["batch/batches"] == c0.get("batch/batches", 0) + 1
+    # the one batched dispatch counts its six in-place reads once
+    assert c1["latemat/direct_cols"] - c0["latemat/direct_cols"] == 6
+    assert c1.get("latemat/gathered_cols", 0) \
+        == c0.get("latemat/gathered_cols", 0)
+    for i, w in enumerate(want):
+        assert list(got[i].columns) == list(w.columns)
+        for c in w.columns:
+            assert np.array_equal(got[i][c].to_numpy(), w[c].to_numpy()), \
+                (i, c)
+    assert not want[0].equals(want[1])     # the literals do differ
+    batched = [r["program"] for r in progstats.inventory_rows()
+               if r["kind"] == "batched"
+               and r["name"].startswith("jit_lineitem_")]
+    assert batched
+    for kid in batched:
+        text = progstats.hlo_text(kid)
+        assert text
+        assert not re.findall(r' gather\(.*op_name="[^"]*latemat\[', text)

@@ -16,19 +16,29 @@ None of that may change a single output byte:
   * lever flips replan + recompile (the lever rides the plan-cache
     fingerprint and every program cache key) instead of reusing
     shape-mismatched artifacts, and repeated runs mint no new programs
-    (the sticky compact capacity pins cache churn).
+    (the sticky compact capacity pins cache churn);
+  * a deferred SCAN column first referenced while the row positions are
+    still the iota is read in place (`latemat/direct_cols`: TPC-H Q1's
+    six); once a compact, compress, sort or limit has moved rows it is
+    gathered at the small shape (`latemat/gathered_cols`).
 
 All aggregated columns hold integer-valued doubles, so sums are exact
 in float64 regardless of reduction order — capacity changes between the
 two lever states cannot excuse an LSB drift.
 """
 
+import re
+
 import numpy as np
 import pandas as pd
 import pytest
 
+from ydb_tpu.bench.tpch_gen import load_tpch
 from ydb_tpu.query import QueryEngine
+from ydb_tpu.utils import progstats
 from ydb_tpu.utils.metrics import GLOBAL
+
+from tests.tpch_util import QUERIES
 
 
 @pytest.fixture(scope="module")
@@ -212,3 +222,127 @@ def test_repeat_runs_mint_no_new_programs(eng, monkeypatch):
     eng.query(sql)
     assert len(eng.executor._fused_cache) == n_off, \
         "lever flip back must reuse the on-state program"
+
+
+# -- a deferred scan column is read in place while nothing has moved --------
+# (CPU runs: the traced program and its counters, never a speed)
+
+
+@pytest.fixture(scope="module")
+def tpch():
+    e = QueryEngine()
+    load_tpch(e.catalog, sf=0.002)
+    return e
+
+
+def _latemat_reads(eng, sql: str):
+    """One statement's (`latemat/direct_cols`, `latemat/gathered_cols`)
+    deltas, and {program name: its gathers scoped `latemat[`}."""
+    names = ("latemat/direct_cols", "latemat/gathered_cols")
+    before = [GLOBAL.get(n) for n in names]
+    got = eng.query(sql)
+    assert eng.executor.last_path == "fused"
+    delta = tuple(GLOBAL.get(n) - b for n, b in zip(names, before))
+    gathers = {}
+    for p in eng.last_stats.programs["programs"]:
+        text = progstats.hlo_text(p["key"])
+        assert text.startswith(f"HloModule {p['name']}")
+        gathers[p["name"]] = re.findall(
+            r' gather\(.*op_name="[^"]*/(latemat\[[^"]*)"', text)
+    return got, delta, gathers
+
+
+def _lever_off(eng, sql: str, monkeypatch):
+    monkeypatch.setenv("YDB_TPU_LATE_MAT", "0")
+    off = eng.query(sql)
+    monkeypatch.setenv("YDB_TPU_LATE_MAT", "1")
+    return off
+
+
+def test_q1_reads_its_deferred_columns_in_place(tpch, monkeypatch):
+    """Q1's pre-program only narrows the mask: the group-by's six
+    columns are first referenced while `__lmpos` is still the iota, so
+    the program holds no `latemat[` gather (ten identity gathers of
+    6.29 M indices at SF1)."""
+    off = _lever_off(tpch, QUERIES["q1"], monkeypatch)
+    assert "latemat: 6 deferred" in _explain(tpch, QUERIES["q1"])
+    tpch.query(QUERIES["q1"])
+    tpch.query(QUERIES["q1"])   # the first may overflow a Compact and rerun
+    on, (direct, gathered), gathers = _latemat_reads(tpch, QUERIES["q1"])
+    assert (direct, gathered) == (6, 0)
+    (name, found), = gathers.items()
+    assert re.fullmatch(r"jit_lineitem_gs_[0-9a-f]{6}", name)
+    assert found == []
+    _byte_equal(off, on)
+
+
+MOVED_ROWS = {
+    # (statement, deferred scan columns of its own program, byte-equal)
+    # a Compact before the first reference; a sum over the compacted rows
+    # adds in another order than over the scan's, so no bytes to compare
+    "compact": (QUERIES["q6"], 1, False),
+    # a join, then the Compact
+    "join-compact": (QUERIES["q3"], 2, True),
+    # no filter, no Compact: the tail's compress and LIMIT slice
+    "limit": ("select l_extendedprice from lineitem limit 5", 1, True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MOVED_ROWS))
+def test_moved_rows_still_gather_at_the_small_shape(tpch, monkeypatch,
+                                                    case):
+    sql, ncols, exact = MOVED_ROWS[case]
+    off = _lever_off(tpch, sql, monkeypatch)
+    tpch.query(sql)
+    tpch.query(sql)                      # builds and sizing settled
+    on, (direct, gathered), gathers = _latemat_reads(tpch, sql)
+    assert (direct, gathered) == (0, ncols)
+    (name, found), = gathers.items()     # builds are cached by now
+    assert name.startswith("jit_lineitem_")
+    assert len(found) == ncols and all(f.endswith("/gather")
+                                       for f in found)
+    if exact:
+        _byte_equal(off, on)
+    else:
+        assert list(off.columns) == list(on.columns)
+        for col in off.columns:
+            np.testing.assert_allclose(on[col].to_numpy(),
+                                       off[col].to_numpy(), rtol=1e-12)
+
+
+def test_q1_forged_low_compact_reruns_loudly(tpch, monkeypatch):
+    """Q1 under a Compact forged below its live rows: the compacted
+    program gathers the six columns at the bound, overflows, and the
+    rerun at full capacity reads them in place; the answer is the same."""
+    off = _lever_off(tpch, QUERIES["q1"], monkeypatch)
+    monkeypatch.setattr(tpch.executor, "_compact_sizing",
+                        lambda *a, **k: 2048)
+    before = GLOBAL.get("latemat/compact_overflow_reruns")
+    on, (direct, gathered), gathers = _latemat_reads(tpch, QUERIES["q1"])
+    assert GLOBAL.get("latemat/compact_overflow_reruns") == before + 1
+    assert (direct, gathered) == (6, 6)
+    assert sorted(len(g) for g in gathers.values()) == [0, 6]
+    _byte_equal(off, on)
+
+
+@pytest.mark.parametrize("counters,want", [
+    ({"prog/executions": 180.0}, None),            # a program without them
+    ({"latemat/direct_cols": 9900.0, "latemat/gathered_cols": 1651.0},
+     100.0 * 9900 / 11551),                        # Q1 6/0 beside Q6 0/1
+    ({"latemat/gathered_cols": 54.0}, 0.0),        # every build cached
+])
+def test_latemat_direct_pct_reader(counters, want):
+    """The benchmark's reader of the two counters: a share of what was
+    counted, `None` (left out of the line) where nothing was."""
+    import importlib.util
+    from pathlib import Path
+    path = (Path(__file__).resolve().parent.parent / "benchmark" / "metrics"
+            / "latemat_direct_pct.py")
+    spec = importlib.util.spec_from_file_location("latemat_direct_pct", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    got = mod.read({"window_counters": counters})
+    if want is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(want)

@@ -169,7 +169,12 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
     up front — a single int32 row-position column (`__lmpos`) rides the
     pipeline instead, and each deferred column gathers from the
     superblock at its first compute reference or at the bound-sized
-    tail. Joins whose meta carries `late` likewise thread a
+    tail. A column first referenced while `__lmpos` is still the iota
+    the body created (nothing has compacted, grouped, compressed, sorted
+    or sliced the env yet: a fact of the trace) is read in place, as an
+    eager column is loaded; `layout_box["latemat"]` counts both ways
+    (`latemat/direct_cols`, `latemat/gathered_cols`).
+    Joins whose meta carries `late` likewise thread a
     (build row-id, match) pair (`ops/join.probe_lut_traced`) in place of
     their payload widths. `compact_prog` (an `ir.Compact` wrapper built
     by the executor) shrinks the working capacity to a ladder-quantized
@@ -188,6 +193,7 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
         cap = cap0
         aux: dict = {}
         env = {}
+        lm_count = {"direct": 0, "gathered": 0}
         deferred: dict = {}              # out name -> ("scan", src) |
         #                                  ("join", join_idx, src)
         # `jax.named_scope`s below are HLO metadata only (`op_name`): a
@@ -202,8 +208,10 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
                     v = sbv[c.name].reshape(cap0) \
                         if c.name in sb_valid_names else None
                     env[c.name] = (d, v)
+            lm_iota = None
             if deferred:
-                env[LM_POS] = (jnp.arange(cap0, dtype=jnp.int32), None)
+                lm_iota = jnp.arange(cap0, dtype=jnp.int32)
+                env[LM_POS] = (lm_iota, None)
             sel = (jnp.arange(CAP, dtype=jnp.int32)[None, :]
                    < lengths[:, None]).reshape(cap0)
             length = jnp.int32(cap0)
@@ -232,11 +240,21 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
                 if src is None:
                     continue
                 if src[0] == "scan":
+                    # read in place while nothing has moved or dropped
+                    # a row (a Compact, compress, sort or limit slice
+                    # each put a NEW array under `__lmpos`): the TPU
+                    # compiler does not fold `x[arange(n)]`, 54 ms an
+                    # array at SF1
+                    pos = env[LM_POS][0]
+                    moved = pos is not lm_iota
+                    lm_count["gathered" if moved else "direct"] += 1
                     with jax.named_scope(f"latemat[{nm}]"):
-                        pos = env[LM_POS][0]
-                        d = sb[src[1]].reshape(cap0)[pos]
-                        v = (sbv[src[1]].reshape(cap0)[pos]
+                        d = sb[src[1]].reshape(cap0)
+                        v = (sbv[src[1]].reshape(cap0)
                              if src[1] in sb_valid_names else None)
+                        if moved:
+                            d = d[pos]
+                            v = v[pos] if v is not None else None
                     env[nm] = (d, v)
                 else:
                     _k, j, s = src
@@ -343,6 +361,7 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
         valid_names = [n for n in out_names if env[n][1] is not None]
         layout_box["data"] = data_layout
         layout_box["valids"] = valid_names
+        layout_box["latemat"] = lm_count
         with jax.named_scope("output"):
             data_stacks = {k: jnp.stack(v) for k, v in groups.items()}
             valid_stack = (jnp.stack([env[n][1] for n in valid_names])

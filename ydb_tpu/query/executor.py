@@ -67,6 +67,18 @@ def _fused_evict_hook(key) -> None:
     progstats.mark_evicted(kind, key)
 
 
+def _count_latemat_reads(layout_box: dict) -> None:
+    """Count, once per fused or batched dispatch, how the program's trace
+    read its deferred scan columns: in place (`__lmpos` still the iota)
+    or gathered through moved rows. A stored program that predates the
+    counts leaves none."""
+    lm_read = layout_box.get("latemat")
+    if lm_read:
+        from ydb_tpu.utils.metrics import GLOBAL
+        GLOBAL.inc("latemat/direct_cols", lm_read["direct"])
+        GLOBAL.inc("latemat/gathered_cols", lm_read["gathered"])
+
+
 class Executor:
     def __init__(self, catalog, block_rows: int = DEFAULT_BLOCK_ROWS,
                  device_cache=None, mesh=None):
@@ -570,6 +582,7 @@ class Executor:
                     dsp.attrs["compile_ms"] = warm_ms
                     dsp.attrs["compile_wait_ms"] = round(
                         min(fill_wait_ms, warm_ms), 3)
+        _count_latemat_reads(layout_box)
         # result buffers live in HBM until the future drains them
         memledger.record_alloc(
             "result_buffers",
@@ -1339,6 +1352,7 @@ class Executor:
             # cache only after the first successful dispatch, so a
             # trace-failing shape never parks a dead entry in the budget
             self._fused_cache[key] = (fn, layout_box, out_schema)
+        _count_latemat_reads(layout_box)
         # batch-lane padding: the power-of-two axis bucket materializes
         # Bb member slots of every stacked output for B live members
         # (same-text dedup maps all members to one row — min() keeps the

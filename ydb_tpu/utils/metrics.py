@@ -377,6 +377,14 @@ COUNTER_REGISTRY = {
     "latemat/deferred_cols":
         "[viz] columns carried as row-ids per fused dispatch "
         "(scan deferrals + late join payloads)",
+    "latemat/direct_cols":
+        "[viz] deferred scan columns read in place per fused or batched "
+        "dispatch (first referenced while the row positions were still "
+        "the iota)",
+    "latemat/gathered_cols":
+        "[viz] deferred scan columns gathered through moved row "
+        "positions per fused or batched dispatch (after a compact, "
+        "compress, sort or limit)",
     "latemat/compact_plans":
         "[viz] fused dispatches carrying a bound-sized ir.Compact",
     "latemat/compact_capacity_rows":

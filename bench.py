@@ -1101,7 +1101,7 @@ def cold_start_main(n: int = 48, rows: int = 8192) -> int:
     base["YDB_TPU_BATCH_WINDOW"] = "0"
     base["YDB_TPU_COMPILE_AHEAD"] = "0"
     for k in ("JAX_COMPILATION_CACHE_DIR", "YDB_TPU_PROGSTATS",
-              "YDB_TPU_SHAPE_BUCKETS", "YDB_TPU_PROGSTORE_DEVICE"):
+              "YDB_TPU_PROGSTORE_DEVICE"):
         base.pop(k, None)
     me = os.path.abspath(__file__)
 

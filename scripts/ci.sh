@@ -43,19 +43,6 @@ if [ "$rc" -ne 0 ]; then
     exit "$rc"
 fi
 
-echo "== sorted group-by gather budget gate (q3-shaped plan) =="
-# trace-time counter gate: the tiled/late-materialized sorted group-by
-# must emit NO gathers above the tile budget for a canonical q3 shape
-# (CI_GROUPBY_GATHER_BUDGET to loosen) and the legacy path must measure
-# >=4x more — a regression back to per-column scan-capacity gathers
-# fails loudly on the CPU runner
-JAX_PLATFORMS=cpu python scripts/groupby_gate.py
-grc=$?
-if [ "$grc" -ne 0 ]; then
-    echo "groupby gather gate FAILED (rc=$grc)" >&2
-    exit "$grc"
-fi
-
 if [ "${CI_SKIP_SMOKE:-0}" = "1" ]; then
     echo "== pipeline smoke skipped (CI_SKIP_SMOKE=1) =="
     exit 0
@@ -174,20 +161,6 @@ psrc=$?
 if [ "$psrc" -ne 0 ]; then
     echo "zero-compile serving gate FAILED (rc=$psrc)" >&2
     exit "$psrc"
-fi
-
-echo "== bounds-lattice gate (carry rewrite, eager agg, lever byte-equal, fallback class stays retired) =="
-# the bounds floor: the bench join must trace a carry rewrite with
-# nonzero proven-vs-capacity tightening and keep its `-- bounds:`
-# EXPLAIN line, the q13 LEFT JOIN shape must eager-aggregate onto the
-# fused path, YDB_TPU_BOUNDS=0 must be byte-equal, and the newest
-# BENCH_HISTORY.jsonl sf1 entry must report 22/22 with NO fallbacks
-# (q8/q10/q18 timed fused — the retired class cannot quietly return)
-JAX_PLATFORMS=cpu python scripts/bounds_gate.py
-borc=$?
-if [ "$borc" -ne 0 ]; then
-    echo "bounds-lattice gate FAILED (rc=$borc)" >&2
-    exit "$borc"
 fi
 
 echo "== late-materialization gate (row-id deferral, bound-sized compact, bytes_accessed down, lever byte-equal) =="

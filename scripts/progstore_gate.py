@@ -183,7 +183,7 @@ def main() -> int:
     # serialize→deserialize, so nothing would land in the store)
     base["YDB_TPU_COMPILE_AHEAD"] = "0"
     for k in ("JAX_COMPILATION_CACHE_DIR", "YDB_TPU_PROGSTATS",
-              "YDB_TPU_SHAPE_BUCKETS", "YDB_TPU_PROGSTORE_DEVICE"):
+              "YDB_TPU_PROGSTORE_DEVICE"):
         base.pop(k, None)
     me = os.path.abspath(__file__)
     out = {"ok": False, "store_dir": store_dir}

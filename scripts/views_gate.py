@@ -243,7 +243,7 @@ def main() -> int:
     # deterministic compile accounting, same levers as progstore_gate
     base["YDB_TPU_COMPILE_AHEAD"] = "0"
     for k in ("JAX_COMPILATION_CACHE_DIR", "YDB_TPU_PROGSTATS",
-              "YDB_TPU_SHAPE_BUCKETS", "YDB_TPU_PROGSTORE_DEVICE",
+              "YDB_TPU_PROGSTORE_DEVICE",
               "YDB_TPU_VIEW_FOLD_BATCH", "YDB_TPU_VIEW_MAX_GROUPS"):
         base.pop(k, None)
     me = os.path.abspath(__file__)

@@ -2,8 +2,8 @@
 
 Differential discipline: every lane behavior is pinned against the
 `YDB_TPU_BATCH_WINDOW=0` per-query path (byte-equal results), and the
-lift is pinned against literal-embedding execution across literal kinds
-(ints, floats, dictionary-coded strings, dates, IN lists, LIMIT/OFFSET).
+lift is pinned against pandas across literal kinds (ints, floats,
+dictionary-coded strings, dates, IN lists, LIMIT/OFFSET).
 """
 
 import os
@@ -31,37 +31,46 @@ def _mk_engine(rows: int = 500, **env):
 @pytest.fixture
 def no_batch_env(monkeypatch):
     monkeypatch.delenv("YDB_TPU_BATCH_WINDOW", raising=False)
-    monkeypatch.delenv("YDB_TPU_PARAM_LIFT", raising=False)
 
 
 # -- lift correctness across literal kinds ---------------------------------
 
 
-def test_lift_differential_literal_kinds(monkeypatch, no_batch_env):
-    """The same statements with lifting on and off produce identical
-    frames — across int/float/string/date literals, IN lists, arithmetic
-    folds, and LIMIT/OFFSET (the lifted-__lim2 clamp)."""
-    queries = [
-        "select a, b from t where k = 17",
-        "select count(*) as c from t where b > 42.25",
-        "select k from t where s = 'tag3' order by k limit 6",
-        "select count(*) as c from t where d >= date '2024-01-15'",
-        "select k from t where a in (1, 3, 5) order by k limit 7 offset 2",
-        "select a, sum(b) as sb from t where k >= 2 + 3 group by a "
-        "order by a",
-        "select k from t where s = 'zzz-absent'",
+def test_lift_differential_literal_kinds(no_batch_env):
+    """Lifted statements answer what pandas computes over the table
+    `_mk_engine` loads — across int/float/string/date literals, IN
+    lists, arithmetic folds, and LIMIT/OFFSET (the lifted-__lim2
+    clamp)."""
+    import pandas as pd
+    rows = 500
+    i = np.arange(rows)
+    t = pd.DataFrame({
+        "k": i, "a": i % 7, "b": i * 0.5,
+        "s": [f"tag{x % 5}" for x in i],
+        "d": pd.to_datetime([f"2024-01-{x % 28 + 1:02d}" for x in i])})
+    cases = [
+        ("select a, b from t where k = 17", t[t.k == 17][["a", "b"]]),
+        ("select count(*) as c from t where b > 42.25",
+         pd.DataFrame({"c": [(t.b > 42.25).sum()]})),
+        ("select k from t where s = 'tag3' order by k limit 6",
+         t[t.s == "tag3"].sort_values("k")[["k"]].head(6)),
+        ("select count(*) as c from t where d >= date '2024-01-15'",
+         pd.DataFrame({"c": [(t.d >= "2024-01-15").sum()]})),
+        ("select k from t where a in (1, 3, 5) order by k limit 7 offset 2",
+         t[t.a.isin([1, 3, 5])].sort_values("k")[["k"]].iloc[2:9]),
+        ("select a, sum(b) as sb from t where k >= 2 + 3 group by a "
+         "order by a",
+         t[t.k >= 5].groupby("a").agg(sb=("b", "sum")).reset_index()),
+        ("select k from t where s = 'zzz-absent'", t[t.k < 0][["k"]]),
     ]
-    monkeypatch.setenv("YDB_TPU_PARAM_LIFT", "0")
-    plain = _mk_engine()
-    want = [plain.query(q) for q in queries]
-    monkeypatch.setenv("YDB_TPU_PARAM_LIFT", "1")
-    lifted = _mk_engine()
-    for q, w in zip(queries, want):
-        got = lifted.query(q)
-        assert list(got.columns) == list(w.columns), q
+    eng = _mk_engine(rows)
+    for q, want in cases:
+        got = eng.query(q)
+        assert list(got.columns) == list(want.columns), q
         for c in got.columns:
-            assert np.array_equal(got[c].to_numpy(), w[c].to_numpy()), \
-                (q, c)
+            # b = i * 0.5 and its per-group sums are exact in a double
+            assert np.array_equal(got[c].to_numpy(),
+                                  want[c].to_numpy()), (q, c)
 
 
 def test_lift_shares_program_across_literal_kinds(no_batch_env):

@@ -1,6 +1,6 @@
 """Bounds lattice (`query/bounds.py`): derivation units, the executor
 carry rewrite's functional-dependency verification, eager aggregation,
-and the YDB_TPU_BOUNDS differential contract.
+and the bound-shaped plans' agreement with pandas.
 
 Three layers, mirroring the lattice's trust tiers:
 
@@ -11,10 +11,9 @@ Three layers, mirroring the lattice's trust tiers:
     determinant AND the measured `dataset_distinct` verification, with a
     non-functional-dependency negative), and the planner's eager
     aggregation of LEFT JOIN builds (q13's expanding-probe retirement);
-  * the lever — YDB_TPU_BOUNDS=0 must execute byte-equal at capacity
-    sizing on tile-boundary / skew / 0-row shapes (the lever rides the
-    plan-cache fingerprint and `groupby_tuning`, so in-process flips
-    replan + recompile instead of reusing bound-shaped artifacts).
+  * the differential — bound-shaped plans (carried keys, join bounds,
+    eager aggregation) on tile-boundary / skew / 0-row shapes must
+    answer what pandas computes over the same frames.
 
 The q8/q10/q18 regression pins run the real queries at test scale and
 assert the fused path (no fallback class) with finite stamped bounds.
@@ -370,71 +369,90 @@ def test_eager_agg_probe_minmax_still_rewrites(eng13):
                 == want[col].to_numpy().astype(np.int64)).all(), col
 
 
-def test_eager_agg_count_dtype_stable_across_lever(eng13, monkeypatch):
+def test_eager_agg_count_keeps_uint64(eng13):
     # the rewritten count merges as sum(coalesce(...)) — the outer cast
-    # must restore count's uint64 result type so the lever cannot flip
+    # must restore count's uint64 result type so the rewrite cannot flip
     # the output schema, only the plan shape
-    sql = ("select cust.ck as ck, count(ords.ok) as c from cust "
-           "left join ords on cust.ck = ords.ck group by cust.ck "
-           "order by ck")
-    on = eng13.query(sql)
-    monkeypatch.setenv("YDB_TPU_BOUNDS", "0")
-    off = eng13.query(sql)
-    assert list(on.dtypes) == list(off.dtypes)
-    assert (on["c"].to_numpy() == off["c"].to_numpy()).all()
+    got = eng13.query(
+        "select cust.ck as ck, count(ords.ok) as c from cust "
+        "left join ords on cust.ck = ords.ck group by cust.ck "
+        "order by ck")
+    assert got["c"].dtype == np.uint64
+    cu, od = eng13.frames["cust"], eng13.frames["ords"]
+    want = (cu.merge(od, on="ck", how="left").groupby("ck")["ok"].count()
+            .reset_index(name="c").sort_values("ck"))
+    assert (got["ck"].to_numpy() == want["ck"].to_numpy()).all()
+    assert (got["c"].to_numpy() == want["c"].to_numpy()).all()
 
 
-# -- the YDB_TPU_BOUNDS lever: byte-equal differential ----------------------
+# -- bound-shaped plans against pandas --------------------------------------
 
 
-def _byte_equal(a, b):
-    pa, pb = a, b
-    assert list(pa.columns) == list(pb.columns)
-    assert len(pa) == len(pb)
-    for col in pa.columns:
-        xa, xb = pa[col].to_numpy(), pb[col].to_numpy()
+def _matches(got, want):
+    """Exact for integers and NULLs, 1e-9 relative for float sums."""
+    assert list(got.columns) == list(want.columns)
+    assert len(got) == len(want)
+    for col in got.columns:
+        xa, xb = got[col].to_numpy(), want[col].to_numpy()
         na, nb = pd.isna(xa), pd.isna(xb)
         assert (na == nb).all(), col
-        assert (xa[~na] == xb[~nb]).all(), col
+        if xb.dtype.kind == "f":
+            np.testing.assert_allclose(xa[~na].astype(np.float64),
+                                       xb[~nb], rtol=1e-9, err_msg=col)
+        else:
+            assert (xa[~na] == xb[~nb]).all(), col
+
+
+def _joined(frames):
+    return frames["f"].merge(frames["d"], on="k")
+
+
+def _want_carried(frames):
+    return (_joined(frames).groupby(["k", "grp", "a"])
+            .agg(s=("val", "sum"), c=("val", "size")).reset_index()
+            .sort_values("k").reset_index(drop=True))
+
+
+def _want_one_giant_group(frames):
+    return (_joined(frames).groupby("b")
+            .agg(c=("val", "size"), s=("val", "sum")).reset_index()
+            .sort_values("b").reset_index(drop=True))
+
+
+def _want_empty(frames):
+    j = _joined(frames)
+    return (j[j.val > 1e12].groupby("k").agg(c=("val", "size"))
+            .reset_index())
+
+
+def _want_eager(frames):
+    j = frames["d"][["k"]].merge(frames["f"], on="k", how="left")
+    return (j.groupby("k").agg(c=("id", "count")).reset_index()
+            .sort_values("k").head(40).reset_index(drop=True))
 
 
 DIFF_QUERIES = [
     # carried keys + join bound (skewed: most rows in few groups)
-    "select f.k as k, grp, a, sum(val) as s, count(*) as c from f "
-    "join d on f.k = d.k group by f.k, grp, a order by k",
+    ("select f.k as k, grp, a, sum(val) as s, count(*) as c from f "
+     "join d on f.k = d.k group by f.k, grp, a order by k",
+     _want_carried),
     # tile-boundary shape: one giant group (all rows through one bucket)
-    "select b, count(*) as c, sum(val) as s from f "
-    "join d on f.k = d.k group by b order by b",
+    ("select b, count(*) as c, sum(val) as s from f "
+     "join d on f.k = d.k group by b order by b", _want_one_giant_group),
     # 0-row: nothing survives the filter
-    "select f.k as k, count(*) as c from f join d on f.k = d.k "
-    "where val > 1e12 group by f.k order by k",
+    ("select f.k as k, count(*) as c from f join d on f.k = d.k "
+     "where val > 1e12 group by f.k order by k", _want_empty),
     # eager-agg shape over the same store (LEFT JOIN d's dup-free key is
-    # the DEGENERATE eager case: still must stay byte-equal)
-    "select d.k as k, count(f.id) as c from d left join f "
-    "on d.k = f.k group by d.k order by k limit 40",
+    # the DEGENERATE eager case)
+    ("select d.k as k, count(f.id) as c from d left join f "
+     "on d.k = f.k group by d.k order by k limit 40", _want_eager),
 ]
 
 
 @pytest.mark.parametrize("qi", range(len(DIFF_QUERIES)))
-def test_bounds_lever_byte_equal(eng, qi, monkeypatch):
-    sql = DIFF_QUERIES[qi]
-    monkeypatch.setenv("YDB_TPU_BOUNDS", "0")
-    off = eng.query(sql)
-    monkeypatch.setenv("YDB_TPU_BOUNDS", "1")
-    on = eng.query(sql)
-    _byte_equal(off, on)
-
-
-def test_lever_off_freezes_lattice(eng, monkeypatch):
-    monkeypatch.setenv("YDB_TPU_BOUNDS", "0")
-    mark = (GLOBAL.get("bounds/plans"), GLOBAL.get("bounds/carry_rewrites"),
-            GLOBAL.get("bounds/eager_agg_rewrites"))
-    p = _plan(eng, "select k from f limit 3")
-    assert p.out_bound == 0            # no stamping with the lever off
-    eng.query("select f.k as kk, grp, count(*) as c from f "
-              "join d on f.k = d.k group by f.k, grp order by kk limit 5")
-    assert (GLOBAL.get("bounds/plans"), GLOBAL.get("bounds/carry_rewrites"),
-            GLOBAL.get("bounds/eager_agg_rewrites")) == mark
+def test_bounded_plans_match_pandas(eng, qi):
+    sql, want = DIFF_QUERIES[qi]
+    _matches(eng.query(sql), want(eng.frames))
 
 
 # -- q8/q10/q18 regression: the fallback class is retired -------------------

@@ -14,6 +14,19 @@ import pytest
 
 from ydb_tpu.ops.exec_cache import ExecCache, _Budget
 
+_AGGS = ("sum(a)", "min(a)", "max(a)", "sum(b)", "min(b)", "max(b)",
+         "count(a)")
+
+
+def _distinct_shape(i: int, table: str, where: str) -> str:
+    """Statement `i` (< 128) of a family whose members differ in
+    STRUCTURE — bit j of `i` adds `_AGGS[j]` to the select list — so
+    each is a distinct executable: parameter lifting collapses literal
+    variants of one shape, never these."""
+    extra = "".join(f", {agg} as x{j}" for j, agg in enumerate(_AGGS)
+                    if i >> j & 1)
+    return f"select count(*) as n{extra} from {table} where {where}"
+
 
 def test_lru_within_one_cache():
     b = _Budget(3)
@@ -46,16 +59,16 @@ def test_get_refresh_protects_across_caches():
     assert "x" in c1 and "y" not in c2
 
 
-def test_engine_soak_live_executables_bounded(monkeypatch):
+def test_engine_soak_live_executables_bounded():
     """Many distinct query shapes through ONE engine: the live-executable
     count stays under the global budget and results stay correct (the
-    r4 segfault scenario, minus the segfault). Lifting pinned OFF so the
-    distinct literals really are distinct executables — the storm-shares-
-    one-program property has its own pin above."""
+    r4 segfault scenario, minus the segfault). The statements differ in
+    structure (`_distinct_shape`) so they really are distinct
+    executables — the storm-shares-one-program property has its own pin
+    below."""
     from ydb_tpu.ops.exec_cache import GLOBAL_BUDGET, live_executables
     from ydb_tpu.query import QueryEngine
 
-    monkeypatch.setenv("YDB_TPU_PARAM_LIFT", "0")
     eng = QueryEngine(block_rows=1 << 12)
     eng.execute("create table s (k Int64 not null, a Int64, b Double, "
                 "c Int64, primary key (k))")
@@ -66,12 +79,11 @@ def test_engine_soak_live_executables_bounded(monkeypatch):
     old_max = GLOBAL_BUDGET.max_entries
     GLOBAL_BUDGET.max_entries = 24
     try:
-        # every distinct literal is a distinct program fingerprint →
-        # a distinct compiled executable per query shape
+        # every distinct select list is a distinct program fingerprint
+        # → a distinct compiled executable per query shape
         for i in range(60):
-            n = eng.query(
-                f"select count(*) as n from s where a = {i % 11} "
-                f"and k >= {i}").n[0]
+            n = eng.query(_distinct_shape(
+                i, "s", f"a = {i % 11} and k >= {i}")).n[0]
             expect = sum(1 for k in range(200)
                          if k % 7 == i % 11 and k >= i)
             assert n == expect, (i, n, expect)
@@ -115,7 +127,7 @@ def test_eviction_releases_executables():
     assert inner1.cleared == 1 and inner2.cleared == 1
 
 
-def test_evicted_program_recompile_is_miss_not_hit(monkeypatch):
+def test_evicted_program_recompile_is_miss_not_hit():
     """The eviction-accounting companion of the PR-4 spurious-evict fix
     (overwrite-in-place must NOT evict — pinned above in
     test_eviction_releases_executables): a real LRU eviction must
@@ -129,7 +141,6 @@ def test_evicted_program_recompile_is_miss_not_hit(monkeypatch):
     from ydb_tpu.utils import progstats
     from ydb_tpu.utils.metrics import GLOBAL
 
-    monkeypatch.setenv("YDB_TPU_PARAM_LIFT", "0")
     # the inventory is process-global; scope the state assertions below
     # to THIS test's programs, not leftovers from earlier suites
     progstats.reset_for_tests()
@@ -147,11 +158,11 @@ def test_evicted_program_recompile_is_miss_not_hit(monkeypatch):
         base = "select count(*) as n from ev where a = 0"
         assert int(eng.query(base).n[0]) == 24
         ev0 = GLOBAL.get("prog/evicted")
-        # flood with distinct literal shapes (lift off → distinct
+        # flood with structurally distinct shapes (→ distinct
         # programs) until the base query's programs are LRU victims
         for i in range(1, 9):
-            eng.query(f"select count(*) as n from ev where a = {i % 5} "
-                      f"and k >= {i * 7}")
+            eng.query(_distinct_shape(
+                i, "ev", f"a = {i % 5} and k >= {i * 7}"))
         assert GLOBAL.get("prog/evicted") > ev0, \
             "LRU evictions must emit prog/evicted"
         evicted = [r for r in progstats.inventory_rows()
@@ -213,18 +224,18 @@ def test_literal_storm_compiles_one_program():
 
 
 @pytest.mark.slow
-def test_soak_compile_twice_the_lru_cap_releases(monkeypatch):
+def test_soak_compile_twice_the_lru_cap_releases():
     """Soak (marked slow): compile 2× the LRU cap of DISTINCT query
     shapes in ONE process — the live-executable count stays under the
     cap, evictions actually release (released counter tracks them), and
     results stay correct throughout. The full-suite-SIGSEGV scenario,
-    run deliberately. Parameter lifting is pinned OFF: it would collapse
-    the distinct literals into one shape and starve the eviction path
-    this soak exists to exercise."""
+    run deliberately. The statements differ in structure
+    (`_distinct_shape`): parameter lifting would collapse literal
+    variants into one shape and starve the eviction path this soak
+    exists to exercise."""
     from ydb_tpu.ops.exec_cache import GLOBAL_BUDGET, live_executables
     from ydb_tpu.query import QueryEngine
 
-    monkeypatch.setenv("YDB_TPU_PARAM_LIFT", "0")
     eng = QueryEngine(block_rows=1 << 12)
     eng.execute("create table soak (k Int64 not null, a Int64, b Double, "
                 "primary key (k))")
@@ -239,11 +250,10 @@ def test_soak_compile_twice_the_lru_cap_releases(monkeypatch):
         if (c := ref()) is not None)
     try:
         for i in range(2 * cap):
-            # distinct literals → distinct program fingerprints →
+            # distinct select lists → distinct program fingerprints →
             # distinct compiled executables
-            got = eng.query(
-                f"select count(*) as n, sum(b) as s from soak "
-                f"where a = {i % 13} and k >= {i * 3}")
+            got = eng.query(_distinct_shape(
+                i, "soak", f"a = {i % 13} and k >= {i * 3}"))
             expect = [k for k in range(300)
                       if k % 13 == i % 13 and k >= i * 3]
             assert int(got.n[0]) == len(expect), i
@@ -254,6 +264,63 @@ def test_soak_compile_twice_the_lru_cap_releases(monkeypatch):
         assert released_after > released_before
     finally:
         GLOBAL_BUDGET.max_entries = old_max
+
+
+# a deployment may still export a lever this engine no longer reads: the
+# value it gave the losing side (1 for the legacy lowering, 0 for the rest)
+RETIRED = (("YDB_TPU_GROUPBY_LEGACY", "1"), ("YDB_TPU_GATHER_BATCH_CAP", "0"),
+           ("YDB_TPU_BOUNDS", "0"), ("YDB_TPU_PARAM_LIFT", "0"),
+           ("YDB_TPU_SHAPE_BUCKETS", "0"))
+
+
+@pytest.mark.parametrize("name,value", RETIRED, ids=[n for n, _ in RETIRED])
+def test_retired_lever_is_ignored(name, value, monkeypatch):
+    """With a retired variable set, `groupby_tuning()`, the plan
+    fingerprint and a join + group-by's answer are what they are with it
+    unset, and nothing recompiles — not the statement, not a literal
+    variant of it (one shape, one executable), over a fact table of 5
+    sources (the ladder pads 5 to 6)."""
+    import pandas as pd
+
+    from ydb_tpu.ops.xla_exec import groupby_tuning
+    from ydb_tpu.query import QueryEngine
+    from ydb_tpu.utils.metrics import GLOBAL
+
+    monkeypatch.delenv(name, raising=False)
+    eng = QueryEngine(block_rows=1 << 12)
+    eng.execute("create table f (id Int64 not null, k Int64 not null, "
+                "val Double not null, primary key (id)) "
+                "with (store = column)")
+    eng.execute("create table d (k Int64 not null, grp Int64 not null, "
+                "a Int64 not null, primary key (k)) with (store = column)")
+    ids = np.arange(5 * 256, dtype=np.int64)
+    f = pd.DataFrame({"id": ids, "k": ids % 50, "val": ids * 0.5})
+    k = np.arange(50, dtype=np.int64)
+    d = pd.DataFrame({"k": k, "grp": k % 9, "a": k * 2})
+    for chunk in np.split(ids, 5):
+        eng.catalog.table("f").bulk_upsert(f.iloc[chunk],
+                                           eng._next_version())
+    eng.catalog.table("d").bulk_upsert(d, eng._next_version())
+    for t in ("f", "d"):
+        eng.catalog.table(t).indexate()
+
+    def sql(lit):
+        return ("select f.k as k, grp, a, count(*) as c, sum(val) as s "
+                f"from f join d on f.k = d.k where val > {lit} "
+                "group by f.k, grp, a order by k")
+
+    tuning = groupby_tuning()
+    want = eng.query(sql(10.5))
+    fp = eng._plan_cache[sql(10.5)][0]
+    compiled = GLOBAL.get("prog/registered")
+
+    monkeypatch.setenv(name, value)
+    assert groupby_tuning() == tuning
+    got = eng.query(sql(10.5))
+    assert eng._plan_cache[sql(10.5)][0] == fp
+    pd.testing.assert_frame_equal(got, want)
+    eng.query(sql(99.25))
+    assert GLOBAL.get("prog/registered") == compiled
 
 
 def test_build_cache_hit_and_invalidation():

@@ -2,14 +2,13 @@
 vs the numpy oracle (`ops/numpy_exec`) under forced-tiny tile budgets.
 
 `YDB_TPU_GROUPBY_TILE_ROWS` forces many tiles at test scale (blocks pad
-to the 8192-row capacity bucket, so tile_rows=1024 → 8 tiles) and
-`YDB_TPU_GATHER_BATCH_CAP` toggles the per-dtype batched gathers; both
-knobs are part of every compiled-program cache key, so in-process env
-flips recompile rather than reuse a differently-tiled trace. Cases pin
-the tile-boundary hazards: one group spanning a tile boundary, all rows
-one group, mostly-empty tiles, skewed group sizes, nullable-int and
-NaN-float keys, 0-row input, batching on/off byte-equality, legacy-path
-equivalence, and the `out_bound` late-materialization contract.
+to the 8192-row capacity bucket, so tile_rows=1024 → 8 tiles); the knob
+is part of every compiled-program cache key, so in-process env flips
+recompile rather than reuse a differently-tiled trace. Cases pin the
+tile-boundary hazards: one group spanning a tile boundary, all rows one
+group, mostly-empty tiles, skewed group sizes, nullable-int and
+NaN-float keys, 0-row input, batched / per-column gather byte-equality,
+and the `out_bound` late-materialization contract.
 """
 
 import numpy as np
@@ -46,12 +45,8 @@ def _block(keys: dict, v, v_valid=None, extra_valids=None):
     return HostBlock.from_arrays(Schema(cols), arrays, valids)
 
 
-def _set_tiny(monkeypatch, tile_rows="1024", batch_cap=None, legacy=None):
+def _set_tiny(monkeypatch, tile_rows="1024"):
     monkeypatch.setenv("YDB_TPU_GROUPBY_TILE_ROWS", tile_rows)
-    if batch_cap is not None:
-        monkeypatch.setenv("YDB_TPU_GATHER_BATCH_CAP", batch_cap)
-    if legacy is not None:
-        monkeypatch.setenv("YDB_TPU_GROUPBY_LEGACY", legacy)
 
 
 def _run_both(program, block, sort_by):
@@ -149,9 +144,10 @@ def test_filter_then_group(monkeypatch, rng):
 
 
 def test_batched_vs_unbatched_byte_equal(monkeypatch, rng):
-    # YDB_TPU_GATHER_BATCH_CAP=0 must disable per-dtype batched gathers
-    # and pin byte-identical results (gathers are exact — stacking then
-    # slicing changes nothing)
+    # the per-column side of `_GATHER_BATCH_ROWS` (a 1-row threshold: no
+    # tile is that small) and the batched side (the default) must give
+    # byte-identical results (gathers are exact — stacking then slicing
+    # changes nothing)
     n = 6000
     k = rng.integers(0, 300, n)
     v = rng.normal(size=n) * 1e6
@@ -167,11 +163,18 @@ def test_batched_vs_unbatched_byte_equal(monkeypatch, rng):
         Agg("s1", "sum", "v"), Agg("s2", "sum", "w"),
         Agg("mn", "min", "v"), Agg("mx", "max", "w"),
         Agg("c", "count", "v")])
-    outs = {}
-    for cap in ("0", "1048576"):
-        _set_tiny(monkeypatch, batch_cap=cap)
-        outs[cap] = xla_exec.run_program(p, b)
-    a, z = outs["0"], outs["1048576"]
+    _set_tiny(monkeypatch)
+    outs, batched = {}, {}
+    for rows in (1, xla_exec._GATHER_BATCH_ROWS):
+        # the constant rides no cache key: a cache of its own per side
+        monkeypatch.setattr(xla_exec, "_GATHER_BATCH_ROWS", rows)
+        xla_exec.groupby_trace_reset()
+        outs[rows] = xla_exec.run_program(p, b,
+                                          cache=xla_exec.ProgramCache())
+        batched[rows] = xla_exec.groupby_trace_snapshot().get(
+            "batched_gathers", 0)
+    (a, z), (na, nz) = outs.values(), batched.values()
+    assert na == 0 and nz >= 1
     assert a.length == z.length
     for name in a.schema.names:
         ca, cz = a.columns[name], z.columns[name]
@@ -182,21 +185,6 @@ def test_batched_vs_unbatched_byte_equal(monkeypatch, rng):
         assert (va is None) == (vz is None)
         if va is not None:
             assert np.array_equal(va, vz), name
-
-
-def test_legacy_path_equivalent(monkeypatch, rng):
-    # the pre-round-8 lowering (YDB_TPU_GROUPBY_LEGACY=1) must agree with
-    # the tiled path on the same block — the CI gate's A/B baseline
-    n = 5000
-    b = _block({"k": rng.integers(0, 64, n)}, rng.normal(size=n) * 10,
-               v_valid=rng.random(n) > 0.1)
-    p = ir.Program().group_by(["k"], ALL_AGGS)
-    _set_tiny(monkeypatch, legacy="1")
-    legacy = _run_both(p, b, ["k"]).to_pandas().sort_values("k")
-    _set_tiny(monkeypatch, legacy="0")
-    tiled = _run_both(p, b, ["k"]).to_pandas().sort_values("k")
-    pd.testing.assert_frame_equal(legacy.reset_index(drop=True),
-                                  tiled.reset_index(drop=True))
 
 
 def test_out_bound_shrinks_output_capacity(monkeypatch, rng):
@@ -219,10 +207,10 @@ def test_trace_counters(monkeypatch, rng):
     # forced-tiny tiles + a proven group bound (how real tail plans run:
     # planner domain products / executor join bounds): the trace must
     # report tiling active, NO gather above the tile budget — value
-    # gathers are tile-sized, per-group gathers bound-sized — no
-    # scatters, and batched gathers engaged
+    # gathers are tile-sized, per-group gathers bound-sized — and
+    # batched gathers engaged
     from ydb_tpu.utils.metrics import GLOBAL
-    _set_tiny(monkeypatch, tile_rows="2048", batch_cap="1048576")
+    _set_tiny(monkeypatch, tile_rows="2048")
     n = 6000
     k = rng.integers(0, 500, n)
     b = _block({"k": k}, rng.normal(size=n), v_valid=rng.random(n) > 0.1)
@@ -233,7 +221,6 @@ def test_trace_counters(monkeypatch, rng):
     tr = xla_exec.groupby_trace_snapshot()
     assert tr.get("traces", 0) >= 1
     assert tr.get("tiles", 0) >= 4           # 8192-cap / 2048-row tiles
-    assert tr.get("scatter_ops", 0) == 0     # scatter-free sorted path
     assert tr.get("value_gather_rows_max", 0) <= 2048
     assert tr.get("gather_ops", 0) == 0      # nothing above the budget
     assert GLOBAL.get("groupby/gather_ops") == before

@@ -3,8 +3,7 @@ key encoding, the shape-bucket ladder, single-flight compile dedup, the
 zero-compile restart path (store write → fresh process → deserialize
 with `compile_ms ~= 0`), the corruption/device-mismatch failure ladder,
 bucket migration recompiling exactly once per boundary, the
-`YDB_TPU_PROGSTORE=0` / `YDB_TPU_SHAPE_BUCKETS=0` byte-equal levers,
-and the `.sys/progstore` + ProgStoreStats observability surfaces.
+`YDB_TPU_PROGSTORE=0` byte-equal lever, and the `.sys/progstore` + ProgStoreStats observability surfaces.
 """
 
 import os
@@ -108,8 +107,7 @@ def test_bucket_ladder_shape():
     assert len({buckets.bucket_sources(k) for k in range(1, 65)}) <= 12
 
 
-def test_bucket_sources_quantizes_up(monkeypatch):
-    monkeypatch.delenv("YDB_TPU_SHAPE_BUCKETS", raising=False)
+def test_bucket_sources_quantizes_up():
     assert buckets.bucket_sources(1) == 1
     assert buckets.bucket_sources(4) == 4
     assert buckets.bucket_sources(5) == 6
@@ -117,13 +115,9 @@ def test_bucket_sources_quantizes_up(monkeypatch):
     assert buckets.bucket_sources(7) == 8
     assert buckets.bucket_sources(13) == 16
     # above the ceiling: pass-through, never pad a giant scan
-    assert buckets.bucket_sources(buckets.bucket_ceiling() + 1) == \
-        buckets.bucket_ceiling() + 1
-    monkeypatch.setenv("YDB_TPU_SHAPE_BUCKETS", "0")
-    assert all(buckets.bucket_sources(k) == k for k in range(1, 20))
-    monkeypatch.setenv("YDB_TPU_SHAPE_BUCKETS", "8")
-    assert buckets.bucket_sources(5) == 6
-    assert buckets.bucket_sources(9) == 9     # over the custom ceiling
+    assert buckets.bucket_sources(buckets.CEILING) == buckets.CEILING
+    assert buckets.bucket_sources(buckets.CEILING + 1) == \
+        buckets.CEILING + 1
 
 
 # -- single-flight dedup ----------------------------------------------------
@@ -378,7 +372,6 @@ def test_bucket_migration_recompiles_exactly_once(monkeypatch):
     """Growing 4 → 5 sources crosses the 4→6 bucket boundary: ONE
     recompile. Growing 5 → 6 stays inside bucket 6: ZERO recompiles —
     the padded program serves the larger table as-is."""
-    monkeypatch.delenv("YDB_TPU_SHAPE_BUCKETS", raising=False)
     monkeypatch.setenv("YDB_TPU_COMPILE_AHEAD", "0")
     monkeypatch.setenv("YDB_TPU_PROGSTORE", "0")
     progstats.reset_for_tests()
@@ -393,14 +386,13 @@ def test_bucket_migration_recompiles_exactly_once(monkeypatch):
     assert _fused_programs() == 2, \
         "growth inside a bucket reuses the padded program"
 
-    # differential: exact-K legacy shapes under the lever, byte-equal
-    monkeypatch.setenv("YDB_TPU_SHAPE_BUCKETS", "0")
-    progstats.reset_for_tests()
-    eng0 = _mk_growing_engine(5)
-    assert _frames_equal(r5, eng0.query(SQL))
-    _grow_chunk(eng0, 5)
-    assert _frames_equal(r6, eng0.query(SQL))
-    assert _fused_programs() == 2, "exact-K mints one shape per count"
+    # the zero-length pad sources change no answer (v = id * 0.5 and
+    # its sums are exact in a double)
+    for got, chunks in ((r5, 5), (r6, 6)):
+        ids = np.arange(chunks * 256, dtype=np.int64)
+        want = (pd.DataFrame({"k": ids % 7, "v": ids * 0.5}).groupby("k")
+                .agg(n=("v", "size"), s=("v", "sum")).reset_index())
+        assert _frames_equal(got, want)
 
 
 # -- observability surfaces -------------------------------------------------

@@ -328,10 +328,13 @@ def test_pgwire_counts_its_own_work(eng, annotations):
 
 
 def test_host_slow_statement_logs_its_phases(eng, monkeypatch, caplog):
+    # the threshold is pinned in both legs: what a warm Q6 costs the host
+    # on a shared CPU runner is not this test's to assert
+    monkeypatch.setattr(engine_mod, "HOST_SLOW_MS", 1e9)
     eng.query(QUERIES["q6"])
     n0 = GLOBAL.get("slow_query/host_slow")
     with caplog.at_level(logging.WARNING, logger="ydb_tpu.slow_query"):
-        eng.query(QUERIES["q6"])                 # warm: a few ms of host
+        eng.query(QUERIES["q6"])                 # under the threshold
         assert GLOBAL.get("slow_query/host_slow") == n0
         assert not caplog.records
         monkeypatch.setattr(engine_mod, "HOST_SLOW_MS", 0.0)

@@ -643,10 +643,10 @@ def tile_cache_key(pipe, scan_cols, K, CAP, sb_valid_names, builds_sig,
 def fused_cache_key(plan, scan_cols, K, CAP, sb_valid_names, builds_sig,
                     sort_spec, rank_assigns, param_names, lim_key=None,
                     compact_cap=None):
-    # the plan signature carries the group-by tuning (tile rows / gather
-    # batch cap / legacy flag): the cost gate for the tile count P runs
-    # at trace time from (capacity, tuning), so a knob flip must compile
-    # a fresh program rather than reuse one tiled differently.
+    # the plan signature carries the group-by tuning (tile rows, the
+    # late-mat lever): the cost gate for the tile count P runs at trace
+    # time from (capacity, tuning), so a knob flip must compile a fresh
+    # program rather than reuse one tiled differently.
     # `lim_key`: lifted-LIMIT plans key on the limit's capacity bucket
     # (("limB", bucket)) instead of the exact values — every LIMIT inside
     # one bucket shares one executable, the clamp rides in as __lim2

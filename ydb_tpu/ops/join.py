@@ -136,12 +136,10 @@ def build(block: HostBlock, key: str, payload_names: list[str],
     # side of this platform, but only the consumer's exact shape pins it:
     # the caller passes keep_fd when the consuming pipeline carries a
     # multi-key group-by (`Executor._prepare_builds`), and the FD lane
-    # only ever reads unique-keyed builds with the lattice ON; anything
-    # else (and any build past the budget) just skips the FD lane and
-    # keeps every key in the sort identity
-    from ydb_tpu.query.bounds import bounds_enabled
-    fd_block = block if keep_fd and unique and bounds_enabled() \
-        and block.length \
+    # only ever reads unique-keyed builds; anything else (and any build
+    # past the budget) just skips the FD lane and keeps every key in the
+    # sort identity
+    fd_block = block if keep_fd and unique and block.length \
         and sum(cd.data.nbytes
                 for cd in block.columns.values()) <= _FD_BUDGET \
         else None

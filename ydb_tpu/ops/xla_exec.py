@@ -264,6 +264,11 @@ def _sentinel(dtype, for_min: bool):
 _SMALL_DOMAIN_BUCKETS = 1 << 9     # one-hot 2-D reduction path bound
 _CHUNK_W = 64                      # buckets per one-hot chunk
 _SCATTER_MAX_BUCKETS = 1 << 16    # medium-domain single-scatter path bound
+# per-dtype batched (multi-column 2-D) gathers are emitted only while one
+# op reads at most this many rows; above it each column gathers alone
+# (byte-identical results). An (m, rows) gather past ~4M is the shape the
+# platform's compiler wedged on (PERF.md round-5).
+_GATHER_BATCH_ROWS = 1 << 22
 
 
 # --------------------------------------------------------------------------
@@ -272,25 +277,13 @@ _SCATTER_MAX_BUCKETS = 1 << 16    # medium-domain single-scatter path bound
 
 
 def groupby_tuning() -> tuple:  # lint: tuning-provider
-    """(tile_rows, batch_cap, legacy, bounds) resolved from the environment.
+    """(tile_rows, late_mat) resolved from the environment.
 
     * YDB_TPU_GROUPBY_TILE_ROWS — value-column gathers inside the sorted
       group-by split into tiles of at most this many rows (default 4M:
       the largest size at which 2-D gathers compile on the platform's
       remote TPU compiler — PERF.md round-5/8; tiny values force many
       tiles for tests);
-    * YDB_TPU_GATHER_BATCH_CAP — per-dtype batched (multi-column 2-D)
-      gathers are emitted only while a tile is at most this many rows;
-      0 disables batching entirely (per-column gathers, byte-identical
-      results);
-    * YDB_TPU_GROUPBY_LEGACY — any non-empty value other than "0" routes
-      to the pre-round-8 early-materializing lowering (A/B lever for the
-      CI gather-budget gate).
-
-    * YDB_TPU_BOUNDS — the bounds-lattice lever (`query/bounds.py`):
-      plans carry structurally different GroupBys (carry keys,
-      out_bounds) per setting, and the lever riding here puts it in
-      every compiled-program cache key by construction.
 
     * YDB_TPU_LATE_MAT — the late-materialization lever
       (`late_mat_enabled`): fused traces thread row-id vectors instead
@@ -301,18 +294,13 @@ def groupby_tuning() -> tuple:  # lint: tuning-provider
     The tuple is a component of every compiled-program cache key
     (ProgramCache, fused/tile/finalize/dist-agg keys), so flipping a knob
     recompiles instead of serving a trace built under other settings."""
-    from ydb_tpu.query.bounds import bounds_enabled
-
-    def _int(name: str, default: int) -> int:
-        try:
-            return int(os.environ.get(name, "") or default)
-        except ValueError:
-            return default
-    tile_rows = max(_int("YDB_TPU_GROUPBY_TILE_ROWS", 1 << 22), 8)
-    batch_cap = max(_int("YDB_TPU_GATHER_BATCH_CAP", 1 << 22), 0)
-    legacy = os.environ.get("YDB_TPU_GROUPBY_LEGACY", "") not in ("", "0")
-    return (tile_rows, batch_cap, legacy, bounds_enabled(),
-            late_mat_enabled())
+    tile_rows = 1 << 22
+    try:
+        tile_rows = int(os.environ.get("YDB_TPU_GROUPBY_TILE_ROWS", "")
+                        or tile_rows)
+    except ValueError:
+        pass
+    return (max(tile_rows, 8), late_mat_enabled())
 
 
 def late_mat_enabled() -> bool:  # lint: tuning-provider
@@ -663,8 +651,8 @@ def _groupby_medium_domain(cmd: ir.GroupBy, env, schema: Schema, sel,
                                strides, cap, firstpos)
 
 
-def _gather_sorted(cols: dict, perm, cap: int, tiles: int, tile_budget: int,
-                   batch_cap: int) -> dict:
+def _gather_sorted(cols: dict, perm, cap: int, tiles: int,
+                   tile_budget: int) -> dict:
     """Materialize env columns in key-sorted order: the ONLY place value
     columns are gathered at row-level granularity on the sorted path.
 
@@ -673,8 +661,8 @@ def _gather_sorted(cols: dict, perm, cap: int, tiles: int, tile_budget: int,
     compiler wedge (PERF.md round-5), which also re-unlocks the reverted
     per-dtype BATCHED gather: all requested columns of one dtype fold into
     one (m, tile) gather per tile (measured cost of a 2-8 column 2-D
-    gather equals ONE column's). `batch_cap` gates the batch by tile rows;
-    0 disables it (per-column gathers — byte-identical results)."""
+    gather equals ONE column's). `_GATHER_BATCH_ROWS` gates the batch by
+    tile rows (per-column gathers above it — byte-identical results)."""
     T = cap // tiles
     by_dt: dict = {}
     for name, arr in cols.items():
@@ -683,7 +671,7 @@ def _gather_sorted(cols: dict, perm, cap: int, tiles: int, tile_budget: int,
     for _dt, names in by_dt.items():
         arrs = [cols[n] for n in names]
         m = len(arrs)
-        if batch_cap > 0 and m > 1 and T <= batch_cap:
+        if m > 1 and T <= _GATHER_BATCH_ROWS:
             stacked = jnp.stack(arrs)                    # (m, cap)
             pieces = [stacked[:, perm[p * T:(p + 1) * T]]
                       for p in range(tiles)]             # (m, T) each
@@ -702,23 +690,28 @@ def _gather_sorted(cols: dict, perm, cap: int, tiles: int, tile_budget: int,
     return out
 
 
-def _csum_diffs(per_rows: list, starts, ends, oc: int, tile_budget: int,
-                batch_cap: int) -> list:
+def _csum_diffs(per_rows: list, starts, ends, oc: int,
+                tile_budget: int) -> list:
     """Per-group sums of sorted per-row arrays via cumulative-sum
     endpoints, evaluated at OUTPUT capacity: diff = c[end] − c[start] +
     v[start]. The cumsums stay 1-D (cheap on the platform; only 2-D ones
     wedge); the endpoint gathers batch per accumulation dtype — one
-    (m, oc) gather triple instead of 3 gathers per aggregate. `batch_cap`
-    gates the batch by oc exactly as `_gather_sorted` gates by tile rows:
-    with no proven out_bound oc == scan capacity, and an (m, cap) 2-D
-    gather is the ~4M compiler-wedge shape this module exists to avoid."""
+    (m, oc) gather triple instead of 3 gathers per aggregate.
+    `_GATHER_BATCH_ROWS` gates the batch by oc exactly as `_gather_sorted`
+    gates by tile rows: with no proven out_bound oc == scan capacity, and
+    an (m, cap) 2-D gather is the ~4M compiler-wedge shape this module
+    exists to avoid.
+
+    Precision: for a tiny group inside a huge total the cancellation
+    costs ~(total / group_sum)·1e-16 relative error — acceptable for SQL
+    doubles and the test oracles' 1e-6 tolerances."""
     out: list = [None] * len(per_rows)
     groups: dict = {}
     for i, pr in enumerate(per_rows):
         groups.setdefault(str(pr.dtype), []).append(i)
     for _dt, idxs in groups.items():
         csums = [cumsum(per_rows[i]) for i in idxs]
-        if batch_cap > 0 and len(idxs) > 1 and oc <= batch_cap:
+        if len(idxs) > 1 and oc <= _GATHER_BATCH_ROWS:
             cs = jnp.stack(csums)                        # (m, cap)
             fs = jnp.stack([per_rows[i] for i in idxs])
             ce, cst, f0 = cs[:, ends], cs[:, starts], fs[:, starts]
@@ -735,10 +728,9 @@ def _csum_diffs(per_rows: list, starts, ends, oc: int, tile_budget: int,
 def _segment_scan(vals, boundary, kind: str):
     """Running min/max within key segments of a sorted block: an
     associative scan over (value, segment-start flag) pairs — log-depth
-    elementwise, NO scatter (the legacy path paid one ~70-100 ms
-    scatter-reduce per min/max aggregate, the platform's most taxed op
-    class). Read at segment END positions it yields the whole-segment
-    reduction."""
+    elementwise, NO scatter (a scatter-reduce per min/max aggregate cost
+    ~70-100 ms, the platform's most taxed op class). Read at segment END
+    positions it yields the whole-segment reduction."""
     combine = jnp.minimum if kind == "min" else jnp.maximum
 
     def op(a, b):
@@ -774,12 +766,8 @@ def _trace_group_by_sorted(cmd: ir.GroupBy, env, schema: Schema, sel,
 
     `cmd.out_bound` is a PROVEN upper bound on ngroups: an understated
     value would silently drop groups, so only guaranteed sources may set
-    it. Precision of csum diffs is unchanged from the legacy path (see
-    `_trace_group_by_sorted_legacy`)."""
-    tile_budget, batch_cap, legacy, _bounds, _lm = groupby_tuning()
-    if legacy:
-        return _trace_group_by_sorted_legacy(cmd, env, schema, sel, length,
-                                             cap)
+    it. Precision of the csum diffs: see `_csum_diffs`."""
+    tile_budget, _lm = groupby_tuning()
     tiles = 1
     while cap // tiles > tile_budget and cap % (tiles * 2) == 0 \
             and cap // tiles > 1:
@@ -892,10 +880,10 @@ def _trace_group_by_sorted(cmd: ir.GroupBy, env, schema: Schema, sel,
             need_data.append(a.arg)
     data_s = _gather_sorted(
         {n: env[n][0] for n in dict.fromkeys(need_data)}, perm, cap, tiles,
-        tile_budget, batch_cap)
+        tile_budget)
     valid_s = _gather_sorted(
         {n: env[n][1] for n in dict.fromkeys(need_valid)}, perm, cap, tiles,
-        tile_budget, batch_cap)
+        tile_budget)
 
     # ---- phase 1: register every cumulative-sum job so endpoint gathers
     # batch per dtype across aggregates
@@ -927,7 +915,7 @@ def _trace_group_by_sorted(cmd: ir.GroupBy, env, schema: Schema, sel,
         else:
             raise ValueError(a.func)
 
-    diffs = _csum_diffs(jobs, starts, ends, oc, tile_budget, batch_cap)
+    diffs = _csum_diffs(jobs, starts, ends, oc, tile_budget)
 
     # ---- phase 2: assemble per-group outputs at oc capacity
     for (kind, a, data_j, cnt_j, m) in agg_plan:
@@ -955,149 +943,6 @@ def _trace_group_by_sorted(cmd: ir.GroupBy, env, schema: Schema, sel,
             data = env[a.arg][0][rowid]
             _count_gather(oc, tile_budget)
             new_env[a.out] = (data, any_valid)
-    return new_env, ngroups.astype(jnp.int32)
-
-
-def _trace_group_by_sorted_legacy(cmd: ir.GroupBy, env, schema: Schema, sel,
-                                  length, cap):
-    """Pre-round-8 sorted aggregation (YDB_TPU_GROUPBY_LEGACY=1): sort
-    (keys + row-id only), EARLY value materialization (every key and
-    aggregate column gathered at scan capacity), sums/counts via
-    cumulative-sum differences, min/max via one scatter-reduce per
-    aggregate. Kept as the A/B baseline for the CI gather-budget gate
-    and the byte-equality differential tests.
-
-    Precision note: a segment sum is csum[end] − csum[start] + v[start];
-    for a tiny group inside a huge total the cancellation costs ~(total /
-    group_sum)·1e-16 relative error — acceptable for SQL doubles and the
-    test oracles' 1e-6 tolerances."""
-    tile_budget, _batch_cap, _legacy, _bounds, _lm = groupby_tuning()
-    _t_inc("traces")
-    _t_inc("tiles", 1)
-    _t_max("sort_rows_max", cap)
-    record_sort(cap, 2 * len(cmd.keys) + 2)
-    iota = jnp.arange(cap, dtype=jnp.int32)
-    row_mask = iota < length
-    active = row_mask if sel is None else (row_mask & sel)
-
-    inactive = (~active).astype(jnp.int32)
-    sort_keys = [inactive]
-    for kname in cmd.keys:
-        d, v = env[kname]
-        enc = _sort_operand(d)
-        if v is not None:
-            enc = jnp.where(v, enc, _zero_like_operand(enc))
-            sort_keys.append(v.astype(jnp.int32))
-        else:
-            sort_keys.append(jnp.ones((cap,), jnp.int32))
-        sort_keys.append(enc)
-    # iota as the last key → deterministic total order, and the sort output
-    # IS the permutation (no carried operands)
-    out = sort_total(sort_keys, iota)
-    inactive_s = out[0]
-    keyparts_s = out[1:-1]
-    perm = out[-1]
-
-    env_s = {}
-
-    def sorted_col(name):
-        got = env_s.get(name)
-        if got is None:
-            d, v = env[name]
-            _count_gather(cap, tile_budget, value=True,
-                          ops=1 if v is None else 2)
-            got = (d[perm], v[perm] if v is not None else None)
-            env_s[name] = got
-        return got
-
-    active_s = inactive_s == 0
-    changed = jnp.zeros((cap,), jnp.bool_)
-    for kp in keyparts_s:
-        prev = jnp.concatenate([kp[:1], kp[:-1]])
-        neq = kp != prev
-        if np.issubdtype(np.dtype(kp.dtype), np.floating):
-            # NaN != NaN would split every NaN row into its own group;
-            # lax.sort places NaNs adjacently, so treat them as equal
-            neq = neq & ~(jnp.isnan(kp) & jnp.isnan(prev))
-        changed = changed | neq
-    boundary = active_s & ((iota == 0) | changed)
-    ngroups = jnp.sum(boundary.astype(jnp.int32))
-    nactive = jnp.sum(active_s.astype(jnp.int32))
-
-    # compact segment-start row indices to the front: starts[i] = sorted-row
-    # index where group i begins
-    record_sort(cap, 2)
-    starts = stable_argsort(jnp.where(boundary, iota, jnp.int32(cap)))
-    gi = jnp.arange(cap, dtype=jnp.int32)
-    next_start = jnp.concatenate([starts[1:], jnp.full((1,), cap, jnp.int32)])
-    ends = jnp.where(gi + 1 < ngroups, next_start - 1, nactive - 1)
-    ends = jnp.clip(ends, 0, cap - 1)
-    live = gi < ngroups
-
-    new_env = {}
-    for kname in list(cmd.keys) + list(cmd.carry_keys):
-        d, v = sorted_col(kname)
-        kd = d[starts]
-        _count_gather(cap, tile_budget)
-        dt = schema.dtype(kname)
-        if dt.nullable:
-            if v is not None:
-                kv = v[starts]
-                _count_gather(cap, tile_budget)
-            else:
-                kv = jnp.ones((cap,), jnp.bool_)
-            new_env[kname] = (kd, kv & live)
-        else:
-            new_env[kname] = (kd, None)
-
-    seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    seg_safe = jnp.where(active_s, seg, cap)
-
-    def csum_diff(per_row):
-        """Per-group sum of a sorted per-row array via cumsum endpoints."""
-        c = cumsum(per_row)
-        first = per_row[starts]
-        _count_gather(cap, tile_budget, ops=3)
-        return c[ends] - c[starts] + first
-
-    for a in cmd.aggs:
-        if a.func == "count_all":
-            data = csum_diff(active_s.astype(jnp.uint64))
-            new_env[a.out] = (jnp.where(live, data, 0), None)
-            continue
-        d, v = sorted_col(a.arg)
-        m = active_s if v is None else (active_s & v)
-        if a.func == "count":
-            data = csum_diff(m.astype(jnp.uint64))
-            new_env[a.out] = (jnp.where(live, data, 0), None)
-            continue
-        cnt = csum_diff(m.astype(jnp.int64))
-        any_valid = (cnt > 0) & live
-        if a.func == "sum":
-            acc = jnp.where(m, d, 0).astype(_acc_dtype(d))
-            new_env[a.out] = (csum_diff(acc), any_valid)
-        elif a.func in ("min", "max"):
-            sent = _sentinel(np.dtype(d.dtype), a.func == "min")
-            masked = jnp.where(m, d, sent)
-            init = jnp.full((cap + 1,), sent, d.dtype)
-            _t_inc("scatter_ops")
-            upd = (init.at[seg_safe].min(masked, mode="drop")
-                   if a.func == "min"
-                   else init.at[seg_safe].max(masked, mode="drop"))
-            data = jnp.where(any_valid, upd[:cap], jnp.zeros((), d.dtype))
-            new_env[a.out] = (data, any_valid)
-        elif a.func == "some":
-            # first valid value in the segment: rows are key-then-row-id
-            # sorted, so scan for the first m-true position per segment
-            pos = jnp.where(m, iota, cap)
-            init = jnp.full((cap + 1,), cap, jnp.int32)
-            _t_inc("scatter_ops")
-            firstpos = init.at[seg_safe].min(pos, mode="drop")[:cap]
-            data = d[jnp.clip(firstpos, 0, cap - 1)]
-            _count_gather(cap, tile_budget)
-            new_env[a.out] = (data, any_valid)
-        else:
-            raise ValueError(a.func)
     return new_env, ngroups.astype(jnp.int32)
 
 
@@ -1256,8 +1101,8 @@ class ProgramCache:
 
     def get(self, program: ir.Program, sig, cap, param_names):
         # groupby tuning is part of the identity: a program traced under
-        # one tile/batch setting must not serve another (tests flip the
-        # env knobs in-process)
+        # one tile setting must not serve another (tests flip the env
+        # knob in-process)
         key = (program.fingerprint(), sig, cap, param_names,
                groupby_tuning())
         # observability levers cannot stale a program: they choose how

@@ -315,8 +315,8 @@ class ShuffleJoin:
         payload_names = tuple(sorted(build_arrays["payload"]))
         pvalid_names = tuple(sorted(build_arrays["pvalid"]))
         # groupby_tuning: _build traces rest_programs/partial (GroupBy
-        # lowerings read the tile/batch/legacy knobs) — same identity
-        # rule as every other compiled-program cache key
+        # lowerings read the tile-rows knob) — same identity rule as
+        # every other compiled-program cache key
         key = (pcap, seg, rcap, bcap, payload_names, pvalid_names,
                tuple(sorted(params)), groupby_tuning())
         dev_params = {k: jnp.asarray(v) for k, v in params.items()}

@@ -9,38 +9,16 @@ at O(log n); the superblock pads the extra rows with zero-length
 sources, which the fused kernels already mask out via the per-row
 length vector, so padded execution is byte-equal to exact-K execution.
 
-`bucket_sources` is the single tuning provider every bucketed cache
-key must flow through: the bucketed K lands IN the superblock cache
-key and IN the fused/batched program keys, so flipping
-`YDB_TPU_SHAPE_BUCKETS` can never alias a padded program with an exact
-one. `YDB_TPU_SHAPE_BUCKETS=0` disables bucketing (exact K, byte-equal
-legacy shapes); any other value is the ladder ceiling above which K
-passes through unbucketed (default 4096 — a table that large has
-outgrown the single-superblock fused path anyway).
+`bucket_sources` is the single function every bucketed cache key must
+flow through: the bucketed K lands IN the superblock cache key and IN
+the fused/batched program keys. Above `CEILING` K passes through
+unbucketed — a table that large has outgrown the single-superblock
+fused path anyway.
 """
 
 from __future__ import annotations
 
-import os
-
-_DEFAULT_CEILING = 4096
-
-
-def bucket_ceiling() -> int:
-    """`YDB_TPU_SHAPE_BUCKETS` lever: 0 disables, else the largest K
-    the ladder covers (default 4096)."""
-    raw = os.environ.get("YDB_TPU_SHAPE_BUCKETS", "").strip()
-    if raw == "0":
-        return 0
-    try:
-        v = int(raw)
-    except ValueError:
-        v = 0
-    return v if v > 0 else _DEFAULT_CEILING
-
-
-def enabled() -> bool:
-    return bucket_ceiling() > 0
+CEILING = 4096                     # the largest K the ladder covers
 
 
 def ladder(limit: int) -> tuple:
@@ -59,12 +37,10 @@ def ladder(limit: int) -> tuple:
 
 def bucket_sources(k: int) -> int:  # lint: tuning-provider
     """Quantize a scan source count UP to its ladder bucket. Identity
-    when bucketing is off, K is degenerate, or K exceeds the ladder
-    ceiling."""
-    ceiling = bucket_ceiling()
-    if ceiling <= 0 or k <= 1 or k > ceiling:
+    when K is degenerate or exceeds the ladder ceiling."""
+    if k <= 1 or k > CEILING:
         return k
-    for b in ladder(ceiling):
+    for b in ladder(CEILING):
         if b >= k:
             return b
     return k
@@ -91,10 +67,9 @@ def segment_ladder(limit: int) -> tuple:
 def bucket_segment(n: int, minimum: int = 1) -> int:  # lint: tuning-provider
     """Quantize a collective segment size UP to its fine-ladder rung
     (at least `minimum`, itself rounded up to a rung). Unlike
-    `bucket_sources` this is NOT gated by `YDB_TPU_SHAPE_BUCKETS`:
-    planned redistribution always buckets its segments — the ladder IS
-    the shape-stability mechanism, not an optional compression of an
-    exact shape."""
+    `bucket_sources` it has no ceiling: planned redistribution always
+    buckets its segments — the ladder IS the shape-stability mechanism,
+    not an optional compression of an exact shape."""
     n = max(int(n), int(minimum), 1)
     if n <= 4:
         return n                      # 1, 2, 3, 4 are their own rungs

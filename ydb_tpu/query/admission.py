@@ -111,7 +111,7 @@ def estimate_plan_bytes(catalog, plan, snapshot) -> int:
     never block data): the executor enumerates the actual scan sources
     right after admission — re-walking blocks here would do it twice.
 
-    Bounds-lattice tightening (`query/bounds.py`, YDB_TPU_BOUNDS):
+    Bounds-lattice tightening (`query/bounds.py`):
       * the driving scan honors the plan's prune predicates against
         portion min/max stats — the q12/q20 prune-blind outlier class
         (a scan pruned to one month estimated at the full table);
@@ -130,10 +130,8 @@ def estimate_plan_bytes(catalog, plan, snapshot) -> int:
     import numpy as np
 
     from ydb_tpu.ops.xla_exec import late_mat_enabled
-    from ydb_tpu.query.bounds import (bounds_enabled, build_bytes_bound,
-                                      scan_rows_bound)
+    from ydb_tpu.query.bounds import build_bytes_bound, scan_rows_bound
     from ydb_tpu.utils.metrics import GLOBAL
-    lattice = bounds_enabled()
     late = late_mat_enabled()
     memo: dict = {}                    # one stats walk per plan node
 
@@ -143,7 +141,7 @@ def estimate_plan_bytes(catalog, plan, snapshot) -> int:
         except KeyError:
             return 0
         rows = getattr(table, "num_rows", 0)
-        if rows and lattice and pipe.scan.prune:
+        if rows and pipe.scan.prune:
             rows = min(rows, scan_rows_bound(catalog, pipe.scan, snapshot)
                        or rows)
         return int(rows)
@@ -189,12 +187,10 @@ def estimate_plan_bytes(catalog, plan, snapshot) -> int:
         if not hasattr(bp, "scan"):
             continue
         scan_est = pipe_bytes(bp)
-        if lattice:
-            bb = build_bytes_bound(catalog, step, snapshot, memo)
-            if bb and bb < scan_est:
-                GLOBAL.inc("bounds/admission_capped_bytes",
-                           scan_est - bb)
-                scan_est = bb
+        bb = build_bytes_bound(catalog, step, snapshot, memo)
+        if bb and bb < scan_est:
+            GLOBAL.inc("bounds/admission_capped_bytes", scan_est - bb)
+            scan_est = bb
         total += scan_est
         # the probe-time copy of this join's output columns
         if step.kind in ("inner", "left") and step.payload:

@@ -20,25 +20,13 @@ Two trust tiers, deliberately distinct:
     SIZING-QUALITY — consumed by admission estimates, segment sizing with
     overflow reruns, EXPLAIN, and counters. They trust declared PKs for
     join-multiplicity the same way the join planner ranks with them.
-
-`YDB_TPU_BOUNDS=0` disables the lattice end-to-end (plan stamping, the
-executor carry/bound rewrite, admission capping, segment shrinking) —
-byte-equal execution at capacity sizing, and part of the plan-cache
-fingerprint plus every compiled-program cache key via `groupby_tuning`.
 """
 
 from __future__ import annotations
 
-import os
-
 from ydb_tpu.ops import ir
 
 _BIG = 1 << 62
-
-
-def bounds_enabled() -> bool:  # lint: tuning-provider
-    """`YDB_TPU_BOUNDS` lever: unset/1 = on; 0 = capacity sizing."""
-    return os.environ.get("YDB_TPU_BOUNDS", "1").strip() != "0"
 
 
 def groupby_bound(gb: ir.GroupBy) -> int:
@@ -221,11 +209,8 @@ def plan_bound(plan, catalog, snapshot=None, _memo=None) -> int:
 def annotate_plan(plan, catalog, snapshot=None):
     """Stamp the lattice onto a freshly planned SELECT: every pipeline's
     `out_bound` (driving + build fragments, recursively) and the plan's
-    result bound. No-op with the lever off. Mutates the plan in place
-    (plans are per-query objects at this point; the plan cache stores
-    the annotated plan, and the fingerprint carries the lever)."""
-    if not bounds_enabled():
-        return plan
+    result bound. Mutates the plan in place (plans are per-query objects
+    at this point; the plan cache stores the annotated plan)."""
     from ydb_tpu.query.plan import QueryPlan
     from ydb_tpu.utils.metrics import GLOBAL
     memo: dict = {}                    # one stats walk per node

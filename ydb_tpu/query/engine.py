@@ -840,12 +840,11 @@ class QueryEngine:
             self._finish_stats(stats, t, block)
             return block
         from ydb_tpu.ops.xla_exec import late_mat_enabled
-        from ydb_tpu.query.bounds import bounds_enabled
-        # the bounds/late-mat levers change plan STRUCTURE (carry keys,
-        # stamped bounds, latemat annotations) — they must invalidate
-        # cached plans like a schema change
+        # the late-mat lever changes plan STRUCTURE (latemat
+        # annotations) — it must invalidate cached plans like a schema
+        # change
         fp = (self._table_fingerprint(stmt, stats.tables),
-              bounds_enabled(), late_mat_enabled())
+              late_mat_enabled())
         cached = self._plan_cache.get(sql) \
             if self.config.flag("enable_plan_cache") else None
         if cached is not None and cached[0] == fp:

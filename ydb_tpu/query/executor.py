@@ -1390,10 +1390,10 @@ class Executor:
           key the same way without payloads). Bucket-quantized so data
           growth recompiles at capacity-bucket granularity.
 
-        * CARRY keys (`YDB_TPU_BOUNDS`): grouping columns functionally
-          determined by a smaller determinant stop participating in the
-          group-by sort identity — q10's 7-key (16-sort-operand) group-by
-          collapses to its 1-key determinant, the keys materializing from
+        * CARRY keys: grouping columns functionally determined by a
+          smaller determinant stop participating in the group-by sort
+          identity — q10's 7-key (16-sort-operand) group-by collapses
+          to its 1-key determinant, the keys materializing from
           group leaders like everything else late-materialized. The
           dependency is verified, never assumed: the determinant is the
           join's own key (unique ⇒ determines every payload column), or
@@ -1408,7 +1408,6 @@ class Executor:
         cached plans are never mutated."""
         import dataclasses as _dc
 
-        from ydb_tpu.query.bounds import bounds_enabled
         from ydb_tpu.utils.metrics import GLOBAL
         pipe = plan.pipeline
         if pipe.partial is None or not pipe.partial.commands:
@@ -1461,28 +1460,27 @@ class Executor:
         # the keys it contributes and demote the rest to carried keys
         carry: list = []
         claimed: set = set()
-        if bounds_enabled():
-            for (step, bt, allowed, has_payload) in cands:
-                if not has_payload:
-                    continue
-                gj = [k for k in gb.keys
-                      if k in allowed and k not in claimed
-                      and k not in carry]
-                if len(gj) < 2:
-                    continue
-                det, measured = self._fd_determinant(step, bt, gj)
-                if det is None:
-                    continue
-                claimed.add(det)
-                for k in gj:
-                    if k != det:
-                        carry.append(k)
-                if measured is not None and keys <= allowed:
-                    # the measured distinct count of the FULL key tuple
-                    # is an exact ngroups bound for this execution —
-                    # tighter than build rows
-                    best = measured if best is None \
-                        else min(best, measured)
+        for (step, bt, allowed, has_payload) in cands:
+            if not has_payload:
+                continue
+            gj = [k for k in gb.keys
+                  if k in allowed and k not in claimed
+                  and k not in carry]
+            if len(gj) < 2:
+                continue
+            det, measured = self._fd_determinant(step, bt, gj)
+            if det is None:
+                continue
+            claimed.add(det)
+            for k in gj:
+                if k != det:
+                    carry.append(k)
+            if measured is not None and keys <= allowed:
+                # the measured distinct count of the FULL key tuple
+                # is an exact ngroups bound for this execution —
+                # tighter than build rows
+                best = measured if best is None \
+                    else min(best, measured)
 
         bound = gb.out_bound
         if best is not None:
@@ -2012,10 +2010,9 @@ class Executor:
                 # cheap to make (it compiles on its first run): an equal
                 # one made before holds the compiled programs.
                 # groupby_tuning in the key: the ShuffleJoin traces `rest`
-                # and `pipe.partial` (GroupBy lowerings read the tile/
-                # batch/legacy levers at trace time) — a knob flip must
-                # build a fresh join, not reuse a program tiled under old
-                # settings
+                # and `pipe.partial` (GroupBy lowerings read the tile-rows
+                # knob at trace time) — a knob flip must build a fresh
+                # join, not reuse a program tiled under old settings
                 sj = SJ.ShuffleJoin(self.mesh, in_schema, step.probe_key,
                                     step.kind, payload_cols,
                                     step.mark_col or "__mark", step.not_in,

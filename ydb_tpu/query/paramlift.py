@@ -44,7 +44,6 @@ member by `build_lift_values`, builds execute once per batch).
 from __future__ import annotations
 
 import dataclasses
-import os
 
 import numpy as np
 
@@ -54,12 +53,6 @@ from ydb_tpu.query.plan import Pipeline, QueryPlan
 LIFT_PREFIX = "__lit"
 # the lifted LIMIT+OFFSET device input is named by `ops/fused.LIMIT_PARAM`
 # ("__lim2") — the executor attaches it at dispatch time, not this pass
-
-
-def lift_enabled() -> bool:
-    """`YDB_TPU_PARAM_LIFT=0` restores literal-embedding plans (A/B
-    lever; the batch lane requires lifting and disables with it)."""
-    return os.environ.get("YDB_TPU_PARAM_LIFT", "1") not in ("0", "false")
 
 
 def _liftable(c: ir.Const) -> bool:
@@ -151,11 +144,8 @@ class _Lifter:
 
 
 def lift_plan(plan: QueryPlan) -> QueryPlan:
-    """Lift every literal in a freshly planned SELECT (no-op when
-    disabled). Idempotent by construction: lifted plans contain no
-    liftable Consts."""
-    if not lift_enabled():
-        return plan
+    """Lift every literal in a freshly planned SELECT. Idempotent by
+    construction: lifted plans contain no liftable Consts."""
     from ydb_tpu.utils.metrics import GLOBAL
     plan2 = _Lifter().queryplan(plan, top=True)
     if plan2.lift_names or any(

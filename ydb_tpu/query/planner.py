@@ -765,10 +765,7 @@ class Planner:
         subquery, string min/max, DISTINCT) keeps the expanding join."""
         import dataclasses as _dc
 
-        from ydb_tpu.query.bounds import bounds_enabled
         if not sel.group_by or not self._left_specs:
-            return sel
-        if not bounds_enabled():       # lever off: capacity-shaped plans
             return sel
         if any(isinstance(it.expr, ast.Star) for it in sel.items):
             return sel

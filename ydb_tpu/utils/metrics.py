@@ -338,8 +338,8 @@ COUNTER_REGISTRY = {
     "hive/leadership_lost": "leaders fenced by a lost lease",
     "hive/standby_promotions": "engines booted from a standby root",
     # -- sorted group-by trace counters (accrued at TRACE time; deltas
-    # visible only for freshly compiled shapes — the CI gather gate
-    # relies on that; emitted via _t_inc/_t_max in ops/xla_exec.py) ---------
+    # visible only for freshly compiled shapes; emitted via _t_inc/_t_max
+    # in ops/xla_exec.py) --------------------------------------------------
     "groupby/traces": "[viz] (dynamic) sorted group-by lowerings traced",
     "groupby/tiles": "[viz] (dynamic) tiles across those traces",
     "groupby/gather_ops":
@@ -347,11 +347,10 @@ COUNTER_REGISTRY = {
     "groupby/gather_ops_total": "[viz] (dynamic) every traced gather",
     "groupby/batched_gathers":
         "[viz] (dynamic) per-dtype multi-column 2-D gathers",
-    "groupby/scatter_ops": "[viz] (dynamic) scatter-reduces (legacy path)",
     "groupby/sort_rows_max": "[viz] (dynamic) group-by sort row watermark",
     "groupby/value_gather_rows_max":
         "[viz] (dynamic) value-column gather row watermark",
-    # -- bounds lattice (query/bounds.py, YDB_TPU_BOUNDS) ------------------
+    # -- bounds lattice (query/bounds.py) ----------------------------------
     "bounds/plans": "[viz] plans annotated by the bounds lattice",
     "bounds/finite_plans": "[viz] plans whose result bound is finite",
     "bounds/proven_rows":

@@ -20,7 +20,11 @@ None of that may change a single output byte:
   * a deferred SCAN column first referenced while the row positions are
     still the iota is read in place (`latemat/direct_cols`: TPC-H Q1's
     six); once a compact, compress, sort or limit has moved rows it is
-    gathered at the small shape (`latemat/gathered_cols`).
+    gathered at the small shape (`latemat/gathered_cols`);
+  * the one Compact sits directly after the last reducing join (PR 31:
+    TPC-H Q9 probes `partsupp` and `orders` at the bound), and where no
+    join reduces, or the reducing join is the last step, the program is
+    the one the end-placed Compact gave, to the byte.
 
 All aggregated columns hold integer-valued doubles, so sums are exact
 in float64 regardless of reduction order — capacity changes between the
@@ -38,7 +42,7 @@ from ydb_tpu.query import QueryEngine
 from ydb_tpu.utils import progstats
 from ydb_tpu.utils.metrics import GLOBAL
 
-from tests.tpch_util import QUERIES
+from tests.tpch_util import QUERIES, assert_frames_match, oracle
 
 
 @pytest.fixture(scope="module")
@@ -180,7 +184,7 @@ def test_forged_low_bound_reruns_loudly(eng, monkeypatch):
     off = eng.query(sql)                 # ~6000 live rows pre-group
     monkeypatch.setenv("YDB_TPU_LATE_MAT", "1")
     monkeypatch.setattr(eng.executor, "_compact_sizing",
-                        lambda *a, **k: 2048)
+                        lambda _key, pipe, *a: (2048, len(pipe.steps)))
     before = GLOBAL.get("latemat/compact_overflow_reruns")
     on = eng.query(sql)
     assert GLOBAL.get("latemat/compact_overflow_reruns") == before + 1
@@ -231,7 +235,7 @@ def test_repeat_runs_mint_no_new_programs(eng, monkeypatch):
 @pytest.fixture(scope="module")
 def tpch():
     e = QueryEngine()
-    load_tpch(e.catalog, sf=0.002)
+    e.tpch_data = load_tpch(e.catalog, sf=0.002)
     return e
 
 
@@ -316,13 +320,312 @@ def test_q1_forged_low_compact_reruns_loudly(tpch, monkeypatch):
     rerun at full capacity reads them in place; the answer is the same."""
     off = _lever_off(tpch, QUERIES["q1"], monkeypatch)
     monkeypatch.setattr(tpch.executor, "_compact_sizing",
-                        lambda *a, **k: 2048)
+                        lambda _key, pipe, *a: (2048, len(pipe.steps)))
     before = GLOBAL.get("latemat/compact_overflow_reruns")
     on, (direct, gathered), gathers = _latemat_reads(tpch, QUERIES["q1"])
     assert GLOBAL.get("latemat/compact_overflow_reruns") == before + 1
     assert (direct, gathered) == (6, 6)
     assert sorted(len(g) for g in gathers.values()) == [0, 6]
     _byte_equal(off, on)
+
+
+# -- the Compact sits where the rows fall, not after the last join ----------
+# (PR 31; CPU runs: positions, shapes, counters and answers, never a speed)
+
+
+def _attempt(eng) -> dict:
+    """Attributes of the statement's own `fused-attempt` span (a build
+    side that runs fused opens its own, further down the tree)."""
+    return next(s.attrs for s in eng.last_trace if s.name == "fused-attempt")
+
+
+def _gather_sizes(text: str) -> dict:
+    """{scope: {indices}} of an optimized HLO text's gathers: the first
+    `jax.named_scope` under the module's name (`join2.probe`,
+    `join0.payload[d1.w]`, `compact`), and how many indices its gathers
+    take (the output's leading dimension)."""
+    sizes: dict = {}
+    for n, scope in re.findall(
+            r' = \w+\[(\d+)[,\]][^\n]* gather\([^\n]*'
+            r'op_name="jit\([a-z0-9_]+\)/([^"/]*)', text):
+        sizes.setdefault(scope, set()).add(int(n))
+    return sizes
+
+
+def _own_program(eng, table: str = "lineitem") -> dict:
+    own, = [p for p in eng.last_stats.programs["programs"]
+            if p["name"].startswith(f"jit_{table}_")]
+    return own
+
+
+def _forge_lineitem(executor, monkeypatch, cap: int):
+    """Forge the statement's own Compact to `cap`, where the sizing put
+    it (at the end where the sizing refused one); a build side keeps
+    what it was given."""
+    real = executor._compact_sizing
+
+    def forged(base_key, pipe, *a):
+        got_cap, at = real(base_key, pipe, *a)
+        if pipe.scan.table != "lineitem":
+            return got_cap, at
+        return cap, len(pipe.steps) if at is None else at
+
+    monkeypatch.setattr(executor, "_compact_sizing", forged)
+
+
+@pytest.mark.parametrize("case", ["position", "oracle", "overflow",
+                                  "counter"])
+def test_q9_compacts_after_the_part_semi_join(tpch, monkeypatch, case):
+    """Q9's steps: supplier (inner, unfiltered), part (semi, `p_name
+    like`: the one reducing join), partsupp by the hashed composite key
+    (the binary search), the hash's verification, orders. The Compact
+    sits after the second of the five."""
+    sql = QUERIES["q9"]
+    tpch.query(sql)
+    tpch.query(sql)                      # builds cached, sizing settled
+    names = ("latemat/compact_early_plans", "latemat/compact_plans",
+             "latemat/compact_overflow_reruns")
+    before = [GLOBAL.get(n) for n in names]
+    if case == "overflow":
+        _forge_lineitem(tpch.executor, monkeypatch, 128)
+    got = tpch.query(sql)
+    assert tpch.executor.last_path == "fused"
+    early, plans, reruns = (GLOBAL.get(n) - b
+                            for n, b in zip(names, before))
+    at, cap = _attempt(tpch)["compact_at"], _attempt(tpch)["compact_cap"]
+    want = oracle("q9", tpch.tpch_data)
+    want.columns = list(got.columns)
+    if case == "position":
+        sizes = _gather_sizes(progstats.hlo_text(_own_program(tpch)["key"]))
+        plan = _explain(tpch, sql)
+        joins = re.findall(r"^  (\w+) JOIN probe=(\S+)", plan, flags=re.M)
+        assert [k for k, _p in joins] == ["INNER", "LEFT_SEMI", "INNER",
+                                          "INNER"], plan
+        assert joins[1][1] == "lineitem.l_partkey"
+        assert at == 2                   # of 5 steps: 4 joins, 1 program
+        # the probes before the Compact run at scan capacity, the
+        # composite key's twenty search steps and the orders probe at
+        # the bound, and so does every payload gather
+        cap0, = sizes["join0.probe"]
+        assert cap < cap0 // 2
+        assert sizes["join1.probe"] == {cap0}
+        assert sizes["join2.probe"] == sizes["join3.probe"] == {cap}
+        assert all(ns == {cap} for sc, ns in sizes.items()
+                   if ".payload[" in sc or sc.startswith("latemat[")), sizes
+        analyzed = "\n".join(tpch.query("explain analyze " + sql)["plan"])
+        assert re.search(rf"fused-attempt: .* compact_cap={cap} "
+                         r"compact_at=2$", analyzed, flags=re.M), analyzed
+    elif case == "oracle":
+        assert reruns == 0
+        assert_frames_match(got, want, ordered=True)
+    elif case == "overflow":
+        # overflows where it sits, after the semi join, and the rerun at
+        # full capacity carries no Compact
+        assert (at, cap) == (2, 128)
+        assert (early, plans, reruns) == (1, 1, 1)
+        assert_frames_match(got, want, ordered=True)
+    else:
+        assert (early, plans, reruns) == (1, 1, 0)
+        for other in ("q3", "q18"):
+            tpch.query(QUERIES[other])
+            tpch.query(QUERIES[other])
+            b = GLOBAL.get("latemat/compact_early_plans")
+            tpch.query(QUERIES[other])
+            assert GLOBAL.get("latemat/compact_early_plans") == b, other
+
+
+class _BuildSpy:
+    """Records what the executor hands `ops/fused.build_fused_fn` and
+    `fused_cache_key` for a statement's own program, and the arguments
+    its fill would capture the executable with."""
+
+    def __init__(self, executor, monkeypatch):
+        from ydb_tpu.ops import fused as F
+        self.real_build, self.real_key = F.build_fused_fn, F.fused_cache_key
+        self.builds, self.keys, self.args = [], [], []
+        real_fill = executor._fused_fill
+
+        def build(pipe, *a, **kw):
+            if pipe.scan.table == "lineitem":
+                self.builds.append(((pipe,) + a, kw))
+            return self.real_build(pipe, *a, **kw)
+
+        def key(plan, *a, **kw):
+            if plan.pipeline.scan.table == "lineitem" \
+                    and kw.get("compact_cap"):
+                self.keys.append(((plan,) + a, kw))
+            return self.real_key(plan, *a, **kw)
+
+        def fill(kind, key_, builder, capture_args, **kw):
+            self.args.append(capture_args)
+            return real_fill(kind, key_, builder, capture_args, **kw)
+
+        monkeypatch.setattr(F, "build_fused_fn", build)
+        monkeypatch.setattr(F, "fused_cache_key", key)
+        monkeypatch.setattr(executor, "_fused_fill", fill)
+
+    def lowered(self, call: tuple, **override) -> str:
+        a, kw = call
+        fn, _box = self.real_build(*a, **{**kw, **override})
+        # the statement's own fill is the last one (its builds' come first)
+        return fn.lower(*self.args[-1]).as_text()
+
+
+@pytest.mark.parametrize("q,early", [("q1", False), ("q6", False),
+                                     ("q3", False), ("q18", False),
+                                     ("q9", True)])
+def test_end_position_is_the_program_it_was(tpch, monkeypatch, q, early):
+    """Where no join reduces (Q1, Q6) or the reducing join is the last
+    step (Q3, Q18) the position is the end, and cache key, module name
+    and lowered text are what `compact_at=None` (the only placement
+    before PR 31) gives: the scan cell and two thirds of the join cell
+    compile nothing new. Q9 is the control: there they differ."""
+    sql = QUERIES[q]
+    tpch.query(sql)
+    tpch.query(sql)                      # builds cached, sizing settled
+    if q == "q1":
+        # Q1 compacts on a forged bound only: its own sizing refuses
+        _forge_lineitem(tpch.executor, monkeypatch, 2048)
+    tpch.executor._fused_cache.clear()
+    spy = _BuildSpy(tpch.executor, monkeypatch)
+    tpch.query(sql)
+    call = next(c for c in spy.builds if c[1].get("compact_prog"))
+    (pipe, *_rest), kw = call
+    assert (kw["compact_at"] < len(pipe.steps)) == early
+    here, at_end = spy.lowered(call), spy.lowered(call, compact_at=None)
+    name, = set(re.findall(r"module @(jit_\w+)", here))
+    assert re.fullmatch(r"jit_lineitem_\w*c_[0-9a-f]{6}", name)
+    assert f"module @{name} " in at_end
+    ka, kkw = spy.keys[-1]
+    key_here = spy.real_key(*ka, **kkw)
+    key_end = spy.real_key(*ka, **{**kkw, "compact_at": None})
+    assert kkw["compact_at"] == kw["compact_at"]
+    if early:
+        assert here != at_end and key_here != key_end
+        assert key_here[:-2] == key_end[:-2]
+        assert key_here[-2] == key_end[-2] + (kw["compact_at"],)
+    else:
+        assert here == at_end and key_here == key_end
+
+
+@pytest.fixture(scope="module")
+def star():
+    """A fact table whose FIRST join reduces (`d1` filtered to a ninth),
+    followed by kinds the Compact never preceded: an inner join with a
+    deferred payload (`d3`) and a LEFT join probed by a nullable key with
+    keys past its build (`d2`)."""
+    e = QueryEngine(block_rows=1 << 13)
+    rng = np.random.default_rng(31)
+    e.execute("create table fact (id Int64 not null, a Int64 not null, "
+              "b Int64, c Int64 not null, qty Double not null, "
+              "primary key (id)) with (store = column)")
+    e.execute("create table d1 (k Int64 not null, cat Int64 not null, "
+              "w Double not null, primary key (k)) with (store = column)")
+    e.execute("create table d2 (k Int64 not null, name Utf8, nv Double, "
+              "primary key (k)) with (store = column)")
+    e.execute("create table d3 (k Int64 not null, z Double not null, "
+              "primary key (k)) with (store = column)")
+    n, m = 6000, 400
+    b = pd.array(rng.integers(0, 500, n), dtype="Int64")   # 400..499: no row
+    b[::5] = pd.NA
+    nv = rng.integers(0, 500, m).astype(np.float64)
+    nv[::7] = np.nan
+    frames = {
+        "fact": pd.DataFrame({
+            "id": np.arange(n, dtype=np.int64),
+            "a": rng.integers(0, m, n), "b": b,
+            "c": rng.integers(0, 50, n),
+            "qty": rng.integers(1, 1000, n).astype(np.float64)}),
+        "d1": pd.DataFrame({
+            "k": np.arange(m, dtype=np.int64),
+            "cat": rng.integers(0, 9, m),
+            "w": rng.integers(1, 100, m).astype(np.float64)}),
+        "d2": pd.DataFrame({
+            "k": np.arange(m, dtype=np.int64),
+            "name": np.array([f"name#{i % 37:02d}" for i in range(m)],
+                             dtype=object),
+            "nv": nv}),
+        "d3": pd.DataFrame({
+            "k": np.arange(50, dtype=np.int64),
+            "z": rng.integers(1, 9, 50).astype(np.float64)}),
+    }
+    ver = e._next_version()
+    for name, df in frames.items():
+        t = e.catalog.table(name)
+        t.bulk_upsert(df, ver)
+        t.indexate()
+    e.frames = frames
+    return e
+
+
+_STAR_FROM = ("from fact join d1 on fact.a = d1.k "
+              "left join d2 on fact.b = d2.k join d3 on fact.c = d3.k "
+              "where d1.cat = 3 ")
+STAR = {
+    "rows": "select fact.id as id, w, name, nv, z " + _STAR_FROM
+            + "order by id",
+    "grouped": "select name, count(*) as c, sum(qty * w) as s, "
+               "sum(z) as sz " + _STAR_FROM + "group by name order by name",
+}
+
+
+def _star_oracle(frames: dict, shape: str) -> pd.DataFrame:
+    f, d1, d2, d3 = (frames[t] for t in ("fact", "d1", "d2", "d3"))
+    j = f.merge(d1[d1["cat"] == 3], left_on="a", right_on="k") \
+        .merge(d2, how="left", left_on="b", right_on="k",
+               suffixes=("", "_d2")) \
+        .merge(d3, left_on="c", right_on="k", suffixes=("", "_d3"))
+    if shape == "rows":
+        return j.sort_values("id")[["id", "w", "name", "nv", "z"]]
+    j = j.assign(s=j["qty"] * j["w"])
+    g = j.groupby("name", dropna=False).agg(
+        c=("id", "size"), s=("s", "sum"), sz=("z", "sum")).reset_index()
+    # NULL names sort first, as the engine's ascending order puts them
+    return g.sort_values("name", na_position="first")
+
+
+def _assert_star(got: pd.DataFrame, want: pd.DataFrame):
+    assert list(got.columns) == list(want.columns) and len(got) == len(want)
+    for col in got.columns:
+        g, w = got[col].to_numpy(), want[col].to_numpy()
+        assert (pd.isna(g) == pd.isna(w)).all(), col
+        keep = ~pd.isna(g)
+        assert (g[keep] == w[keep]).all(), col   # integer-valued doubles
+
+
+@pytest.mark.parametrize("shape", sorted(STAR))
+def test_compact_before_deferred_left_and_null_keyed_joins(star, monkeypatch,
+                                                           shape):
+    sql = STAR[shape]
+    want = _star_oracle(star.frames, shape)
+    star.query(sql)                      # builds cached
+    before = GLOBAL.get("latemat/compact_early_plans")
+    got = star.query(sql)
+    assert star.executor.last_path == "fused"
+    assert GLOBAL.get("latemat/compact_early_plans") == before + 1
+    at, cap = _attempt(star)["compact_at"], _attempt(star)["compact_cap"]
+    sizes = _gather_sizes(progstats.hlo_text(
+        _own_program(star, "fact")["key"]))
+    plan = _explain(star, sql)
+    assert re.findall(r"^  (\w+) JOIN probe=(\S+)", plan, flags=re.M) == [
+        ("INNER", "fact.a"), ("INNER", "fact.c"), ("LEFT", "fact.b")], plan
+    assert at == 1                       # after d1, before d3 and d2
+    _assert_star(got, want)
+    # `__lmr0` / `__lmf0` rode the Compact: d1's payload is gathered by
+    # the compacted row ids, at the bound, and so is every later join's
+    assert sizes["join0.probe"] == {1 << 13}
+    assert sizes["join1.probe"] == sizes["join2.probe"] == {cap}
+    assert sizes["join0.payload[d1.w]"] == {cap}
+    assert all(ns == {cap} for sc, ns in sizes.items() if ".payload[" in sc)
+    # and the statement without a Compact gives the same rows
+    real = star.executor._try_execute_fused
+    monkeypatch.setattr(
+        star.executor, "_try_execute_fused",
+        lambda *a, **k: real(*a, **{**k, "_no_compact": True}))
+    plain = star.query(sql)
+    assert GLOBAL.get("latemat/compact_early_plans") == before + 1
+    _assert_star(plain, want)
+    _byte_equal(plain, got)
 
 
 @pytest.mark.parametrize("counters,want", [

@@ -19,6 +19,7 @@ from ydb_tpu.query.executor import split_device_wait
 from ydb_tpu.utils import progstats, tracing
 from ydb_tpu.utils.metrics import GLOBAL
 
+from tests.test_latemat import _gather_sizes
 from tests.test_pgwire import PgClient
 from tests.tpch_util import QUERIES
 
@@ -211,6 +212,24 @@ def test_hlo_ops_carry_the_ir_scope(eng, q):
     # kinds and column names, never a literal
     assert not any("1994" in s or "1995" in s or "BUILDING" in s
                    for s in scoped)
+
+
+def test_q9_keeps_its_name_and_probes_after_its_compact(eng):
+    """PR 31 moved Q9's Compact from the end of its steps to directly
+    after the `part` semi join. The name is the plan's shape, not the
+    sizing, so it is the one the ledger's `breakdown` has had since PR 26;
+    the `join2.probe` scope (the composite key's binary search) is still
+    there, after the `compact/` scopes: its gathers take the bound's
+    index count, `join1.probe`'s the scan's."""
+    names = fused_programs(eng, QUERIES["q9"])
+    own = [p for p in eng.last_stats.programs["programs"]
+           if p["name"].startswith("jit_lineitem_")]
+    assert [p["name"] for p in own] == ["jit_lineitem_j4_gsc_346a01"]
+    assert names[own[0]["key"]] == own[0]["name"]
+    sizes = _gather_sizes(progstats.hlo_text(own[0]["key"]))
+    bound, = sizes["compact"]
+    assert sizes["join2.probe"] == {bound}
+    assert min(sizes["join1.probe"]) > 2 * bound
 
 
 def test_name_in_sysview_and_explain(eng):

@@ -152,7 +152,8 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
                 limit: Optional[int], offset: Optional[int],
                 keep: tuple, lift_limit: bool = False,
                 late_scan: frozenset = frozenset(),
-                compact_prog: Optional[ir.Program] = None):
+                compact_prog: Optional[ir.Program] = None,
+                compact_at: Optional[int] = None):
     """Un-jitted trace body shared by the single-query fused program
     (`build_fused_fn`) and the multi-query batched lane
     (`build_fused_batched_fn`, which vmaps it over stacked params).
@@ -178,13 +179,16 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
     (build row-id, match) pair (`ops/join.probe_lut_traced`) in place of
     their payload widths. `compact_prog` (an `ir.Compact` wrapper built
     by the executor) shrinks the working capacity to a ladder-quantized
-    bound after the joins, so deferred gathers and the partial group-by
-    run at the small shape: one int32 sort of the live positions, then
+    bound after the last reducing join — before `pipe.steps[compact_at]`
+    (`Executor._compact_sizing`; None: after the last step) — so every
+    later probe, deferred gather and the partial group-by run at the
+    small shape: one int32 sort of the live positions, then
     one bound-sized gather per column still in the env
-    (`xla_exec.compact_env`; deferred columns are not in it and gather
-    from the superblock through the compacted `__lmpos`). Its
-    live/overflow scalars come back in the 4th return element (the
-    executor's loud-rerun input)."""
+    (`xla_exec.compact_env`: `__lmpos` and the `__lmr<i>` / `__lmf<i>` of
+    the joins already probed among them; deferred columns are not in it
+    and gather from the superblock, or their build, through the
+    compacted row ids). Its live/overflow scalars come back in the 4th
+    return element (the executor's loud-rerun input)."""
     lim2 = None if limit is None else limit + (offset or 0)
     layout_box: dict = {}
 
@@ -288,8 +292,12 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
 
         if pipe.pre_program is not None:
             run(pipe.pre_program)
+        steps = list(pipe.steps)
+        if compact_prog is not None:
+            steps.insert(len(steps) if compact_at is None else compact_at,
+                         ("compact", compact_prog))
         bi = 0
-        for kind, step in pipe.steps:
+        for kind, step in steps:
             if kind == "join":
                 meta = join_metas[bi]
                 if meta["probe_key"] in deferred:
@@ -305,8 +313,6 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
                 schema = apply_join_schema(schema, meta["payload_cols"])
             else:
                 run(step)
-        if compact_prog is not None:
-            run(compact_prog)
         if pipe.partial is not None:
             run(pipe.partial)
         if final_program is not None:
@@ -378,7 +384,8 @@ def build_fused_fn(pipe, final_program: Optional[ir.Program],
                    limit: Optional[int], offset: Optional[int],
                    keep: tuple, lift_limit: bool = False,
                    late_scan: frozenset = frozenset(),
-                   compact_prog: Optional[ir.Program] = None):
+                   compact_prog: Optional[ir.Program] = None,
+                   compact_at: Optional[int] = None):
     """Compile the full single-node query pipeline into one jitted fn.
 
     scan_cols: [Column] of the flattened scan env (internal names).
@@ -401,7 +408,8 @@ def build_fused_fn(pipe, final_program: Optional[ir.Program],
                                  sort_spec, limit, offset, keep,
                                  lift_limit=lift_limit,
                                  late_scan=late_scan,
-                                 compact_prog=compact_prog)
+                                 compact_prog=compact_prog,
+                                 compact_at=compact_at)
     name = program_name(pipe, final_program, join_metas, rank_assigns,
                         sort_spec, limit, keep,
                         compact=compact_prog is not None)
@@ -642,7 +650,7 @@ def tile_cache_key(pipe, scan_cols, K, CAP, sb_valid_names, builds_sig,
 
 def fused_cache_key(plan, scan_cols, K, CAP, sb_valid_names, builds_sig,
                     sort_spec, rank_assigns, param_names, lim_key=None,
-                    compact_cap=None):
+                    compact_cap=None, compact_at=None):
     # the plan signature carries the group-by tuning (tile rows, the
     # late-mat lever): the cost gate for the tile count P runs at trace
     # time from (capacity, tuning), so a knob flip must compile a fresh
@@ -675,9 +683,14 @@ def fused_cache_key(plan, scan_cols, K, CAP, sb_valid_names, builds_sig,
             lim,
             tuple(n for (n, _lbl) in plan.output), tuple(param_names),
             # ladder-quantized compact capacity: a re-sized compact is a
-            # different program; the late-mat LEVER itself rides inside
-            # groupby_tuning(), so a flip can never reuse this trace
-            ("compact", int(compact_cap or 0)),
+            # different program, and so is one before an earlier step
+            # (the position follows the builds' row counts; the end, the
+            # only place before PR 31, keeps the key it had); the
+            # late-mat LEVER itself rides inside groupby_tuning(), so a
+            # flip can never reuse this trace
+            ("compact", int(compact_cap or 0))
+            + ((int(compact_at),) if compact_at is not None
+               and compact_at < len(pipe.steps) else ()),
             groupby_tuning())
 
 

@@ -368,9 +368,9 @@ class Executor:
                 return DeviceResultFuture.completed(
                     self._project_output(merged, plan.output))
 
-        with self._span("fused-attempt"):
+        with self._span("fused-attempt") as attempt:
             fused = self._try_execute_fused(plan, params, snapshot,
-                                            defer=True) \
+                                            defer=True, attempt=attempt) \
                 if self.enable_fused else None
         if isinstance(fused, tuple):           # tiled path: (kind, block)
             kind, block = fused
@@ -393,7 +393,7 @@ class Executor:
 
     def _try_execute_fused(self, plan: QueryPlan, params: dict,
                            snapshot: Snapshot, defer: bool = False,
-                           _no_compact: bool = False):
+                           _no_compact: bool = False, attempt=None):
         """Run the query as ONE fused device program (`ops/fused.py`) when
         its shape allows: single device, joins unique-keyed where
         payloads attach (expanding duplicate-key probes need a
@@ -405,7 +405,8 @@ class Executor:
         `DeviceResultFuture` deferring the single-pytree readout — the
         pipeline dispatch/readout seam); on fallback, the list of
         prepared join BuildTables (for `_run_pipeline` to reuse) or None
-        if none were prepared."""
+        if none were prepared. `attempt`: the statement's `fused-attempt`
+        span, which takes the Compact's `compact_cap` / `compact_at`."""
         from ydb_tpu.ops import fused as F
 
         pipe = plan.pipeline
@@ -485,14 +486,15 @@ class Executor:
                                      lim_key=lim_key)
         # bound-sized device compaction: when the filters/joins provably
         # collapse the live count, an `ir.Compact` shrinks the pipeline
-        # from scan capacity to a ladder-quantized bound before the
-        # partial group-by, and every deferred late-mat gather compiles
-        # at the small shape. Sized from CBO + FK selectivities plus the
+        # from scan capacity to a ladder-quantized bound directly after
+        # the last reducing join, and every later probe, deferred
+        # late-mat gather and the partial group-by compile at the small
+        # shape. Sized from CBO + FK selectivities plus the
         # measured-live memo; an underestimate trips the device overflow
         # flag in `fetch` and the statement reruns WITHOUT the compact —
         # loud and counted, never a silent truncation.
-        compact_cap = None if _no_compact else self._compact_sizing(
-            base_key, pipe, builds, sources, K * CAP)
+        compact_cap, compact_at = (None, None) if _no_compact else \
+            self._compact_sizing(base_key, pipe, builds, sources, K * CAP)
         compact_prog = None
         key = base_key
         if compact_cap:
@@ -502,7 +504,8 @@ class Executor:
                                     rank_assigns,
                                     tuple(sorted(all_params)),
                                     lim_key=lim_key,
-                                    compact_cap=compact_cap)
+                                    compact_cap=compact_cap,
+                                    compact_at=compact_at)
         from ydb_tpu.utils.metrics import GLOBAL
         ndeferred = len(late_scan) + sum(
             len(m["payload_names"]) for m in join_metas if m["late"])
@@ -511,6 +514,11 @@ class Executor:
         if compact_cap:
             GLOBAL.inc("latemat/compact_plans")
             GLOBAL.inc("latemat/compact_capacity_rows", compact_cap)
+            if compact_at < len(pipe.steps):
+                GLOBAL.inc("latemat/compact_early_plans")
+            if attempt is not None:
+                attempt.attrs.update(compact_cap=compact_cap,
+                                     compact_at=compact_at)
 
         def _builder():
             fn, layout_box = F.build_fused_fn(
@@ -518,7 +526,7 @@ class Executor:
                 join_metas, rank_assigns, sort_spec, plan.limit, plan.offset,
                 tuple(dict.fromkeys(n for (n, _lbl) in plan.output)),
                 lift_limit=lift_limit, late_scan=late_scan,
-                compact_prog=compact_prog)
+                compact_prog=compact_prog, compact_at=compact_at)
             keep = list(dict.fromkeys(n for (n, _lbl) in plan.output))
             out_cols = [c for c in schema.columns if c.name in keep] \
                 or list(schema.columns)
@@ -866,10 +874,10 @@ class Executor:
                                      tuple(sorted(all_params)),
                                      lim_key=lim_key)
         # MUST mirror the dispatch path's compact sizing exactly — a
-        # warm on a different capacity would compile a program the
-        # dispatch never asks for
-        compact_cap = self._compact_sizing(base_key, pipe, builds,
-                                           sources, K * CAP)
+        # warm on a different capacity or position would compile a
+        # program the dispatch never asks for
+        compact_cap, compact_at = self._compact_sizing(
+            base_key, pipe, builds, sources, K * CAP)
         compact_prog = None
         key = base_key
         if compact_cap:
@@ -879,7 +887,8 @@ class Executor:
                                     rank_assigns,
                                     tuple(sorted(all_params)),
                                     lim_key=lim_key,
-                                    compact_cap=compact_cap)
+                                    compact_cap=compact_cap,
+                                    compact_at=compact_at)
         if key in self._fused_cache:
             return False                 # already live — nothing to warm
 
@@ -889,7 +898,7 @@ class Executor:
                 join_metas, rank_assigns, sort_spec, plan.limit, plan.offset,
                 tuple(dict.fromkeys(n for (n, _lbl) in plan.output)),
                 lift_limit=lift_limit, late_scan=late_scan,
-                compact_prog=compact_prog)
+                compact_prog=compact_prog, compact_at=compact_at)
             keep = list(dict.fromkeys(n for (n, _lbl) in plan.output))
             out_cols = [c for c in schema.columns if c.name in keep] \
                 or list(schema.columns)
@@ -1038,10 +1047,16 @@ class Executor:
         return True, ("limB", bucket_capacity(lim2, minimum=128))
 
     def _compact_sizing(self, base_key, pipe, builds, sources,
-                        cap0: int) -> Optional[int]:
-        """Ladder-quantized capacity the fused pipeline compacts to
-        after its join steps, or None when compaction isn't worth a
-        shape (`ir.Compact` placement: `ops/fused._fused_body`).
+                        cap0: int) -> tuple:
+        """(capacity, position) of the fused pipeline's one `ir.Compact`:
+        the ladder-quantized capacity it compacts to, and the number of
+        `pipe.steps` entries that run before it — directly after the
+        last reducing join, the last JOIN this walk credits with a
+        ratio under 1 (q9: after the part-name semi, before the
+        partsupp and orders probes); the end of the steps where no join
+        lowered the estimate (q6: the scan's own `est_rows` did).
+        (None, None) when compaction isn't worth a shape (`ir.Compact`
+        placement: `ops/fused._fused_body`).
 
         The estimate is sizing-quality, not correctness-bearing — the
         device overflow flag catches every underestimate and the
@@ -1083,27 +1098,28 @@ class Executor:
         Only capacities under cap0/2 are worth the reshape."""
         from ydb_tpu.ops.xla_exec import late_mat_enabled
         if not late_mat_enabled():
-            return None
+            return None, None
         live = float(sum(b.length for b in sources)) if sources else 0.0
         if pipe.scan.est_rows >= 0:
             live = min(live, float(pipe.scan.est_rows))
         est = live
+        at = len(pipe.steps)
         bi = 0
-        for kind, step in pipe.steps:
+        for i, (kind, step) in enumerate(pipe.steps):
             if kind != "join":
                 continue
             bt = builds[bi]
             bi += 1
             if step.not_in:
                 continue
+            dom = 0
             if step.kind == "inner":
-                base = self._build_base_rows(step)
-                if base > 0:
-                    est *= min(1.0, float(int(bt.n)) / base)
+                dom = self._build_base_rows(step)
             elif step.kind == "left_semi":
                 dom = self._semi_key_domain(step)
-                if dom > 0:
-                    est *= min(1.0, float(int(bt.n)) / dom)
+            if int(bt.n) < dom:
+                est *= float(int(bt.n)) / dom
+                at = i + 1
         if pipe.out_bound and not (
                 pipe.partial is not None
                 and any(isinstance(c, ir.GroupBy)
@@ -1114,14 +1130,14 @@ class Executor:
         est = max(est, float(self._compact_memo.get(base_key, 0)))
         prev = self._compact_caps.get(base_key)
         if prev is not None and est <= prev:
-            return prev
+            return prev, at
         cand = shape_buckets.bucket_segment(
             max(int(est * 1.25) + 1, 1024))
         if cand >= cap0 // 2:
             self._compact_caps.pop(base_key, None)
-            return None
+            return None, None
         self._compact_caps[base_key] = cand
-        return cand
+        return cand, at
 
     def _build_base_rows(self, step: JoinStep) -> int:
         """Unfiltered base-table row count of a join's build side (the

@@ -386,6 +386,9 @@ COUNTER_REGISTRY = {
         "compress, sort or limit)",
     "latemat/compact_plans":
         "[viz] fused dispatches carrying a bound-sized ir.Compact",
+    "latemat/compact_early_plans":
+        "[viz] of those, dispatches whose Compact sits before the "
+        "pipeline's last step (directly after the last reducing join)",
     "latemat/compact_capacity_rows":
         "ladder-quantized compact capacities allocated (rows)",
     "latemat/compact_live_rows":

@@ -31,6 +31,8 @@ class MemoryAdmission:
 
     @contextmanager
     def admit(self, est_bytes: int):
+        """Hold `est_bytes` of the budget for the block; yields whether
+        the reservation had to queue behind others'."""
         from ydb_tpu.utils.metrics import GLOBAL, GLOBAL_HIST
         est = max(0, min(int(est_bytes), self.budget))
         with self._cv:
@@ -64,7 +66,7 @@ class MemoryAdmission:
             GLOBAL.set("admission/in_flight_bytes", self.in_flight)
             GLOBAL.set("admission/active_queries", self.active)
         try:
-            yield
+            yield waited
         finally:
             with self._cv:
                 self.in_flight -= est

@@ -919,8 +919,13 @@ class QueryEngine:
             # both waits under ONE span (critical-path extraction
             # classes it admission_wait; `phases["admission_ms"]`), so a
             # statement that queued here says so instead of leaving a gap
-            with self.tracer.span("admission-wait", admitted_mb=est >> 20):
-                if not self._pipe_sem.acquire(
+            # `in_flight_mb`: what others held reserved when this one
+            # arrived; `waited`: whether it queued for a slot or for bytes
+            with self.tracer.span(
+                    "admission-wait", admitted_mb=est >> 20,
+                    in_flight_mb=self.admission.in_flight >> 20) as sp:
+                waited = not self._pipe_sem.acquire(blocking=False)
+                if waited and not self._pipe_sem.acquire(
                         timeout=self.admission.timeout_s):
                     GLOBAL.inc("pipeline/window_timeouts")
                     raise AdmissionTimeout(
@@ -929,7 +934,8 @@ class QueryEngine:
                         "dispatched-or-queued for longer than the "
                         "admission deadline")
                 held.callback(self._pipe_sem.release)
-                held.enter_context(self.admission.admit(est))
+                waited |= held.enter_context(self.admission.admit(est))
+                sp.attrs["waited"] = waited
             return self._dispatch_drain_admitted(plan, snap, est)
 
     def _dispatch_drain_admitted(self, plan, snap, est: int) -> HostBlock:

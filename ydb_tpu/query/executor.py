@@ -233,6 +233,21 @@ class Executor:
         from ydb_tpu.utils.tracing import _NullSpanCtx
         return _NullSpanCtx()   # yields a throwaway span (attrs writable)
 
+    def _superblock(self, table, storage_names, rename, snapshot, prune,
+                    sources, src_ids, Kb: int):
+        """The `superblock-upload` span round the cache's stack of the
+        statement's scan columns; its attrs say what this touch of the
+        table cost: `bytes` stacked and uploaded now (0 on a resident
+        table), `columns`, `sources`, `hit`."""
+        info: dict = {}
+        with self._span("superblock-upload", columns=len(storage_names),
+                        sources=len(sources)) as sp:
+            sb = self.device_cache.superblock(
+                table, storage_names, rename, snapshot, prune, sources,
+                src_ids, pad_to=Kb, info=info)
+            sp.attrs.update(info)
+        return sb
+
     def _await_device(self, outputs, t_enqueued: float, prog_kid,
                       fresh: bool) -> None:
         """The `device-execute` span: block until the dispatched program's
@@ -450,11 +465,8 @@ class Executor:
                 plan, params, pipe, sources, scan_cols, builds, join_metas,
                 dicts, partial_schema)
 
-        with self._span("superblock-upload"):
-            sb = self.device_cache.superblock(table, storage_names, rename,
-                                              snapshot,
-                                              pipe.scan.prune or None,
-                                              sources, src_ids, pad_to=Kb)
+        sb = self._superblock(table, storage_names, rename, snapshot,
+                              pipe.scan.prune or None, sources, src_ids, Kb)
         if sb is None:
             return builds or None          # empty scan → portioned path
         arrays, valids, lengths, K, CAP, sb_dicts = sb
@@ -1232,10 +1244,8 @@ class Executor:
                                               pad_to=Kb) \
                 > self.fused_scan_budget_bytes:
             return None                  # empty / tiled-class scan
-        with self._span("superblock-upload"):
-            sb = self.device_cache.superblock(table, storage_names, rename,
-                                              snapshot, None, sources,
-                                              src_ids, pad_to=Kb)
+        sb = self._superblock(table, storage_names, rename, snapshot, None,
+                              sources, src_ids, Kb)
         if sb is None:
             return None
         arrays, valids, lengths, K, CAP, sb_dicts = sb

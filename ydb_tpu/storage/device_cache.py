@@ -20,6 +20,7 @@ import numpy as np
 from ydb_tpu.core.block import HostBlock
 from ydb_tpu.ops.device import DeviceBlock, bucket_capacity
 from ydb_tpu.storage.portion import Portion
+from ydb_tpu.utils.metrics import GLOBAL, Timer
 
 import os as _os
 
@@ -112,11 +113,15 @@ class DeviceColumnCache:
         # LRU bookkeeping (uploads serialize on the device link anyway)
         self._mu = threading.RLock()
 
-    def _evict(self):
-        while self.bytes + self.foreign_bytes > self.budget \
+    def _evict(self, room: int = 0):
+        """Drop LRU entries until `room` more bytes fit the budget (called
+        under `_mu`); what goes is counted."""
+        while self.bytes + self.foreign_bytes + room > self.budget \
                 and self._entries:
             _key, (_d, _v, nbytes) = self._entries.popitem(last=False)
             self.bytes -= nbytes
+            GLOBAL.inc("devcache/evictions")
+            GLOBAL.inc("devcache/evicted_bytes", nbytes)
 
     def acquire_foreign(self, nbytes: int) -> None:
         """Register device bytes owned by another long-lived cache
@@ -134,10 +139,7 @@ class DeviceColumnCache:
         set — for paths that allocate device memory the cache doesn't
         track (tiled scan stacks, spill partials)."""
         with self._mu:
-            while self.bytes + self.foreign_bytes + nbytes > self.budget \
-                    and self._entries:
-                _key, (_d, _v, nb) = self._entries.popitem(last=False)
-                self.bytes -= nb
+            self._evict(nbytes)
 
     def _lookup(self, key):
         with self._mu:
@@ -149,10 +151,18 @@ class DeviceColumnCache:
                 self.misses += 1
             return hit
 
-    def _insert(self, key, data, valid, nbytes):
+    def _insert(self, key, data, valid, nbytes,
+                built: Optional[Timer] = None):
         """Insert a freshly built entry; a concurrent builder of the same
         key may have won the race — keep the existing entry (dropping the
-        duplicate upload) so bytes accounting stays exact."""
+        duplicate upload) so bytes accounting stays exact. `built`: the
+        timer started before a HOST-built entry's stack and transfer —
+        given, the entry counts as an upload (bytes that crossed the
+        link, whether or not it won the race)."""
+        if built is not None:
+            GLOBAL.inc("devcache/uploads")
+            GLOBAL.inc("devcache/upload_bytes", nbytes)
+            GLOBAL.inc("devcache/upload_ms", built.ms())
         with self._mu:
             hit = self._entries.get(key)
             if hit is not None:
@@ -183,6 +193,7 @@ class DeviceColumnCache:
             return hit[0], hit[1]
         put = (lambda x: jax.device_put(x, device)) if device is not None \
             else jnp.asarray
+        built = Timer()
         cd = portion.block.columns[col]
         cap = bucket_capacity(max(portion.num_rows, 1))
         pad = cap - portion.num_rows
@@ -196,11 +207,11 @@ class DeviceColumnCache:
         memledger.record_padded_buffers(
             "portion_column", "scan_columns", portion.num_rows, cap,
             data, valid)
-        return self._insert(key, data, valid, nbytes)
+        return self._insert(key, data, valid, nbytes, built)
 
     def superblock(self, table, storage_names: list, rename: dict,
                    snapshot, prune, sources=None, src_ids=None,
-                   pad_to: int = 0):
+                   pad_to: int = 0, info: Optional[dict] = None):
         """Stacked (K, CAP) device arrays covering every visible scan source
         of `table` — the input of the whole-query fused program
         (`ydb_tpu/ops/fused.py`), one upload per column per data version.
@@ -215,6 +226,10 @@ class DeviceColumnCache:
         bucket's compiled program instead of minting a shape per count.
         The EFFECTIVE row count rides the cache key (an exact-K stack
         and its padded sibling are different device arrays).
+
+        `info`: given a dict, it is told what this call cost: `bytes`
+        stacked on the host and uploaded now (0 on a resident table) and
+        `hit`, whether every entry it asked for was resident.
 
         Returns (arrays {internal: (K,CAP)}, valids {internal: (K,CAP)},
         lengths jnp (K,), K, CAP, dicts) or None when the table has no
@@ -234,10 +249,12 @@ class DeviceColumnCache:
         lengths_np = np.zeros(K, np.int32)
         lengths_np[:len(sources)] = [b.length for b in sources]
         arrays, valids, dicts = {}, {}, {}
+        uploaded, all_hit = 0, True
         for s in storage_names:
             out = rename.get(s, s)
             key = ("sbc", src_key, s)
             hit = self._lookup(key)
+            all_hit &= hit is not None
             if hit is not None:
                 arrays[out] = hit[0]
                 if hit[1] is not None:
@@ -287,6 +304,7 @@ class DeviceColumnCache:
                     valids[out] = v
             else:
                 # stack + upload OUTSIDE the mutex (see column())
+                built = Timer()
                 dtype = sources[0].columns[s].data.dtype
                 stack = np.zeros((K, CAP), dtype=dtype)
                 has_valid = any(b.columns[s].valid is not None
@@ -301,7 +319,8 @@ class DeviceColumnCache:
                 d = jnp.asarray(stack)
                 v = jnp.asarray(vstack) if vstack is not None else None
                 nbytes = d.nbytes + (v.nbytes if v is not None else 0)
-                d, v = self._insert(key, d, v, nbytes)
+                uploaded += nbytes
+                d, v = self._insert(key, d, v, nbytes, built)
                 arrays[out] = d
                 if v is not None:
                     valids[out] = v
@@ -313,11 +332,17 @@ class DeviceColumnCache:
 
         lkey = ("sbl", src_key)
         lhit = self._lookup(lkey)
+        all_hit &= lhit is not None
         if lhit is None:
+            built = Timer()
             lengths = jnp.asarray(lengths_np)
-            lengths, _ = self._insert(lkey, lengths, None, lengths.nbytes)
+            uploaded += lengths.nbytes
+            lengths, _ = self._insert(lkey, lengths, None, lengths.nbytes,
+                                      built)
         else:
             lengths = lhit[0]
+        if info is not None:
+            info.update(bytes=uploaded, hit=all_hit)
         return arrays, valids, lengths, K, CAP, dicts
 
     def device_block(self, portion: Portion, columns: list,

@@ -458,6 +458,28 @@ COUNTER_REGISTRY = {
     "device_cache/hits": "(derived) HBM column cache hits",
     "device_cache/misses": "(derived) HBM column cache misses",
     "device_cache/bytes": "(derived) HBM column cache residency",
+    # what the three above cannot say: the traffic over the host link and
+    # what the budget pushed out, counted where it happens (`storage/
+    # device_cache.py`), process-wide, so a window's delta can be read
+    "devcache/uploads":
+        "entries stacked on the host and handed to the device (a "
+        "device-side stack of stage landings inserts without one); past "
+        "warm-up it should stand still: one that climbs says a table's "
+        "columns are being rebuilt (new data version, or evicted and "
+        "asked for again)",
+    "devcache/upload_bytes":
+        "bytes of those uploads (data + validity); against "
+        "device_cache/bytes it says how often the resident set was "
+        "paid for (2x after a cold start: the compile-ahead thunk)",
+    "devcache/upload_ms":
+        "host wall of those uploads: the stack or pad and the transfer's "
+        "enqueue (the copy itself may end later, inside the program's "
+        "wait); the part of a first statement's latency the cache owes",
+    "devcache/evictions":
+        "entries dropped to stay under the HBM budget; with uploads "
+        "climbing beside it the working set does not fit: raise "
+        "YDB_TPU_HBM_BUDGET or shard the table",
+    "devcache/evicted_bytes": "bytes of the entries dropped",
     # -- critical-path analysis (utils/critpath.py): the blocking-chain
     # decomposition of query wall — crit/<class>_ms accumulate via the
     # wildcard family below --------------------------------------------------

@@ -125,7 +125,11 @@ def annotate(device_ops: list, programs: dict) -> list:
     for op, sec in device_ops:
         module, _, rest = op.partition("/")
         prog = programs.get(re.sub(r"[(_]\.\..*$", "", module), {})
-        info = prog.get("ops", {}).get(re.split(r"[ _]", rest)[0])
+        # `fusion.3 fusion:Custom`, and the compiler's own names with
+        # underscores in them: `select_reduce_fusion.1 fusion:Loop`
+        ops = prog.get("ops", {})
+        info = ops.get(rest.split(" ")[0]) \
+            or ops.get(re.split(r"[ _]", rest)[0])
         if info is None:
             scopes = "?"
         else:

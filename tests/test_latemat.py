@@ -24,7 +24,11 @@ None of that may change a single output byte:
   * the one Compact sits directly after the last reducing join (PR 31:
     TPC-H Q9 probes `partsupp` and `orders` at the bound), and where no
     join reduces, or the reducing join is the last step, the program is
-    the one the end-placed Compact gave, to the byte.
+    the one the end-placed Compact gave, to the byte;
+  * a Compact is planned only where what follows it is priced per row
+    (PR 34): a keyless aggregate over a filtered scan (TPC-H Q6) keeps
+    its mask and sums in place, with no sort and no gather
+    (`latemat/compact_skipped_plans`, `compact_skipped=keyless-tail`).
 
 All aggregated columns hold integer-valued doubles, so sums are exact
 in float64 regardless of reduction order — capacity changes between the
@@ -271,7 +275,7 @@ def test_q1_reads_its_deferred_columns_in_place(tpch, monkeypatch):
     off = _lever_off(tpch, QUERIES["q1"], monkeypatch)
     assert "latemat: 6 deferred" in _explain(tpch, QUERIES["q1"])
     tpch.query(QUERIES["q1"])
-    tpch.query(QUERIES["q1"])   # the first may overflow a Compact and rerun
+    tpch.query(QUERIES["q1"])
     on, (direct, gathered), gathers = _latemat_reads(tpch, QUERIES["q1"])
     assert (direct, gathered) == (6, 0)
     (name, found), = gathers.items()
@@ -280,11 +284,47 @@ def test_q1_reads_its_deferred_columns_in_place(tpch, monkeypatch):
     _byte_equal(off, on)
 
 
+def test_q6_sums_in_place_and_plans_no_compact(tpch, monkeypatch):
+    """Q6's tail is a keyless masked sum: its estimate qualifies for a
+    Compact (126 of 16 384 slots) and the tail declines it, so
+    `l_extendedprice` is first read while `__lmpos` is still the iota:
+    no `compact/sort` of every position, no gather (five at the bound at
+    SF1), the program Q1's shape has."""
+    off = _lever_off(tpch, QUERIES["q6"], monkeypatch)
+    assert "latemat: 1 deferred" in _explain(tpch, QUERIES["q6"])
+    tpch.query(QUERIES["q6"])
+    names = ("latemat/compact_skipped_plans", "latemat/compact_plans")
+    before = [GLOBAL.get(n) for n in names]
+    on, (direct, gathered), gathers = _latemat_reads(tpch, QUERIES["q6"])
+    assert [GLOBAL.get(n) - b for n, b in zip(names, before)] == [1, 0]
+    assert (direct, gathered) == (1, 0)
+    (name, found), = gathers.items()
+    assert re.fullmatch(r"jit_lineitem_g_[0-9a-f]{6}", name)   # no `c`
+    assert found == []
+    text = progstats.hlo_text(_own_program(tpch)["key"])
+    assert not re.search(r" (?:sort|gather|scatter)\(", text)
+    assert _attempt(tpch) == {"compact_skipped": "keyless-tail"}
+    analyzed = "\n".join(tpch.query("explain analyze "
+                                    + QUERIES["q6"])["plan"])
+    assert re.search(r"fused-attempt: .* compact_skipped=keyless-tail$",
+                     analyzed, flags=re.M), analyzed
+    # the same rows, added in the scan's order and not the bound's
+    assert list(off.columns) == list(on.columns)
+    np.testing.assert_allclose(on.to_numpy(), off.to_numpy(), rtol=1e-12)
+
+
+# Q6's predicates over a tail that does gather: a sorted group-by
+_Q6_BY_ORDER = QUERIES["q6"].replace(
+    "select sum(", "select l_orderkey, sum(") \
+    + " group by l_orderkey order by l_orderkey"
+
 MOVED_ROWS = {
     # (statement, deferred scan columns of its own program, byte-equal)
-    # a Compact before the first reference; a sum over the compacted rows
-    # adds in another order than over the scan's, so no bytes to compare
-    "compact": (QUERIES["q6"], 1, False),
+    # a Compact before the first reference (no join; Q6 itself stood here
+    # until its keyless tail declined the Compact, PR 34); a group's sum
+    # over the compacted rows may add in another order than over the
+    # scan's, so no bytes to compare
+    "compact": (_Q6_BY_ORDER, 2, False),
     # a join, then the Compact
     "join-compact": (QUERIES["q3"], 2, True),
     # no filter, no Compact: the tail's compress and LIMIT slice
@@ -483,8 +523,9 @@ def test_end_position_is_the_program_it_was(tpch, monkeypatch, q, early):
     sql = QUERIES[q]
     tpch.query(sql)
     tpch.query(sql)                      # builds cached, sizing settled
-    if q == "q1":
-        # Q1 compacts on a forged bound only: its own sizing refuses
+    if q in ("q1", "q6"):
+        # Q1 and Q6 compact on a forged bound only: their tails (a
+        # 12-bucket one-hot, a keyless sum) decline the Compact (PR 34)
         _forge_lineitem(tpch.executor, monkeypatch, 2048)
     tpch.executor._fused_cache.clear()
     spy = _BuildSpy(tpch.executor, monkeypatch)
@@ -626,6 +667,182 @@ def test_compact_before_deferred_left_and_null_keyed_joins(star, monkeypatch,
     assert GLOBAL.get("latemat/compact_early_plans") == before + 1
     _assert_star(plain, want)
     _byte_equal(plain, got)
+
+
+# -- a Compact is planned only where its tail gathers (PR 34) ---------------
+# (CPU runs: the decision, the plan's span, keys and counters, never a speed)
+
+
+def _tail_case(steps=(), partial=None, metas=(), at=None):
+    from types import SimpleNamespace as NS
+    steps = [("join", NS()) if st == "join" else ("program", st)
+             for st in steps]
+    return (NS(steps=steps, partial=partial), list(metas),
+            len(steps) if at is None else at)
+
+
+def _tail_cases() -> dict:
+    from ydb_tpu.core.dtypes import DType, Kind
+    from ydb_tpu.ops import ir, xla_exec as X
+    f64 = DType(Kind.FLOAT64, False)
+
+    def over(col, keys=(), **kw):
+        return ir.Program([
+            ir.Assign("x", ir.call("mul", ir.Col("a"), ir.Col(col))),
+            ir.GroupBy(tuple(keys), (ir.Agg("s", "sum", "x"),), **kw)])
+
+    keyless = over("b")
+    late = {"late": True, "payload_names": ("p.w",)}
+    semi = {"late": False, "payload_names": ()}
+    reads_w = ir.Program([ir.Filter(ir.call(
+        "lt", ir.Col("p.w"), ir.Const(3.0, f64)))])
+    assert X._INPLACE_BUCKETS == 64      # priced: PERF.md round 34
+    return {
+        # id: ((steps, partial, metas, at), the reason or None)
+        "keyless-no-join": (_tail_case(partial=keyless), "keyless-tail"),
+        "keyless-after-semi-join": (_tail_case(
+            ["join", ir.Program([ir.Filter(ir.Col("f"))])], keyless,
+            [semi], at=1), "keyless-tail"),
+        "keyless-over-late-payload": (_tail_case(
+            ["join"], over("p.w"), [late]), None),
+        "late-payload-unread-by-tail": (_tail_case(
+            ["join"], keyless, [late]), "keyless-tail"),
+        "late-payload-read-before-position": (_tail_case(
+            ["join", reads_w], keyless, [late]), "keyless-tail"),
+        "late-payload-read-after-position": (_tail_case(
+            ["join", reads_w], keyless, [late], at=1), None),
+        "join-after-position": (_tail_case(
+            ["join", "join"], keyless, [semi, semi], at=1), None),
+        "sorted-groupby": (_tail_case(partial=over("b", ["k"])), None),
+        "medium-domain-groupby": (_tail_case(
+            partial=over("b", ["k"], key_domains=(4000,))), None),
+        "small-domain-12-buckets": (_tail_case(
+            partial=over("b", ["k"], key_domains=(11,))),
+            "small-domain-tail"),
+        "small-domain-64-buckets": (_tail_case(
+            partial=over("b", ["k"], key_domains=(7, 7))),
+            "small-domain-tail"),
+        "small-domain-65-buckets": (_tail_case(
+            partial=over("b", ["k"], key_domains=(64,))), None),
+        "groupby-in-a-step": (_tail_case(
+            [over("b", ["k"])], keyless, at=0), None),
+        "rows-out": (_tail_case(), None),
+    }
+
+
+@pytest.mark.parametrize("case", sorted(_tail_cases()))
+def test_tail_decision_table(case):
+    """`latemat.tail_reads_in_place`: the one input `_compact_sizing` has
+    beside the row estimate. One function says how a group-by lowers
+    (`xla_exec.groupby_route`), for the trace and for this."""
+    from ydb_tpu.ops import ir, xla_exec as X
+    from ydb_tpu.query import latemat
+    (pipe, metas, at), want = _tail_cases()[case]
+    assert latemat.tail_reads_in_place(pipe, metas, at) == want
+    for cmd in (c for c in (pipe.partial.commands if pipe.partial else ())
+                if isinstance(c, ir.GroupBy)):
+        route, nb = X.groupby_route(cmd)
+        assert (X.reads_in_place(cmd) is not None) == (
+            route == "keyless"
+            or (route == "small-domain" and nb <= X._INPLACE_BUCKETS))
+
+
+@pytest.fixture(scope="module")
+def tpch_fresh():
+    """An engine no other test has forged, sized or warmed."""
+    e = QueryEngine()
+    e.tpch_data = load_tpch(e.catalog, sf=0.002)
+    return e
+
+
+_Q6_ROWS = QUERIES["q6"].replace(
+    "select sum(l_extendedprice*l_discount) as revenue",
+    "select l_orderkey, l_extendedprice") \
+    + " order by l_orderkey, l_extendedprice"
+
+PLANNED = {
+    # id: (statement, compact_skipped or None, digest of the statement's
+    # own `fused_cache_key` as the parent commit 7665ebc gives it)
+    "q6": (QUERIES["q6"], "keyless-tail", None),
+    "q1-twelve-buckets": (QUERIES["q1"], "small-domain-tail", None),
+    "q14-keyless-over-late-payload": (QUERIES["q14"], None, None),
+    "sorted-groupby": (_Q6_BY_ORDER, None, None),
+    "rows-out-order-by": (_Q6_ROWS, None, None),
+    "q3": (QUERIES["q3"], None, "0f3e96776936"),
+    "q9": (QUERIES["q9"], None, "c0dd02a128e9"),
+    "q18": (QUERIES["q18"], None, "8183ef615f10"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PLANNED))
+def test_compact_is_planned_by_the_tail(tpch_fresh, monkeypatch, case):
+    """Real plans through `_compact_sizing`: every statement's estimate
+    qualifies (under half the scan capacity); only the keyless tail with
+    no deferred join payload, and Q1's 12-bucket one-hot, decline. Q3 /
+    Q9 / Q18 (the join cell) keep
+    their cache keys to the byte: the rule changed no plan there."""
+    import hashlib
+    eng = tpch_fresh
+    sql, skipped, digest = PLANNED[case]
+    eng.query(sql)
+    eng.query(sql)                       # builds cached, sizing settled
+    spy = _BuildSpy(eng.executor, monkeypatch)
+    names = ("latemat/compact_skipped_plans", "latemat/compact_plans")
+    before = [GLOBAL.get(n) for n in names]
+    eng.query(sql)
+    assert eng.executor.last_path == "fused"
+    delta = [GLOBAL.get(n) - b for n, b in zip(names, before)]
+    attrs, own = _attempt(eng), _own_program(eng)
+    if skipped:
+        assert delta == [1, 0]
+        assert attrs == {"compact_skipped": skipped}
+        assert re.fullmatch(r"jit_lineitem_(?:j\d+_)?[gsl]+_[0-9a-f]{6}",
+                            own["name"])   # no `c` among the marks
+    else:
+        assert delta == [0, 1]
+        assert set(attrs) == {"compact_cap", "compact_at"}
+        assert re.fullmatch(r"jit_lineitem_(?:j\d+_)?[gsl]*c_[0-9a-f]{6}",
+                            own["name"])
+    if digest:
+        ka, kkw = spy.keys[-1]           # the key with the Compact in it
+        key = spy.real_key(*ka, **kkw)
+        assert hashlib.sha1(repr(key).encode()).hexdigest()[:12] == digest
+
+
+@pytest.mark.parametrize("q", ["q6", "q3"])
+def test_warm_registers_the_key_the_dispatch_hits(monkeypatch, q):
+    """The compile-ahead thunk and the dispatch read one decision: the
+    warm builds Q6's program without a Compact (Q3's with one) and the
+    statement then finds it; no second `lineitem` program is built."""
+    eng = QueryEngine()
+    load_tpch(eng.catalog, sf=0.002)
+    ex = eng.executor
+    real_fill, fills, live = ex._fused_fill, [], []
+
+    def fill(kind, key, builder, capture_args, **kw):
+        if any(c[0].startswith("lineitem.") for c in key[1]):
+            fills.append((key, kw.get("source", "fresh")))
+        return real_fill(kind, key, builder, capture_args, **kw)
+
+    def warm_now(plan, params, snapshot):
+        if plan.pipeline.scan.table == "lineitem":
+            assert ex._fused_warm(plan, dict(params), snapshot)
+            live.append(len(ex._fused_cache))
+        return False
+
+    monkeypatch.setattr(ex, "_fused_fill", fill)
+    monkeypatch.setattr(ex, "compile_ahead", warm_now)
+    hits = GLOBAL.get("prog/compile_ahead_hits")
+    eng.query(QUERIES[q])
+    assert eng.executor.last_path == "fused"
+    assert GLOBAL.get("prog/compile_ahead_hits") == hits + 1
+    # one fill, the warm's; the dispatch found its key live
+    (key, source), = fills
+    assert source == "compile_ahead" and key in ex._fused_cache
+    assert live == [len(ex._fused_cache)]
+    name = _own_program(eng)["name"]
+    assert ("c_" in name) == (q == "q3"), name
+    assert (key[-2] == ("compact", 0)) == (q == "q6")
 
 
 @pytest.mark.parametrize("counters,want", [

@@ -82,6 +82,16 @@ def _compile(fn, *args):
     return compiled, took
 
 
+def _as_spec(x, sharding, shape=None):
+    """The shape of a captured program argument, placed on `sharding`
+    (`shape`: rebuilt at another size); a non-array passes through."""
+    if not hasattr(x, "shape"):
+        return x
+    return jax.ShapeDtypeStruct(
+        x.shape if shape is None else shape, x.dtype, sharding=sharding,
+        weak_type=bool(getattr(x, "weak_type", False)))
+
+
 def _block_args(sig, cap, sharding):
     """(arrays, valids, length, params) shapes of a ProgramCache program."""
     arrays = {n: jax.ShapeDtypeStruct((cap,), dt.DType(dt.Kind(k), nu).np,
@@ -158,6 +168,52 @@ def test_compact_at_scan_capacity(one_chip, no_compile_cache):
     assert out_d["k"].shape == (1 << 18,)
 
 
+@pytest.mark.parametrize("sources", [6, 64])
+def test_keyless_tail_in_place_at_scan_capacity(one_chip, no_compile_cache,
+                                                monkeypatch, sources):
+    """Q6 as the fused path builds it since PR 34 — no `ir.Compact`, the
+    keyless sum over the selection mask, `l_extendedprice` read in place —
+    rebuilt at SF1's (6 x 1 Mi slots) and SF10's (64 x 1 Mi) scan
+    capacity: the chip's compiler leaves no sort and no gather in it."""
+    import re
+
+    from tests.tpch_util import QUERIES
+    from ydb_tpu.bench.tpch_gen import load_tpch
+    from ydb_tpu.ops import fused as F
+    from ydb_tpu.query import QueryEngine
+
+    eng = QueryEngine()
+    load_tpch(eng.catalog, sf=0.002)
+    built, filled = [], []
+    real_build, real_fill = F.build_fused_fn, eng.executor._fused_fill
+
+    def build(pipe, final, scan_cols, K, CAP, *a, **kw):
+        built.append((pipe, final, scan_cols, a, kw))
+        return real_build(pipe, final, scan_cols, K, CAP, *a, **kw)
+
+    def fill(kind, key, builder, capture_args, **kw):
+        filled.append(capture_args)
+        return real_fill(kind, key, builder, capture_args, **kw)
+
+    monkeypatch.setattr(F, "build_fused_fn", build)
+    monkeypatch.setattr(eng.executor, "_fused_fill", fill)
+    eng.query(QUERIES["q6"])
+    assert eng.executor.last_path == "fused"
+    (pipe, final, scan_cols, a, kw), = built
+    assert kw["compact_prog"] is None
+    K, CAP = sources, 1 << 20
+    fn, _box = real_build(pipe, final, scan_cols, K, CAP, *a, **kw)
+
+    def spec(x):                         # superblock (K, CAP), lengths (K,)
+        return _as_spec(x, one_chip,
+                        ((), (K,), (K, CAP))[getattr(x, "ndim", 0)])
+
+    compiled, _s = _compile(fn, *jax.tree_util.tree_map(spec, filled[-1]))
+    text = compiled.as_text()
+    assert "jit_lineitem_g_" in text
+    assert not re.search(r" (?:sort|gather|scatter)\(", text)
+
+
 def test_fused_join_program(one_chip, no_compile_cache, monkeypatch):
     """A fused join + group-by + sort + limit program of the q3 class, as
     `ops/fused.py` builds it: captured at the AOT seam in a small CPU
@@ -185,14 +241,8 @@ def test_fused_join_program(one_chip, no_compile_cache, monkeypatch):
     fn, args = max(fused,
                    key=lambda fa: len(jax.tree_util.tree_leaves(fa[1])))
 
-    def spec(x):
-        if not hasattr(x, "shape"):
-            return x
-        return jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip,
-            weak_type=bool(getattr(x, "weak_type", False)))
-
-    _compile(fn, *jax.tree_util.tree_map(spec, args))
+    _compile(fn, *jax.tree_util.tree_map(
+        lambda x: _as_spec(x, one_chip), args))
 
 
 def test_four_device_shuffle_has_all_to_all(topo, no_compile_cache):

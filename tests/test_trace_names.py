@@ -35,13 +35,16 @@ LITERALS = {
 # what the gather / scatter ops of the query's programs must be scoped by
 SCOPES = {
     "q1": {"groupby"},
-    "q6": {"latemat[", "compact"},
+    "q6": set(),
     "q3": {"latemat[", "groupby", "compact", "join0.probe"},
 }
 # and what none of a program may be: Q1's deferred columns are first read
 # while the row positions are still the iota, so the program that does not
-# compact first reads them in place (PR 29); {query: (program, scope)}
-NO_SCOPES = {"q1": ("jit_lineitem_gs_", "latemat[")}
+# compact first reads them in place (PR 29); Q6's keyless sum plans no
+# Compact at all (PR 34): its one program gathers and scatters nothing;
+# {query: (program, scopes)}
+NO_SCOPES = {"q1": ("jit_lineitem_gs_", ("latemat[",)),
+             "q6": ("jit_lineitem_g_", ("latemat[", "compact"))}
 PROGRAM_SPANS = ("statement", "parse", "plan", "admission-wait", "execute",
                  "fused-attempt", "join-builds", "superblock-upload",
                  "device-dispatch", "device-execute", "readout-transfer",
@@ -206,9 +209,14 @@ def test_hlo_ops_carry_the_ir_scope(eng, q):
         assert any(want in s for s in scoped), (want, sorted(scoped))
     if q in NO_SCOPES:
         prefix, bad = NO_SCOPES[q]
+        assert any(n.startswith(prefix) for n in by_name), sorted(by_name)
         own = [s for n, ss in by_name.items() if n.startswith(prefix)
                for s in ss]
-        assert own and not any(bad in s for s in own), (bad, sorted(own))
+        # Q1's group-by gathers; Q6's program holds no gather at all
+        assert bool(own) == bool(SCOPES[q])
+        assert not any(b in s for b in bad for s in own), (bad, sorted(own))
+    if q == "q6":                       # no second program with a `c` mark
+        assert all(n.startswith("jit_lineitem_g_") for n in by_name)
     # kinds and column names, never a literal
     assert not any("1994" in s or "1995" in s or "BUILDING" in s
                    for s in scoped)
@@ -364,3 +372,32 @@ def test_host_slow_statement_logs_its_phases(eng, monkeypatch, caplog):
     assert "select sum(l_extendedprice*l_discount)" in line
     for part in ("wall", "queue_ms", "device_ms", "unspanned"):
         assert part in line
+
+
+@pytest.mark.parametrize("op,want", [
+    ("jit_lineitem_gc_410372(..9580)/fusion.3 fusion:Custom",
+     "compact/gather"),
+    # the compiler's own name for a fusion has underscores in it
+    ("jit_lineitem_gc_410372(..9580)/select_reduce_fusion.1 fusion:Loop",
+     "groupby/reduce_sum | reduce: groupby/reduce_sum"),
+    ("jit_lineitem_gc_410372(..9580)/sort.5 sort", "compact/sort"),
+    ("jit_lineitem_gc_410372(..9580)/fusion.99 fusion:Loop", "?"),
+])
+def test_op_scopes_names_a_breakdowns_operation(op, want):
+    """`scripts/op_scopes.annotate`: a traced line's `device_ops` entry to
+    the scopes the program's HLO text gave that instruction."""
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parent.parent / "scripts" / "op_scopes.py"
+    spec = importlib.util.spec_from_file_location("op_scopes", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    programs = {"jit_lineitem_gc_410372": {"ops": {
+        "fusion.3": {"scope": "compact/gather", "inner": {}},
+        "sort.5": {"scope": "compact/sort", "inner": {}},
+        "select_reduce_fusion.1": {
+            "scope": "groupby/reduce_sum",
+            "inner": {"reduce": ["groupby/reduce_sum"]}},
+    }}}
+    (_op, sec, scopes), = mod.annotate([[op, 1.5]], programs)
+    assert (sec, scopes) == (1.5, want)

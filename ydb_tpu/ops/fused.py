@@ -178,7 +178,10 @@ def _fused_body(pipe, final_program: Optional[ir.Program],
     Joins whose meta carries `late` likewise thread a
     (build row-id, match) pair (`ops/join.probe_lut_traced`) in place of
     their payload widths. `compact_prog` (an `ir.Compact` wrapper built
-    by the executor) shrinks the working capacity to a ladder-quantized
+    by the executor; None where the rows do not collapse, and where what
+    follows reads them in place: a keyless aggregate such as Q6's sums
+    over the selection mask, `latemat.tail_reads_in_place`) shrinks the
+    working capacity to a ladder-quantized
     bound after the last reducing join — before `pipe.steps[compact_at]`
     (`Executor._compact_sizing`; None: after the last step) — so every
     later probe, deferred gather and the partial group-by run at the

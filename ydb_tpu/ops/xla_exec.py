@@ -34,7 +34,11 @@ scatter is priced by its updates, 67 ns each for a float64 column, a
 gather by its indices, 9-12 ns each a 32-bit stream, and a one-word
 6.29 M-row sort at about 10 ms: so `ir.Compact` (`compact_env`) sorts the
 live positions once and gathers each column at the bound, and writes
-nothing of scan width per column.
+nothing of scan width per column. And (PERF.md round 34) that a masked
+reduction over the scan costs the same rows whether 2 % or 100 % of them
+are live and less than that sort alone: so a keyless aggregate plans no
+Compact in front of it (`reads_in_place`; TPC-H Q6 keeps its mask and
+sums where the scan left the rows).
 """
 
 from __future__ import annotations
@@ -263,6 +267,18 @@ def _sentinel(dtype, for_min: bool):
 
 _SMALL_DOMAIN_BUCKETS = 1 << 9     # one-hot 2-D reduction path bound
 _CHUNK_W = 64                      # buckets per one-hot chunk
+# a small-domain group-by of at most this many buckets (one one-hot chunk)
+# counts as read in place (`reads_in_place`): it costs buckets x rows over
+# the masked scan, a Compact in front of it a sort of every position and a
+# gather a column before buckets x bound. Priced on the v5e by
+# `scripts/compact_micro.py --sections tail` (PERF.md round 34; Q1's eleven
+# aggregates, 10 % of 6 Mi / 64 Mi slots live): in place 6.8 / 70.5 ms at
+# 12 buckets, 16.7 / 183.8 at 64, 47.2 / 541.8 at 192, 123.3 / 1 437 at
+# 512; behind the Compact 78.8-95.8 / 2 472-2 685: they cross near 375
+# buckets at 6 Mi and not under 512 at 64 Mi. At 64 buckets in place is
+# also under the cheapest Compact measured (Q6's: 1.8 % live, two columns,
+# 18.7 / 314.8 ms), so it wins whatever the filter keeps.
+_INPLACE_BUCKETS = _CHUNK_W
 _SCATTER_MAX_BUCKETS = 1 << 16    # medium-domain single-scatter path bound
 # per-dtype batched (multi-column 2-D) gathers are emitted only while one
 # op reads at most this many rows; above it each column gathers alone
@@ -946,22 +962,54 @@ def _trace_group_by_sorted(cmd: ir.GroupBy, env, schema: Schema, sel,
     return new_env, ngroups.astype(jnp.int32)
 
 
-def _trace_group_by(cmd: ir.GroupBy, env, schema: Schema, sel, length, cap):
-    """GroupBy dispatch: keyless → plain reductions; small bounded domains →
-    one-hot 2-D reduction; medium bounded → scatter-reduce; unbounded →
-    sort-based. Returns (new_env, new_length)."""
+def groupby_route(cmd: ir.GroupBy) -> tuple:
+    """(route, buckets): which lowering `_trace_group_by` gives `cmd`:
+    `keyless` (1 bucket), `small-domain`, `medium-domain` (their bucket
+    count), or `sorted` (0: unbounded). The one place that decides it:
+    the trace dispatches on it and `Executor._compact_sizing` prices a
+    pipeline's tail by it (`reads_in_place`)."""
     if not cmd.keys:
-        iota = jnp.arange(cap, dtype=jnp.int32)
-        active = (iota < length) if sel is None else ((iota < length) & sel)
-        return _groupby_global(cmd, env, active, iota)
+        return "keyless", 1
     if cmd.key_domains and all(d > 0 for d in cmd.key_domains):
         nb = 1
         for d in cmd.key_domains:
             nb *= d + 1
         if nb <= _SMALL_DOMAIN_BUCKETS:
-            return _groupby_small_domain(cmd, env, schema, sel, length, cap)
+            return "small-domain", nb
         if nb + 1 <= _SCATTER_MAX_BUCKETS:
-            return _groupby_medium_domain(cmd, env, schema, sel, length, cap)
+            return "medium-domain", nb
+    return "sorted", 0
+
+
+def reads_in_place(cmd: ir.GroupBy) -> Optional[str]:
+    """The name of `cmd`'s route where it reads each live row once, where
+    the scan left it, and moves nothing per row: masked reductions over
+    the selection mask (keyless; a one-hot of at most `_INPLACE_BUCKETS`
+    buckets) cost the same rows whether 2 % or 100 % are live, and less
+    than the Compact in front would (a sort of every position, a gather
+    a column). None where the lowering gathers or scatters per row
+    (medium-domain, sorted) and is priced by the rows it is given, or
+    its buckets x rows pass what the Compact costs."""
+    route, nb = groupby_route(cmd)
+    if route == "keyless" or (route == "small-domain"
+                              and nb <= _INPLACE_BUCKETS):
+        return route
+    return None
+
+
+def _trace_group_by(cmd: ir.GroupBy, env, schema: Schema, sel, length, cap):
+    """GroupBy dispatch (`groupby_route`): keyless → plain reductions; small
+    bounded domains → one-hot 2-D reduction; medium bounded →
+    scatter-reduce; unbounded → sort-based. Returns (new_env, new_length)."""
+    route, _nb = groupby_route(cmd)
+    if route == "keyless":
+        iota = jnp.arange(cap, dtype=jnp.int32)
+        active = (iota < length) if sel is None else ((iota < length) & sel)
+        return _groupby_global(cmd, env, active, iota)
+    if route == "small-domain":
+        return _groupby_small_domain(cmd, env, schema, sel, length, cap)
+    if route == "medium-domain":
+        return _groupby_medium_domain(cmd, env, schema, sel, length, cap)
     return _trace_group_by_sorted(cmd, env, schema, sel, length, cap)
 
 

@@ -501,12 +501,16 @@ class Executor:
         # from scan capacity to a ladder-quantized bound directly after
         # the last reducing join, and every later probe, deferred
         # late-mat gather and the partial group-by compile at the small
-        # shape. Sized from CBO + FK selectivities plus the
+        # shape; none where what follows reads its rows in place (a
+        # keyless aggregate: `latemat.tail_reads_in_place`). Sized from
+        # CBO + FK selectivities plus the
         # measured-live memo; an underestimate trips the device overflow
         # flag in `fetch` and the statement reruns WITHOUT the compact —
         # loud and counted, never a silent truncation.
+        declined: dict = {}
         compact_cap, compact_at = (None, None) if _no_compact else \
-            self._compact_sizing(base_key, pipe, builds, sources, K * CAP)
+            self._compact_sizing(base_key, pipe, builds, sources, K * CAP,
+                                 join_metas, declined)
         compact_prog = None
         key = base_key
         if compact_cap:
@@ -531,6 +535,10 @@ class Executor:
             if attempt is not None:
                 attempt.attrs.update(compact_cap=compact_cap,
                                      compact_at=compact_at)
+        elif declined:
+            GLOBAL.inc("latemat/compact_skipped_plans")
+            if attempt is not None:
+                attempt.attrs.update(declined)
 
         def _builder():
             fn, layout_box = F.build_fused_fn(
@@ -889,7 +897,7 @@ class Executor:
         # warm on a different capacity or position would compile a
         # program the dispatch never asks for
         compact_cap, compact_at = self._compact_sizing(
-            base_key, pipe, builds, sources, K * CAP)
+            base_key, pipe, builds, sources, K * CAP, join_metas)
         compact_prog = None
         key = base_key
         if compact_cap:
@@ -1059,16 +1067,24 @@ class Executor:
         return True, ("limB", bucket_capacity(lim2, minimum=128))
 
     def _compact_sizing(self, base_key, pipe, builds, sources,
-                        cap0: int) -> tuple:
+                        cap0: int, join_metas: list,
+                        declined: Optional[dict] = None) -> tuple:
         """(capacity, position) of the fused pipeline's one `ir.Compact`:
         the ladder-quantized capacity it compacts to, and the number of
         `pipe.steps` entries that run before it — directly after the
         last reducing join, the last JOIN this walk credits with a
         ratio under 1 (q9: after the part-name semi, before the
         partsupp and orders probes); the end of the steps where no join
-        lowered the estimate (q6: the scan's own `est_rows` did).
+        lowered the estimate (the scan's own `est_rows` did: a filtered
+        scan that returns rows or groups them by a wide key).
         (None, None) when compaction isn't worth a shape (`ir.Compact`
-        placement: `ops/fused._fused_body`).
+        placement: `ops/fused._fused_body`), and where the estimate is
+        but what runs from the position on reads its rows in place
+        (`latemat.tail_reads_in_place`; q6's keyless sum: the sort of
+        every scan position and five gathers were 79 % of its time at
+        SF10, PERF.md round 34). `declined` then takes
+        `compact_skipped=<reason>`: the dispatch counts it
+        (`latemat/compact_skipped_plans`), the compile-ahead does not.
 
         The estimate is sizing-quality, not correctness-bearing — the
         device overflow flag catches every underestimate and the
@@ -1109,6 +1125,7 @@ class Executor:
 
         Only capacities under cap0/2 are worth the reshape."""
         from ydb_tpu.ops.xla_exec import late_mat_enabled
+        from ydb_tpu.query import latemat
         if not late_mat_enabled():
             return None, None
         live = float(sum(b.length for b in sources)) if sources else 0.0
@@ -1140,13 +1157,17 @@ class Executor:
             # rows only when no partial group-by sits between
             est = min(est, float(pipe.out_bound))
         est = max(est, float(self._compact_memo.get(base_key, 0)))
-        prev = self._compact_caps.get(base_key)
-        if prev is not None and est <= prev:
-            return prev, at
-        cand = shape_buckets.bucket_segment(
-            max(int(est * 1.25) + 1, 1024))
-        if cand >= cap0 // 2:
-            self._compact_caps.pop(base_key, None)
+        cand = self._compact_caps.get(base_key)
+        if cand is None or est > cand:
+            cand = shape_buckets.bucket_segment(
+                max(int(est * 1.25) + 1, 1024))
+            if cand >= cap0 // 2:
+                self._compact_caps.pop(base_key, None)
+                return None, None
+        why = latemat.tail_reads_in_place(pipe, join_metas, at)
+        if why is not None:
+            if declined is not None:
+                declined["compact_skipped"] = why
             return None, None
         self._compact_caps[base_key] = cand
         return cand, at

@@ -64,6 +64,60 @@ def deferrable_joins(pipe) -> list:
     return out
 
 
+def tail_reads_in_place(pipe, join_metas, at: int):
+    """Why the fused pipeline wants no `ir.Compact` before `pipe.steps[at]`
+    (`Executor._compact_sizing`), or None where it may: a Compact sorts
+    every scan position and gathers each column still in the env so that
+    what FOLLOWS runs at a small shape, which pays only where what
+    follows is priced per row it is given. It is not where, from `at`
+    on, all of:
+
+      * no join is probed (a probe is a gather at the current capacity);
+      * the steps, and the partial program up to its group-by, only
+        assign, filter and project: elementwise, fused into the reduction;
+      * that group-by lowers to masked reductions over the scan
+        (`xla_exec.reads_in_place`: keyless, TPC-H Q6; a one-hot of few
+        buckets, Q1), after which the programs run on the groups and do
+        not count;
+      * none of them computes over a late JOIN payload still deferred at
+        `at`: its first read is a gather through `__lmr<j>` at the
+        current capacity (Q14: a keyless sum over `part`'s payload keeps
+        its Compact). A deferred SCAN column is read in place while
+        `__lmpos` is the iota, which no probe or filter changes.
+
+    Returns the reason (`keyless-tail`, `small-domain-tail`) for the
+    `fused-attempt` span."""
+    from ydb_tpu.ops.xla_exec import reads_in_place
+    if pipe.partial is None:
+        return None                      # rows come out
+    deferred: set = set()
+    metas = iter(join_metas)
+    for kind, step in pipe.steps[:at]:
+        if kind == "join":
+            m = next(metas)
+            if m["late"]:
+                deferred.update(m["payload_names"])
+        else:
+            deferred -= _prog_refs(step)
+    if any(kind == "join" for kind, _step in pipe.steps[at:]):
+        return None
+    tail = [step for _kind, step in pipe.steps[at:]]
+    cmds = list(pipe.partial.commands)
+    gb = next((i for i, c in enumerate(cmds)
+               if isinstance(c, ir.GroupBy)), None)
+    if gb is None:
+        return None                      # rows come out
+    before = [c for p in tail for c in p.commands] + cmds[:gb]
+    if not all(isinstance(c, (ir.Assign, ir.Filter, ir.Projection))
+               for c in before):
+        return None
+    route = reads_in_place(cmds[gb])
+    if route is None or any(deferred & _prog_refs(p)
+                            for p in tail + [pipe.partial]):
+        return None
+    return f"{route}-tail"
+
+
 def annotate_plan(plan) -> None:
     """Stamp the pipeline with its late-materialization sets (sizing/
     observability metadata — EXPLAIN's `-- latemat:` lines; the executor

@@ -389,6 +389,11 @@ COUNTER_REGISTRY = {
     "latemat/compact_early_plans":
         "[viz] of those, dispatches whose Compact sits before the "
         "pipeline's last step (directly after the last reducing join)",
+    "latemat/compact_skipped_plans":
+        "[viz] fused dispatches whose estimate qualified for a Compact "
+        "(under half the scan capacity) and whose tail declined it: a "
+        "keyless aggregate reads its rows in place (span attribute "
+        "compact_skipped)",
     "latemat/compact_capacity_rows":
         "ladder-quantized compact capacities allocated (rows)",
     "latemat/compact_live_rows":

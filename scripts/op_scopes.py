@@ -50,7 +50,7 @@ HEAVY = ("gather", "scatter", "sort", "reduce", "reduce-window",
 COLLECTIVES = ("all-to-all", "all-gather", "all-reduce",
                "collective-permute")
 # the kinds `utils/progstats` inventories the named programs under
-KINDS = ("fused", "mesh-sj", "mesh-merge")
+KINDS = ("fused", "batched", "mesh-sj", "mesh-merge")
 
 
 def _heavy(opcode: str) -> bool:
@@ -108,6 +108,9 @@ def build_and_run(workload: str, seed: int, sf: float | None = None):
         cfg["sf"] = sf
     mix = traffic.read_json(ROOT / "benchmark" / "workloads"
                             / f"{workload}.json")
+    import os
+    for k, v in (cfg.get("env") or {}).items():
+        os.environ[k] = str(v)           # the configuration's levers
     import ydb_tpu                       # noqa: F401 — x64, cache dir
     eng = bench_run.build_engine(cfg)
     loader = traffic.load_module("loaders", cfg["loader"])
@@ -168,9 +171,14 @@ def main(argv=None) -> int:
                 f.write(text)
         # programs of one shape (two literal sets, two Compact sizes)
         # share a name and a numbering: the first one's operations stand
-        # for all, each key keeps its own count
+        # for all, each key keeps its own count. The batched lane's
+        # stacked programs share a name across member-slot buckets and
+        # are built smallest first: the LAST one's (the full bucket, what
+        # a herd runs) stand for them
         prog = programs.setdefault(row["name"], {"keys": [],
                                                  "ops": parse_hlo(text)})
+        if row["kind"] == "batched":
+            prog["ops"] = parse_hlo(text)
         prog["keys"].append({"key": row["program"], "execs": row["execs"],
                              "device_ms_max": row["device_ms_max"]})
     del eng

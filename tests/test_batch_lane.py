@@ -384,8 +384,9 @@ def test_batch_q1_reads_deferred_columns_in_place(monkeypatch):
     batched = [r["program"] for r in progstats.inventory_rows()
                if r["kind"] == "batched"
                and r["name"].startswith("jit_lineitem_")]
-    assert batched
-    for kid in batched:
-        text = progstats.hlo_text(kid)
-        assert text
+    # the inventory is the process's: a row of an engine that is gone
+    # (another test's, on this worker) has no live handle and reads ''
+    live = [t for t in map(progstats.hlo_text, batched) if t]
+    assert live
+    for text in live:
         assert not re.findall(r' gather\(.*op_name="[^"]*latemat\[', text)

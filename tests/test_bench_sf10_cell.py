@@ -125,7 +125,9 @@ def test_the_cell_reports_what_the_sf1_scan_cell_reports_but_its_tail():
         - {"latency_p90_ms"}
     for m in BENCHMARK["per_layer"]:
         if m["name"] in NEW_METRICS:
-            assert m["workloads"] == [CELL]
+            # the cell they came with; later cells join the list
+            # (`tpch-sf10-batched.scan-burst`, PR 36)
+            assert m["workloads"][0] == CELL
 
 
 @pytest.mark.parametrize("run", ["untraced", "traced"])

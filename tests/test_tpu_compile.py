@@ -214,6 +214,85 @@ def test_keyless_tail_in_place_at_scan_capacity(one_chip, no_compile_cache,
     assert not re.search(r" (?:sort|gather|scatter)\(", text)
 
 
+def test_batched_q6_at_sf10_capacity_is_what_admission_reserves(
+        one_chip, no_compile_cache, monkeypatch):
+    """The stacked Q6 program (`build_fused_batched_fn`: sixteen members
+    over ONE shared superblock) rebuilt at SF10's scan capacity, 64
+    sources x 1 Mi slots: the chip's compiler takes it, leaves no sort,
+    gather or scatter in it, and what it holds (arguments + temporaries
+    + outputs, the figure the lane admits a compiled shape by) lets TWO
+    such batches stand side by side under the default admission budget
+    where sixteen times the scan estimate let none; the bound the lane
+    uses before the first compile lies above the compiler's figure."""
+    import re
+
+    from ydb_tpu.bench.tpch_gen import load_tpch
+    from ydb_tpu.ops import fused as F
+    from ydb_tpu.query import QueryEngine
+    from ydb_tpu.query.admission import (
+        batch_reservation_bytes, estimate_plan_bytes, stacked_body_width,
+    )
+    from ydb_tpu.storage.mvcc import MAX_SNAPSHOT
+    from tests.tpch_util import QUERIES
+
+    B = 16
+    monkeypatch.setenv("YDB_TPU_BATCH_WINDOW", "50")
+    monkeypatch.setenv("YDB_TPU_BATCH_MAX", str(B))
+    eng = QueryEngine()
+    load_tpch(eng.catalog, sf=0.002)
+    built, filled = [], []
+    real_build, real_fill = F.build_fused_batched_fn, eng.executor._fused_fill
+
+    def build(pipe, final, scan_cols, K, CAP, *a, **kw):
+        built.append((pipe, final, scan_cols, a, kw))
+        return real_build(pipe, final, scan_cols, K, CAP, *a, **kw)
+
+    def fill(kind, key, builder, capture_args, **kw):
+        filled.append((kind, capture_args))
+        return real_fill(kind, key, builder, capture_args, **kw)
+
+    monkeypatch.setattr(F, "build_fused_batched_fn", build)
+    monkeypatch.setattr(eng.executor, "_fused_fill", fill)
+    eng.query(QUERIES["q6"])     # the first statement builds Bb = 2 .. 16
+    pipe, final, scan_cols, a, kw = built[-1]
+    assert a[-1] == B                        # axis_size
+    kind, (arrays, valids, lengths, builds, params) = filled[-1]
+    assert kind == "batched" and builds == []
+    K, CAP = 64, 1 << 20
+    fn, _box = real_build(pipe, final, scan_cols, K, CAP, *a, **kw)
+
+    def as_specs(tree, shape=None):
+        return jax.tree_util.tree_map(
+            lambda x: _as_spec(x, one_chip, shape), tree)
+
+    compiled, _s = _compile(fn, as_specs(arrays, (K, CAP)),
+                            as_specs(valids, (K, CAP)),
+                            as_specs(lengths, (K,)), [], as_specs(params))
+    text = compiled.as_text()
+    assert "jit_lineitem_gb_" in text
+    assert not re.search(r" (?:sort|gather|scatter)\(", text)
+    mem = compiled.memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
+        + mem.output_size_in_bytes
+    scan = K * CAP * 28                      # Q6's four columns, shared
+    assert mem.argument_size_in_bytes == pytest.approx(scan, rel=0.01)
+    # two batches side by side under the default 10 GiB, and the gate's
+    # 6 GiB for one; B x the scan estimate passes both
+    assert 2 * held <= eng.admission.budget
+    assert held <= eng.executor.fused_scan_budget_bytes
+    assert batch_reservation_bytes(scan, B) > eng.admission.budget
+    # the bound before the first compile is above the compiler's figure
+    plan = next(iter(eng._plan_cache.values()))[1]
+    bound = scan + B * K * CAP * stacked_body_width(eng.catalog, plan)
+    assert held <= bound
+    assert estimate_plan_bytes(eng.catalog, plan, MAX_SNAPSHOT) > 0
+    from ydb_tpu.utils import progstats
+    progstats.reset_for_tests()      # no inventory row outlives its engine
+    print(f"batched q6 K={K} B={B}: args {mem.argument_size_in_bytes} "
+          f"temp {mem.temp_size_in_bytes} out {mem.output_size_in_bytes} "
+          f"bound {bound} compile {_s:.1f}s")
+
+
 def test_fused_join_program(one_chip, no_compile_cache, monkeypatch):
     """A fused join + group-by + sort + limit program of the q3 class, as
     `ops/fused.py` builds it: captured at the AOT seam in a small CPU

@@ -103,6 +103,81 @@ def batch_reservation_bytes(est_bytes: int, n_members: int,
         max(int(member_floor), int(est_bytes))
 
 
+def stacked_body_width(catalog, plan):
+    """Bytes per scan slot that ONE member of a stacked (vmapped)
+    execution may materialize beside the shared inputs: the plan-derived
+    half of `Executor.batched_working_set`, used until the compiler's own
+    figure for the program exists. None for a body that SORTS at scan
+    capacity (a sorted or scattered group-by, an ORDER BY over rows): what
+    a radix sort holds is the compiler's to say, and until it has, such a
+    shape is charged members x the per-member estimate as before.
+
+    Counted once each, at scan capacity: the selection mask; a mask per
+    filter; every column the steps and the partial program assign, at its
+    width (+1 when nullable); every join's attached payload (+1 a column:
+    probe payloads are nullable-tagged) or its one-byte match flag; up to
+    the first group-by, which reads its rows in place (keyless, or a
+    one-hot of a small domain: a 4-byte bucket number) and past which the
+    body runs at group capacity. A plan with no group-by hands its output
+    columns back at scan capacity. The compiler fuses most of this away
+    (Q6 at 64 Mi slots: 10 bytes here, 2.5 in `memory_analysis()`), so it
+    is an upper bound, not a forecast; `tests/test_tpu_compile.py` and
+    `tests/test_batch_admission.py` hold it above the compiler's figure."""
+    import numpy as np
+
+    from ydb_tpu.core.schema import Column, Schema
+    from ydb_tpu.ops import ir
+    from ydb_tpu.ops.xla_exec import reads_in_place
+
+    def width(dt) -> int:
+        return int(np.dtype(dt.np).itemsize) + (1 if dt.nullable else 0)
+
+    pipe = plan.pipeline
+    table = catalog.table(pipe.scan.table)
+    schema = Schema([Column(i, table.schema.dtype(s))
+                     for (s, i) in pipe.scan.columns])
+    total = 1                               # the selection mask
+    progs = [pipe.pre_program]
+    for kind, step in pipe.steps:
+        if kind != "join":
+            progs.append(step)
+        elif step.kind in ("inner", "left") and step.payload:
+            progs.append(step)
+        else:
+            total += 1                      # semi / anti / mark: a flag
+    for prog in progs + [pipe.partial, plan.final_program]:
+        if prog is None:
+            continue
+        if not isinstance(prog, ir.Program):        # a payload join
+            bp = getattr(prog.build, "pipeline", prog.build)
+            bschema = catalog.table(bp.scan.table).schema
+            stored = {i: s for (s, i) in bp.scan.columns}
+            # a derived build column raises here: the lane then charges
+            # members x estimate
+            cols = [Column(n, bschema.dtype(stored[n]).with_nullable(True))
+                    for n in prog.payload]
+            total += sum(width(c.dtype) for c in cols)
+            names = {c.name for c in cols}
+            schema = Schema([c for c in schema.columns
+                             if c.name not in names] + cols)
+            continue
+        for cmd in prog.commands:
+            if isinstance(cmd, ir.Filter):
+                total += 1
+            elif isinstance(cmd, ir.Assign):
+                total += width(ir.infer_expr(cmd.expr, schema))
+            elif isinstance(cmd, ir.GroupBy):
+                if reads_in_place(cmd) is None:
+                    return None
+                return total + (4 if cmd.keys else 0)
+            schema = ir.infer_schema(ir.Program([cmd]), schema)
+    if plan.sort:
+        return None
+    # rows out: every output column at scan capacity
+    return total + sum(width(schema.dtype(n)) if schema.has(n) else 9
+                       for n in dict.fromkeys(n for (n, _l) in plan.output))
+
+
 def estimate_plan_bytes(catalog, plan, snapshot) -> int:
     """Device-byte estimate for a SELECT plan: the driving scan's columns
     at the table's row count, plus each join build's scan (one level deep

@@ -222,6 +222,11 @@ class QueryEngine:
         # clients). 0 = off, byte-identical to the per-query path.
         self.batch_window_ms = float(
             os.environ.get("YDB_TPU_BATCH_WINDOW", "0") or 0)
+        # how long a group's leader waits for a SECOND member before it
+        # runs alone on the per-query program (the lane reads it at every
+        # seal). An attribute a deployment's configuration may state, not
+        # an environment lever.
+        self.batch_alone_probe_ms = 2.0
         self._batch_lane = None
         if self.batch_window_ms > 0:
             from ydb_tpu.query.batch_lane import BatchLane
@@ -912,31 +917,35 @@ class QueryEngine:
         # saturated by admission-queued queries sheds instead of
         # head-of-line blocking every later SELECT indefinitely.
         from contextlib import ExitStack
+        with ExitStack() as held:
+            self._admission_wait(held, est)
+            return self._dispatch_drain_admitted(plan, snap, est)
 
+    def _admission_wait(self, held, est: int) -> None:
+        """Take a pipeline-window slot and `est` bytes of the admission
+        budget into the ExitStack `held`, both waits under ONE span
+        (critical-path extraction classes it admission_wait;
+        `phases["admission_ms"]`), so a statement that queued here says so
+        instead of leaving a gap. `in_flight_mb`: what others held
+        reserved when this one arrived; `waited`: whether it queued for a
+        slot or for bytes. The per-query path's, and a batch leader's."""
         from ydb_tpu.query.admission import AdmissionTimeout
         from ydb_tpu.utils.metrics import GLOBAL
-        with ExitStack() as held:
-            # both waits under ONE span (critical-path extraction
-            # classes it admission_wait; `phases["admission_ms"]`), so a
-            # statement that queued here says so instead of leaving a gap
-            # `in_flight_mb`: what others held reserved when this one
-            # arrived; `waited`: whether it queued for a slot or for bytes
-            with self.tracer.span(
-                    "admission-wait", admitted_mb=est >> 20,
-                    in_flight_mb=self.admission.in_flight >> 20) as sp:
-                waited = not self._pipe_sem.acquire(blocking=False)
-                if waited and not self._pipe_sem.acquire(
-                        timeout=self.admission.timeout_s):
-                    GLOBAL.inc("pipeline/window_timeouts")
-                    raise AdmissionTimeout(
-                        f"pipeline window saturated: "
-                        f"{self.pipeline_window} queries "
-                        "dispatched-or-queued for longer than the "
-                        "admission deadline")
-                held.callback(self._pipe_sem.release)
-                waited |= held.enter_context(self.admission.admit(est))
-                sp.attrs["waited"] = waited
-            return self._dispatch_drain_admitted(plan, snap, est)
+        with self.tracer.span(
+                "admission-wait", admitted_mb=est >> 20,
+                in_flight_mb=self.admission.in_flight >> 20) as sp:
+            waited = not self._pipe_sem.acquire(blocking=False)
+            if waited and not self._pipe_sem.acquire(
+                    timeout=self.admission.timeout_s):
+                GLOBAL.inc("pipeline/window_timeouts")
+                raise AdmissionTimeout(
+                    f"pipeline window saturated: "
+                    f"{self.pipeline_window} queries "
+                    "dispatched-or-queued for longer than the "
+                    "admission deadline")
+            held.callback(self._pipe_sem.release)
+            waited |= held.enter_context(self.admission.admit(est))
+            sp.attrs["waited"] = waited
 
     def _dispatch_drain_admitted(self, plan, snap, est: int) -> HostBlock:
         """Body of the pipeline once the window slot + byte reservation
@@ -1150,15 +1159,18 @@ class QueryEngine:
         """A statement the HOST held up says where: one log line (text
         prefix, wall, phases, the wall no span covers) and
         `slow_query/host_slow` when its wall less the device's share
-        (`queue_ms`, `device_ms`) passes `HOST_SLOW_MS`. Not at
+        (`queue_ms`, `device_ms`, a batched member's `batch_wait_ms`)
+        passes `HOST_SLOW_MS`. Not at
         `slow_query_ms`: a statement whose program runs a second is not
         slow on the host. Sampled statements only: an unsampled one has
         no phases to take the device's share from."""
         ph = stats.phases
         if not ph:
             return
+        # a batched member's wait for its group is its group's device
+        # work (and the window), not the host holding it up
         host_ms = stats.total_ms - ph.get("queue_ms", 0.0) \
-            - ph.get("device_ms", 0.0)
+            - ph.get("device_ms", 0.0) - ph.get("batch_wait_ms", 0.0)
         if host_ms < HOST_SLOW_MS:
             return
         from ydb_tpu.utils.metrics import GLOBAL

@@ -57,6 +57,25 @@ def split_device_wait(t_enqueued: float, t_wait: float, t_done: float,
     return (run_start - t_wait) * 1000.0, (t_done - run_start) * 1000.0
 
 
+def unpruned(plan: QueryPlan) -> QueryPlan:
+    """The plan as a stacked execution runs it (`query/batch_lane.py`):
+    scan pruning stripped — its outcome is literal-dependent and cannot
+    partition a shared scan."""
+    import dataclasses
+    pipe = plan.pipeline
+    return dataclasses.replace(plan, pipeline=dataclasses.replace(
+        pipe, scan=dataclasses.replace(pipe.scan, prune=[])))
+
+
+def batch_bucket(n_members: int) -> int:
+    """Member slots a stacked program runs `n_members` in: the next
+    power of two, one executable a bucket."""
+    bb = 1
+    while bb < n_members:
+        bb *= 2
+    return bb
+
+
 def _fused_evict_hook(key) -> None:
     """Map a fused-cache eviction back to its program-inventory kind:
     batched-lane entries key on a ("batched", ...) tuple, everything
@@ -170,6 +189,19 @@ class Executor:
         # setup on the background pool every time it runs
         self._warm_seen: set = set()
         self._warm_mu = _threading.Lock()
+        # launched compile-ahead thunks that have not ended yet, by the
+        # same triple: the batched lane's first statement of a shape
+        # waits here rather than upload the shape's columns beside the
+        # thunk (guarded-by: _warm_mu)
+        self._warm_pending: dict = {}
+        # triples whose stacked programs `warm_batched` has built
+        # (guarded-by: _warm_mu)
+        self._batched_warm_seen: set = set()
+        # (lift_sig, table uid, data_version, Bb) -> the compiler's
+        # argument + temporary + output bytes of that stacked program:
+        # what the lane's gate and reservation read. Plain dict: values
+        # are ints, reads/writes are GIL-atomic.
+        self._batched_bytes: dict = {}
         # build-time trace deltas parked by the compile-ahead worker,
         # keyed by (kind, cache key): the thread-local groupby/bounds
         # gauges a background build records would otherwise vanish —
@@ -834,9 +866,25 @@ class Executor:
                 return False
             self._warm_seen.add(warm_key)
         params = dict(params)
-        return self._sflight.launch(
-            ("warm",) + warm_key,
-            lambda: self._fused_warm(plan, params, snapshot))
+        import threading as _threading
+        ended = _threading.Event()
+        with self._warm_mu:
+            self._warm_pending[warm_key] = ended
+
+        def _warm():
+            try:
+                return self._fused_warm(plan, params, snapshot)
+            finally:
+                with self._warm_mu:
+                    self._warm_pending.pop(warm_key, None)
+                ended.set()
+
+        launched = self._sflight.launch(("warm",) + warm_key, _warm)
+        if not launched:
+            with self._warm_mu:
+                self._warm_pending.pop(warm_key, None)
+            ended.set()
+        return launched
 
     def _fused_warm(self, plan: QueryPlan, params: dict,
                     snapshot: Snapshot) -> bool:
@@ -1220,7 +1268,7 @@ class Executor:
     # -- multi-query batched dispatch --------------------------------------
 
     def execute_fused_batched(self, plan: QueryPlan, members: list,
-                              snapshot: Snapshot):
+                              snapshot: Snapshot, info: dict = None):
         """ONE stacked fused execution for a batch of same-shape queries
         (the inference-serving lane, `query/batch_lane.py`): the shared
         scan superblock and join builds broadcast, each member's lifted
@@ -1232,14 +1280,109 @@ class Executor:
         literal-dependent and cannot partition a shared execution; the
         lane already verified every member sees identical source sets).
         `members`: [(member_plan, member_params)] — same `lift_sig`,
-        verified by the lane. Returns [HostBlock] projected per member,
-        or None when this shape cannot batch (caller falls back to
-        per-member execution)."""
+        verified by the lane. `info`: the lane's note of the group:
+        `reserved_bytes`, what its ONE admission reservation holds for
+        this dispatch, is read (the span's `reserved_mb`); `bb`, the
+        member slots the program ran, is written. Returns [HostBlock]
+        projected per member, or None when this shape cannot batch
+        (caller falls back to per-member execution)."""
+        from ydb_tpu.ops import fused as F
+        from ydb_tpu.utils import memledger
+        from ydb_tpu.utils.metrics import GLOBAL
+
+        info = {} if info is None else info
+        bp = self._batched_prepare(plan, members, snapshot)
+        if bp is None:
+            return None
+        # observability levers cannot stale a program: they choose how
+        # the identical trace is dispatched/recorded, not what it computes
+        # lint: allow-cache-key(progstats/memledger/critpath observe only)
+        cached = self._fused_cache.get(bp.key)
+        fresh_compile = cached is None
+        if cached is not None:
+            fn, layout_box, out_schema = cached
+            progstats.record_hit(getattr(fn, "key_id", None))
+        else:
+            fn = layout_box = out_schema = None
+        try:
+            with self._span("device-dispatch-batched", k=bp.K, cap=bp.CAP,
+                            b=bp.Bb, reserved_mb=info.get(
+                                "reserved_bytes", 0) >> 20) as dsp:
+                import time as _time
+                t_disp = _time.perf_counter()
+                if fn is None:
+                    # fill for the stacked program too: store consult →
+                    # AOT capture, single-flight deduped (compile inside
+                    # the dispatch span; a trace error re-raises at the
+                    # call below and the lane falls back per-member
+                    # exactly as before). cache=False — the entry parks
+                    # only after the first successful dispatch.
+                    (fn, layout_box, out_schema), fresh_compile = \
+                        self._fused_fill("batched", bp.key, bp.builder,
+                                         bp.args, cache=False)
+                mem = self._note_batched_bytes(bp, fn)
+                if mem is not None:
+                    dsp.attrs["temp_mb"] = mem["temp_bytes"] >> 20
+                # no compact in the batched lane (aux is always empty
+                # — `_fused_plan_setup` never hands it a compact_prog)
+                data_stacks, valid_stack, length, _aux = fn(*bp.args)
+                t_enqueued = _time.perf_counter()
+                if fresh_compile:
+                    dsp.attrs["compile_ms"] = round(
+                        (_time.perf_counter() - t_disp) * 1000.0, 3)
+        except Exception:                # noqa: BLE001 — lane, not law
+            # a shape the vmapped trace can't batch (or a compile-side
+            # failure): fall back to per-member execution rather than
+            # failing B clients on an optimization
+            GLOBAL.inc("batch/trace_errors")
+            return None
+        if cached is None:
+            # cache only after the first successful dispatch, so a
+            # trace-failing shape never parks a dead entry in the budget
+            self._fused_cache[bp.key] = (fn, layout_box, out_schema)
+        _count_latemat_reads(layout_box)
+        B = len(members)
+        info["bb"] = bp.Bb
+        # the power-of-two axis bucket runs `Bb` member slots for `B`
+        # live members; the `Bb - B` repeats of the last member are work
+        # the device does for nobody (same-text dedup runs one slot)
+        GLOBAL.inc("batch/member_slots", bp.Bb)
+        GLOBAL.inc("batch/pad_slots", max(0, bp.Bb - B))
+        memledger.record_padded_buffers(
+            "batch_lane", "result_buffers", min(B, bp.Bb), bp.Bb,
+            (data_stacks, valid_stack))
+
+        out_dicts = {n2: d for n2, d in bp.dicts.items()
+                     if out_schema.has(n2)}
+        out_dicts.update({n2: d for n2, d in bp.plan.result_dicts.items()
+                          if out_schema.has(n2)})
+        self._await_device((data_stacks, valid_stack, length), t_enqueued,
+                           getattr(fn, "key_id", None), fresh_compile)
+        with self._span("readout-transfer", b=len(members)):
+            blocks = F.fetch_fused_batch(data_stacks, valid_stack, length,
+                                         layout_box, out_schema, out_dicts,
+                                         bp.member_rows)
+        out = []
+        for (mp, _prms), blk in zip(members, blocks):
+            blk = _apply_offset(blk, mp.offset or 0, mp.limit)
+            out.append(self._project_output(blk, mp.output))
+        return out
+
+    def _batched_prepare(self, plan: QueryPlan, members: list,
+                         snapshot: Snapshot, ahead_bb: int = 0):
+        """A stacked execution up to its program: builds, plan walk,
+        superblock, the members' params stacked, the cache key, the
+        builder and the arguments. None where this shape cannot batch.
+
+        `ahead_bb`: the build-ahead of one batch-size bucket
+        (`warm_batched`): `members` holds ONE statement, whose scalar
+        literals stand in for `ahead_bb` members'."""
+        from types import SimpleNamespace
+
         from ydb_tpu.ops import fused as F
         from ydb_tpu.storage.device_cache import (
             enumerate_scan_sources, estimate_scan_bytes,
         )
-        from ydb_tpu.utils.metrics import GLOBAL
 
         pipe = plan.pipeline
         table = self.catalog.table(pipe.scan.table)
@@ -1254,6 +1397,7 @@ class Executor:
                     not bt.unique and step.kind in ("inner", "left",
                                                     "mark")):
                 return None
+        lift_sig = getattr(plan, "lift_sig", None)
         (plan, pipe, scan_cols, schema, partial_schema, dicts,
          join_metas, late_scan) = self._fused_plan_setup(plan, builds)
 
@@ -1295,16 +1439,27 @@ class Executor:
             if sorted(p) != names:
                 return None              # shape drift — lane sig was stale
 
-        # stack only the params whose values actually differ across the
-        # batch; batch-invariant ones (rank LUTs, shared pool arrays)
-        # broadcast via in_axes=None instead of B device copies
+        # WHICH params ride the batch axis is a property of the shape,
+        # not of this batch's values: once any member differs, every
+        # numeric SCALAR literal is stacked, whether or not its values
+        # happen to agree here (a herd whose sixteen all ask `quantity <
+        # 24` must not meet a program of its own, compiled inside the
+        # window); arrays (rank LUTs, shared pool arrays, IN lists) stack
+        # only where they differ — B device copies of a LUT are not free
+        # — and broadcast via in_axes=None otherwise
+        B = len(members)
+        column = {n: [p[n] for p in mem_params] for n in names}
+        differs = {n: not all(_param_values_equal(v[0], x) for x in v[1:])
+                   for n, v in column.items()}
+        stack_scalars = bool(ahead_bb) or any(differs.values())
         axes, stacked = {}, {}
         for n in names:
-            vals = [p[n] for p in mem_params]
-            if all(_param_values_equal(vals[0], v) for v in vals[1:]):
-                axes[n] = None
-                stacked[n] = vals[0]
-            else:
+            vals = column[n]
+            if ahead_bb:
+                vals = vals * ahead_bb
+            v0 = np.asarray(vals[0])
+            if differs[n] or (stack_scalars and v0.ndim == 0
+                              and v0.dtype.kind in "biuf"):
                 arrs = [np.asarray(v) for v in vals]
                 if any(a.shape != arrs[0].shape or a.dtype != arrs[0].dtype
                        for a in arrs[1:]):
@@ -1314,13 +1469,14 @@ class Executor:
                     return None
                 axes[n] = 0
                 stacked[n] = np.stack(arrs)
-        B = len(members)
+            else:
+                axes[n] = None
+                stacked[n] = vals[0]
         mapped = tuple(n for n in names if axes[n] == 0)
-        if mapped:
-            Bb = 1
-            while Bb < B:
-                Bb *= 2                  # batch-size buckets: one
-            #                              executable per power-of-two size
+        if ahead_bb:
+            Bb, member_rows = ahead_bb, []
+        elif mapped:
+            Bb = batch_bucket(B)
             if Bb > B:
                 pad = Bb - B             # pad by repeating the last member
                 for n in mapped:
@@ -1338,18 +1494,7 @@ class Executor:
                                      sb_valid_names, builds_sig, sort_spec,
                                      rank_assigns, tuple(names),
                                      lim_key=lim_key)
-        key = ("batched", base_key, Bb, mapped)
         keep = tuple(dict.fromkeys(n for (n, _lbl) in plan.output))
-        # observability levers cannot stale a program: they choose how
-        # the identical trace is dispatched/recorded, not what it computes
-        # lint: allow-cache-key(progstats/memledger/critpath observe only)
-        cached = self._fused_cache.get(key)
-        fresh_compile = cached is None
-        if cached is not None:
-            fn, layout_box, out_schema = cached
-            progstats.record_hit(getattr(fn, "key_id", None))
-        else:
-            fn = layout_box = out_schema = None
 
         def _builder():
             bfn, box = F.build_fused_batched_fn(
@@ -1364,64 +1509,134 @@ class Executor:
         dev_params = {k: (jnp.asarray(v) if isinstance(v, np.ndarray)
                           else v) for k, v in stacked.items()}
         build_inputs = [F.build_traced_inputs(bt) for bt in builds]
-        try:
-            with self._span("device-dispatch-batched", k=K, cap=CAP,
-                            b=Bb) as dsp:
-                import time as _time
-                t_disp = _time.perf_counter()
-                if fn is None:
-                    # fill for the stacked program too: store consult →
-                    # AOT capture, single-flight deduped (compile inside
-                    # the dispatch span; a trace error re-raises at the
-                    # call below and the lane falls back per-member
-                    # exactly as before). cache=False — the entry parks
-                    # only after the first successful dispatch.
-                    (fn, layout_box, out_schema), fresh_compile = \
-                        self._fused_fill(
-                            "batched", key, _builder,
-                            (arrays, valids, lengths, build_inputs,
-                             dev_params), cache=False)
-                # no compact in the batched lane (aux is always empty
-                # — `_fused_plan_setup` never hands it a compact_prog)
-                data_stacks, valid_stack, length, _aux = fn(
-                    arrays, valids, lengths, build_inputs, dev_params)
-                t_enqueued = _time.perf_counter()
-                if fresh_compile:
-                    dsp.attrs["compile_ms"] = round(
-                        (_time.perf_counter() - t_disp) * 1000.0, 3)
-        except Exception:                # noqa: BLE001 — lane, not law
-            # a shape the vmapped trace can't batch (or a compile-side
-            # failure): fall back to per-member execution rather than
-            # failing B clients on an optimization
-            GLOBAL.inc("batch/trace_errors")
-            return None
-        if cached is None:
-            # cache only after the first successful dispatch, so a
-            # trace-failing shape never parks a dead entry in the budget
-            self._fused_cache[key] = (fn, layout_box, out_schema)
-        _count_latemat_reads(layout_box)
-        # batch-lane padding: the power-of-two axis bucket materializes
-        # Bb member slots of every stacked output for B live members
-        # (same-text dedup maps all members to one row — min() keeps the
-        # live share honest there)
-        memledger.record_padded_buffers(
-            "batch_lane", "result_buffers", min(B, Bb), Bb,
-            (data_stacks, valid_stack))
+        return SimpleNamespace(
+            plan=plan, dicts=dicts, K=K, CAP=CAP, Bb=Bb,
+            member_rows=member_rows, builder=_builder,
+            key=("batched", base_key, Bb, mapped),
+            args=(arrays, valids, lengths, build_inputs, dev_params),
+            bytes_key=(lift_sig, table.uid, table.data_version, Bb))
 
-        out_dicts = {n2: d for n2, d in dicts.items() if out_schema.has(n2)}
-        out_dicts.update({n2: d for n2, d in plan.result_dicts.items()
-                          if out_schema.has(n2)})
-        self._await_device((data_stacks, valid_stack, length), t_enqueued,
-                           getattr(fn, "key_id", None), fresh_compile)
-        with self._span("readout-transfer", b=len(members)):
-            blocks = F.fetch_fused_batch(data_stacks, valid_stack, length,
-                                         layout_box, out_schema, out_dicts,
-                                         member_rows)
-        out = []
-        for (mp, _prms), blk in zip(members, blocks):
-            blk = _apply_offset(blk, mp.offset or 0, mp.limit)
-            out.append(self._project_output(blk, mp.output))
-        return out
+    # -- what a stacked program holds ---------------------------------------
+
+    def _note_batched_bytes(self, bp, handle):
+        """Remember the compiler's `memory_analysis()` of a stacked
+        program (`utils/progstats` records it at the AOT seam) under the
+        lane's terms — shape, table version, batch-size bucket — so that
+        `batched_working_set` answers from it. Returns the figures, or
+        None where the compiler gave none (progstats off)."""
+        ent = progstats.inventory_entry(getattr(handle, "key_id", None) or "")
+        mem = (ent or {}).get("memory")
+        if mem and bp.bytes_key[0] is not None:
+            if len(self._batched_bytes) > 256:
+                self._batched_bytes.clear()
+            self._batched_bytes[bp.bytes_key] = (
+                mem["arg_bytes"] + mem["temp_bytes"] + mem["out_bytes"])
+        return mem
+
+    def batched_working_set(self, plan: QueryPlan, snapshot: Snapshot,
+                            n_members: int, est: int):
+        """Device bytes ONE stacked dispatch of `n_members` statements of
+        this shape holds: `(bytes, "compiled" | "plan" | "members")`. The
+        lane's gate and its reservation both ask here.
+
+        The shared inputs enter a vmapped program ONCE (`in_axes=None`:
+        superblock columns, build tables), so the working set is not
+        `n_members` scans. For a program that has been compiled it is the
+        compiler's own figure: arguments + temporaries + outputs. Before
+        that, a bound from the plan: the shared inputs (`est`, or the
+        padded superblock where that is more) plus, per member slot and
+        per scan slot, the widths of what the body computes
+        (`admission.stacked_body_width`): far above what the compiler
+        fuses away, so a large shape may be declined until its program
+        exists — which `warm_batched` sees to before the first group. A
+        body that sorts at scan capacity has no such bound: it is charged
+        `n_members` x `est` (`batch_reservation_bytes`), as every shape
+        was before, until its program exists."""
+        from ydb_tpu.query.admission import (
+            batch_reservation_bytes, stacked_body_width,
+        )
+        from ydb_tpu.storage.device_cache import (
+            enumerate_scan_sources, estimate_scan_bytes, scan_capacity,
+        )
+        Bb = batch_bucket(n_members)
+        pipe = plan.pipeline
+        table = self.catalog.table(pipe.scan.table)
+        fig = self._batched_bytes.get(
+            (getattr(plan, "lift_sig", None), table.uid, table.data_version,
+             Bb))
+        if fig is not None:
+            return fig, "compiled"
+        sources, _ids = enumerate_scan_sources(table, snapshot, None)
+        Kb = shape_buckets.bucket_sources(len(sources))
+        scan = estimate_scan_bytes(
+            sources, [s for (s, _i) in pipe.scan.columns], pad_to=Kb)
+        width = stacked_body_width(self.catalog, plan)
+        if width is None:                # the body sorts at scan capacity
+            return batch_reservation_bytes(est, n_members), "members"
+        return max(int(est), scan) \
+            + Bb * scan_capacity(sources, Kb) * width, "plan"
+
+    def warm_batched(self, plan: QueryPlan, snapshot: Snapshot,
+                     max_batch: int, timeout_s: float) -> None:
+        """The stacked programs exist before the herd arrives: the first
+        statement of a shape (per table version) that reaches the lane
+        waits for the shape's compile-ahead (`compile_ahead`: superblock
+        and single-statement program, so nothing is uploaded twice), then
+        builds the `Bb` = 2, 4, .. `max_batch` executables through
+        `_fused_fill`'s single flight; statements of the shape that
+        arrive meanwhile wait on that flight, later ones pass. A
+        deployment's set-up that sends each shape once therefore ends
+        with every program built. Off with the compile-ahead lane or
+        progstats off (the programs then compile at first use, inside the
+        dispatch)."""
+        from ydb_tpu.utils.metrics import GLOBAL
+        sig = getattr(plan, "lift_sig", None)
+        if sig is None or max_batch < 2 or not (
+                plan.lift_names or plan.limit is not None) or not (
+                ca_lane.enabled() and progstats.enabled()):
+            return
+        table = self.catalog.table(plan.pipeline.scan.table)
+        warm_key = (plan.pipeline.scan.table, table.data_version, sig)
+        with self._warm_mu:
+            pending = self._warm_pending.get(warm_key)
+            done = warm_key in self._batched_warm_seen
+        if pending is not None:
+            pending.wait(timeout_s)
+        if done:
+            return
+
+        def _build():
+            lane_plan, Bb = unpruned(plan), 2
+            while Bb <= batch_bucket(max_batch):
+                bp = self._batched_prepare(
+                    lane_plan, [(lane_plan, dict(plan.params))], snapshot,
+                    ahead_bb=Bb)
+                Bb *= 2
+                if bp is None or not bp.key[-1]:
+                    # the shape cannot batch at all, or carries no
+                    # literal members could differ by: such a herd is one
+                    # statement run once (the same-text dedup)
+                    break
+                ent = self._fused_cache.get(bp.key)
+                if ent is None:
+                    ent, compiled = self._fused_fill(
+                        "batched", bp.key, bp.builder, bp.args,
+                        source="compile_ahead", cache=False)
+                    if not isinstance(ent[0], progstats.ProgramHandle):
+                        break            # the trace or the compiler refused
+                    self._fused_cache[bp.key] = ent
+                    if compiled:
+                        GLOBAL.inc("batch/ahead_compiles")
+                self._note_batched_bytes(bp, ent[0])
+            with self._warm_mu:
+                self._batched_warm_seen.add(warm_key)
+
+        try:
+            with self._span("batch-build-ahead", max_batch=max_batch):
+                self._sflight.run(("warm-batched",) + warm_key, _build)
+        except Exception:                # noqa: BLE001 — lane, not law
+            # the dispatch path meets the real error with full context
+            GLOBAL.inc("prog/compile_ahead_errors")
 
     def _bounded_groupby_rewrite(self, plan: QueryPlan, builds: list,
                                  join_metas: list):

@@ -96,6 +96,14 @@ def estimate_scan_bytes(sources, storage_names: list,
     return total
 
 
+def scan_capacity(sources, pad_to: int = 0) -> int:
+    """Row slots of the superblock `estimate_scan_bytes` prices: K
+    stacked sources (padded to the shape bucket) at the max capacity."""
+    if not sources:
+        return 0
+    return max(len(sources), pad_to) * max(_source_cap(b) for b in sources)
+
+
 class DeviceColumnCache:
     def __init__(self, budget_bytes: int = DEFAULT_BUDGET):
         import threading

@@ -255,8 +255,28 @@ COUNTER_REGISTRY = {
     "batch/singles": "[viz] solo members run per-query",
     "batch/fallbacks": "[viz] sealed batches that fell back per-member",
     "batch/declined": "[viz] lane-ineligible statements",
+    "batch/declined/*":
+        "(dynamic) the same by reason: no-lift | subplans | mesh | "
+        "row-store | merge-budget | working-set (what a stacked dispatch "
+        "of max_batch members holds passes the fused scan budget) | "
+        "fused-off; one that climbs says which shapes the lane never "
+        "serves",
     "batch/trace_errors": "[viz] stacked-trace failures",
     "batch/reservations": "[viz] single admission reservations taken",
+    "batch/reserved_bytes":
+        "bytes those reservations held, summed: over batch/reservations "
+        "the working set a dispatch was admitted by (the compiler's "
+        "figure once its program exists)",
+    "batch/member_slots":
+        "member slots stacked dispatches ran (the power-of-two bucket "
+        "Bb of each)",
+    "batch/pad_slots":
+        "of those, repeats of a batch's last member (Bb - B): device "
+        "work done for nobody; over batch/member_slots the padding share",
+    "batch/ahead_compiles":
+        "stacked programs compiled before a shape's first group formed "
+        "(Executor.warm_batched); one in a serving window means a "
+        "shape or table version the set-up never sent",
     "batch/window_timeouts": "[viz] members that outwaited the seal",
     "batch/lift_hits": "[viz] plans with every literal lifted",
     "batch/lift_misses": "[viz] plans the lift pass skipped",
@@ -647,13 +667,22 @@ class QueryStats:
                     f"queries | leader "
                     f"{str(b.get('leader', False)).lower()} | "
                     f"{'stacked dispatch' if b.get('batched') else 'per-member fallback'}")
+            if b.get("sealed_by"):
+                out += f" | sealed by {b['sealed_by']}"
+            if b.get("bb"):
+                out += (f" | {b['bb']} member slots "
+                        f"({max(0, b['bb'] - b.get('coalesced', 0))} pad)")
+            if b.get("reserved_bytes"):
+                out += (f" | reserved {b['reserved_bytes'] >> 20} MB"
+                        + (f" ({b['admitted_by']})"
+                           if b.get("admitted_by") else ""))
         if self.phases:
             p = self.phases
             out += ("\n-- phases: " + " | ".join(
                 f"{k.removesuffix('_ms')} {p[k]:.1f}ms"
-                for k in ("admission_ms", "compile_ms", "build_ms",
-                          "upload_ms", "dispatch_ms", "queue_ms",
-                          "device_ms", "readout_ms")
+                for k in ("batch_wait_ms", "admission_ms", "compile_ms",
+                          "build_ms", "upload_ms", "dispatch_ms",
+                          "queue_ms", "device_ms", "readout_ms")
                 if k in p))
         if self.memory and (self.memory.get("peak_bytes")
                             or self.memory.get("transfers")):

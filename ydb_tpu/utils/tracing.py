@@ -84,6 +84,10 @@ def span_from_dict(d: dict) -> Span:
 # device-timeline segment of a fused/batched/DQ execution
 PHASE_SPANS = {
     "admission-wait": "admission_ms",
+    # the batched lane (`query/batch_lane.py`): a member's wait from
+    # joining its group to its slice — the window, the other members, on
+    # a follower the leader's whole execution
+    "batch-wait": "batch_wait_ms",
     "join-builds": "build_ms",
     "superblock-upload": "upload_ms",
     "device-dispatch": "dispatch_ms",

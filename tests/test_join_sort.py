@@ -1,5 +1,7 @@
 """Tests for broadcast join (searchsorted MapJoin) and device sort/top-k."""
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pandas as pd
 import pytest
@@ -171,6 +173,142 @@ def test_expand_join_null_probe_keys(rng):
     assert len(df) == 5  # rows 0,2,3 null-extended + two matches for row 1
 
 
+# -- existence builds: a direct-address LUT whatever the density ----------
+
+_Q18_SPAN = 1_500_000     # TPC-H SF1's orderkeys, 1..1 500 000
+
+
+def _key_block(keys, valid=None, payload=False):
+    cols = [Column("k", dt.DType(dt.Kind.INT64, valid is not None))]
+    arrays = {"k": np.asarray(keys, dtype=np.int64)}
+    if payload:
+        cols.append(Column("p", dt.FLOAT64))
+        arrays["p"] = arrays["k"] * 0.5
+    return HostBlock.from_arrays(Schema(cols), arrays,
+                                 valids={} if valid is None
+                                 else {"k": np.asarray(valid)})
+
+
+def _q18_keys(seed=18, n=6000):
+    # the `having sum(l_quantity) > q` set: ~6k orderkeys over a 1.5M span
+    rng = np.random.default_rng(seed)
+    keys = rng.choice(np.arange(3, _Q18_SPAN), n - 2, replace=False)
+    return np.concatenate([[1, _Q18_SPAN], keys])
+
+
+@pytest.mark.parametrize("case,keys,existence,payload,lut_len", [
+    # Q18's semi join: sparse (6k keys, 64 x 6k < span) yet one gather
+    ("existence-sparse", "q18", True, False, 1 << 21),
+    # the same keys carrying a payload keep the 64x density cap
+    ("payload-sparse", "q18", False, True, None),
+    # past the absolute budget no kind gets a LUT
+    ("existence-past-budget", "wide", True, False, None),
+    ("payload-past-budget", "wide", False, True, None),
+])
+def test_existence_build_lut_is_bounded_by_the_budget_alone(
+        case, keys, existence, payload, lut_len):
+    from ydb_tpu.utils.metrics import GLOBAL
+    ks = _q18_keys() if keys == "q18" else \
+        np.array([0, 7, mj._LUT_SPAN_BUDGET], np.int64)
+    before = GLOBAL.snapshot()
+    bt = mj.build(_key_block(ks, payload=payload), "k",
+                  ["p"] if payload else [], existence=existence)
+    after = GLOBAL.snapshot()
+    delta = {n: after.get(n, 0) - before.get(n, 0)
+             for n in ("join/lut_builds", "join/bsearch_builds",
+                       "join/existence_lut_builds")}
+    if lut_len is None:
+        assert bt.lut is None
+        assert delta == {"join/lut_builds": 0, "join/bsearch_builds": 1,
+                         "join/existence_lut_builds": 0}
+    else:
+        assert bt.lut.shape[0] == lut_len and bt.lut_base == 1
+        assert delta == {"join/lut_builds": 1, "join/bsearch_builds": 0,
+                         "join/existence_lut_builds": 1}
+    if keys == "q18":
+        assert bt.keys_sorted.shape[0] == 8192  # the bsearch input stays
+
+
+_EDGE_PROBES = np.array([
+    0, -1, -5, np.iinfo(np.int64).min + 1,          # below lut_base, negative
+    _Q18_SPAN + 1, _Q18_SPAN + 600_000, 1 << 21, 1 << 40,   # past the span
+    np.iinfo(np.int64).max,                          # the padding sentinel
+    1, _Q18_SPAN, 2], dtype=np.int64)               # both ends, a miss
+
+
+@pytest.mark.parametrize("kind,not_in,build_null", [
+    ("left_semi", False, False),
+    ("left_anti", False, False),
+    ("left_anti", True, False),         # NOT IN, no NULL in the set
+    ("left_anti", True, True),          # NOT IN over a set holding NULL
+    ("mark", False, False),
+])
+def test_lut_probe_equals_bsearch_probe(kind, not_in, build_null):
+    """The fused probe through an existence build's LUT selects, marks and
+    gathers what the binary search over the same build does, and what the
+    SQL semantics say, for probe keys below lut_base, past the span,
+    negative, NULL and inactive."""
+    from ydb_tpu.ops import fused as F
+    keys = _q18_keys()
+    valid = None
+    if build_null:
+        keys, valid = np.append(keys, 777), np.append(
+            np.ones(len(keys), bool), False)
+    payload = kind == "mark"
+    block = _key_block(keys, valid, payload=payload)
+    pnames = ["p"] if payload else []
+    lut_bt = mj.build(block, "k", pnames, existence=True)
+    bs_bt = mj.build(block, "k", pnames, existence=False)
+    assert lut_bt.lut is not None and bs_bt.lut is None
+    for bt in (lut_bt, bs_bt):
+        bt.anti_has_null = build_null      # what the executor's check sets
+
+    rng = np.random.default_rng(37)
+    probe = np.concatenate([_EDGE_PROBES, rng.choice(keys, 3000),
+                            rng.integers(-10, _Q18_SPAN + 10, 5000)])
+    pvalid = np.ones(len(probe), bool)
+    pvalid[rng.choice(len(probe), 300, replace=False)] = False   # NULL
+    pvalid[len(_EDGE_PROBES) - 1] = False
+    active = np.ones(len(probe), bool)
+    active[rng.choice(len(probe), 200, replace=False)] = False
+    env = {"fk": (jnp.asarray(probe), jnp.asarray(pvalid))}
+    meta = {"probe_key": "fk", "kind": kind, "src_names": tuple(pnames),
+            "payload_names": tuple(pnames), "mark_col": "m",
+            "not_in": not_in}
+
+    def run(bt, bsearch):
+        out_env, out_sel = jax.jit(
+            lambda e, s, b: mj.probe_lut_traced(
+                e, s, b, dict(meta, bsearch=bsearch)))(
+            env, jnp.asarray(active), F.build_traced_inputs(bt))
+        return np.asarray(out_sel), {
+            n: tuple(np.asarray(a) for a in dv)
+            for n, dv in out_env.items() if n != "fk"}
+
+    sel_lut, cols_lut = run(lut_bt, False)
+    sel_bs, cols_bs = run(bs_bt, True)
+    np.testing.assert_array_equal(sel_lut, sel_bs)
+    assert cols_lut.keys() == cols_bs.keys()
+    for n in cols_lut:
+        found = cols_lut["m"][0] if kind == "mark" else None
+        for a, b in zip(cols_lut[n], cols_bs[n]):
+            if n == "p":        # payload data is only defined where found
+                a, b = np.where(found, a, 0), np.where(found, b, 0)
+            np.testing.assert_array_equal(a, b)
+
+    member = np.isin(probe, keys[:len(_q18_keys())]) & pvalid & active
+    want = {"left_semi": member, "mark": active,
+            "left_anti": ~member & active
+            & (pvalid if not_in else True)
+            & (not build_null)}[kind]
+    np.testing.assert_array_equal(sel_lut, want)
+    if kind == "mark":
+        np.testing.assert_array_equal(cols_lut["m"][0], member)
+        np.testing.assert_array_equal(
+            np.where(member, cols_lut["p"][0], 0.0),
+            np.where(member, probe * 0.5, 0.0))
+
+
 # -- sort_total: the radix lowering equals the one wide lax.sort -----------
 
 
@@ -302,3 +440,47 @@ def test_blocked_cumsum_matches_numpy(n):
     ints = rng.integers(-9, 9, size=n)
     np.testing.assert_array_equal(
         np.asarray(X.cumsum(jnp.asarray(ints))), np.cumsum(ints))
+
+
+def test_q18_existence_luts_count_once_a_set_and_share_one_program():
+    """Q18's semi join at every quantity of the join cell (248..252) gets
+    an existence LUT, one build a literal set, and every set runs the one
+    program its shape compiled: the LUT is sized by the key span, which
+    the literal does not move."""
+    from tests.tpch_util import QUERIES
+    from ydb_tpu.bench.tpch_gen import load_tpch
+    from ydb_tpu.query import QueryEngine
+    from ydb_tpu.utils.metrics import GLOBAL
+
+    eng = QueryEngine(block_rows=1 << 16)
+    data = load_tpch(eng.catalog, sf=0.01)
+    li = pd.DataFrame(data.tables["lineitem"])
+    qty = li.groupby("l_orderkey").l_quantity.sum()
+
+    def count(name):
+        return GLOBAL.snapshot().get(name, 0)
+
+    def run(q):
+        got = eng.query(QUERIES["q18"].replace("> 250", f"> {q}"))
+        assert eng.executor.last_path == "fused"
+        want = qty[qty > q]
+        assert 0 < len(want) * 64 < want.index.max() - want.index.min()
+        assert sorted(got.o_orderkey) == sorted(want.index)
+        np.testing.assert_array_equal(
+            got.set_index("o_orderkey").total_qty.loc[want.index], want)
+
+    before = count("join/existence_lut_builds")
+    run(248)
+    # the shape's first statement and its compile-ahead thunk may both
+    # miss the build cache (PERF.md section 7: they upload twice too)
+    assert count("join/existence_lut_builds") - before in (1, 2)
+    run(248)
+    programs = count("prog/registered")
+    for q in (249, 250, 251, 252):
+        before = count("join/existence_lut_builds")
+        run(q)
+        assert count("join/existence_lut_builds") - before == 1
+    assert count("prog/registered") == programs
+    plan = "\n".join(eng.query(
+        "explain analyze " + QUERIES["q18"])["plan"])
+    assert "probe=lut,lut lut_span=16384,16384" in plan

@@ -52,6 +52,9 @@ def _host_key(block: HostBlock, name: str) -> tuple[np.ndarray, Optional[np.ndar
 
 _LUT_SPAN_BUDGET = 1 << 26         # max direct-address entries (256MB int32)
 _FD_BUDGET = 1 << 28               # max host bytes retained for FD checks
+# join kinds whose probe asks only whether a key is in the build: the
+# LUT's one job is membership, so no payload density caps its span
+EXISTENCE_KINDS = ("left_semi", "left_anti", "mark")
 
 
 @dataclass
@@ -86,7 +89,12 @@ class BuildTable:
 
 
 def build(block: HostBlock, key: str, payload_names: list[str],
-          keep_fd: bool = False) -> BuildTable:
+          keep_fd: bool = False, existence: bool = False) -> BuildTable:
+    """Sort the build side and, for an integral key whose span fits, fill
+    its direct-address LUT. `existence`: the join is semi / anti / mark
+    (`EXISTENCE_KINDS`), so the LUT is bounded by `_LUT_SPAN_BUDGET` alone
+    and not by the 64x density cap that weighs it against a payload."""
+    from ydb_tpu.utils.metrics import GLOBAL
     enc, valid = _host_key(block, key)
     if valid is not None:
         # null build keys never match; drop them
@@ -108,8 +116,11 @@ def build(block: HostBlock, key: str, payload_names: list[str],
         span = hi - lo + 1
         # density cap 64x: a filtered 1.6M-row build over a 15M-key span
         # (TPC-H q3/q18 shapes) is a 60MB LUT — far cheaper than losing
-        # whole-query fusion; the absolute budget still bounds HBM
-        if 0 < span <= max(1 << 12, min(_LUT_SPAN_BUDGET, 64 * len(enc))):
+        # whole-query fusion; the absolute budget still bounds HBM. An
+        # existence build skips the cap: Q18's 6k-key `having` set over
+        # 1.5M orderkeys probes with one gather, not a 13-step bsearch
+        dense = max(1 << 12, min(_LUT_SPAN_BUDGET, 64 * len(enc)))
+        if 0 < span <= (_LUT_SPAN_BUDGET if existence else dense):
             span_cap = bucket_capacity(span, minimum=1024)
             lut_np = np.full(span_cap, -1, np.int32)
             offs = (enc - lo).astype(np.int64)
@@ -119,6 +130,12 @@ def build(block: HostBlock, key: str, payload_names: list[str],
                                            dtype=np.int32)
             lut = jnp.asarray(lut_np)
             lut_base = lo
+            if span > dense:
+                GLOBAL.inc("join/existence_lut_builds")
+    if lut is None:
+        GLOBAL.inc("join/bsearch_builds")
+    else:
+        GLOBAL.inc("join/lut_builds")
 
     payload, payload_valid, dicts = {}, {}, {}
     for name in payload_names:
@@ -216,7 +233,8 @@ def bsearch_traced(keys_sorted, enc):
 def probe_lut_traced(env: dict, sel, bt_arrays: dict, meta: dict):
     """Build-probe inside a fused query trace (`ops/fused.py`): a
     direct-address LUT gather when the build has one, an unrolled
-    binary search otherwise (sparse key spans, float keys).
+    binary search otherwise (a payload build's sparse span, a span past
+    the LUT budget, float keys).
 
     env: {name: (data, valid|None)}; sel: bool selection mask — REQUIRED,
     and must already include the row-activity mask (`iota < length`; the

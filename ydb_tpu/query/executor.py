@@ -98,6 +98,21 @@ def _count_latemat_reads(layout_box: dict) -> None:
         GLOBAL.inc("latemat/gathered_cols", lm_read["gathered"])
 
 
+def _note_probes(sp, builds: list) -> None:
+    """The `join-builds` span's attrs, one entry a build in step order:
+    `probe` (`lut`, `bsearch` or `partitioned`) and `lut_span`, the LUT's
+    entries (0 without one)."""
+    if not builds:
+        return
+    luts = [getattr(bt, "lut", None) for bt in builds]
+    sp.attrs["probe"] = ",".join(
+        "partitioned" if isinstance(bt, J.PartitionedBuild)
+        else "bsearch" if lut is None else "lut"
+        for bt, lut in zip(builds, luts))
+    sp.attrs["lut_span"] = ",".join(
+        "0" if lut is None else str(lut.shape[0]) for lut in luts)
+
+
 class Executor:
     def __init__(self, catalog, block_rows: int = DEFAULT_BLOCK_ROWS,
                  device_cache=None, mesh=None):
@@ -465,8 +480,9 @@ class Executor:
         join_steps = [step for kind, step in pipe.steps if kind == "join"]
         if len(join_steps) > self.fuse_max_joins:
             return None                  # program-complexity cap
-        with self._span("join-builds", n=len(join_steps)):
+        with self._span("join-builds", n=len(join_steps)) as sp:
             builds = self._prepare_builds(pipe, params, snapshot)
+            _note_probes(sp, builds)
         for step, bt in zip(join_steps, builds):
             if isinstance(bt, J.PartitionedBuild) or (
                     not bt.unique and step.kind in ("inner", "left", "mark")):
@@ -1058,7 +1074,8 @@ class Executor:
                 "mark_col": step.mark_col,
                 "not_in": step.not_in,
                 "payload_cols": payload_cols,
-                # sparse key spans have no LUT; float PROBES must not
+                # a payload build's sparse span (or one past the LUT
+                # budget) has no LUT; float PROBES must not
                 # truncate through an integer LUT — both take the
                 # unrolled binary search in the trace
                 "bsearch": bt.lut is None
@@ -1390,8 +1407,9 @@ class Executor:
         if len(join_steps) > self.fuse_max_joins:
             return None
         params0 = dict(members[0][1])
-        with self._span("join-builds", n=len(join_steps)):
+        with self._span("join-builds", n=len(join_steps)) as sp:
             builds = self._prepare_builds(pipe, params0, snapshot)
+            _note_probes(sp, builds)
         for step, bt in zip(join_steps, builds):
             if isinstance(bt, J.PartitionedBuild) or (
                     not bt.unique and step.kind in ("inner", "left",
@@ -2643,7 +2661,8 @@ class Executor:
                                            list(step.payload),
                                            self.grace_budget_bytes)
         bt = J.build(built, step.build_key, list(step.payload),
-                     keep_fd=keep_fd)
+                     keep_fd=keep_fd,
+                     existence=step.kind in J.EXISTENCE_KINDS)
         bt.anti_has_null = anti_has_null
         return bt
 

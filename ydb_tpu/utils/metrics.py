@@ -225,6 +225,15 @@ COUNTER_REGISTRY = {
     "executor/fused_plans": "(derived) live fused-plan cache entries",
     "executor/tiled_queries": "queries run through the tiled path",
     "executor/shuffle_joins": "mesh shuffle-join executions",
+    "join/lut_builds":
+        "[viz] join builds given a direct-address LUT (a probe is one "
+        "gather)",
+    "join/bsearch_builds":
+        "[viz] join builds left to the binary search (float keys, a "
+        "span past the LUT budget, or a sparse payload build)",
+    "join/existence_lut_builds":
+        "[viz] of join/lut_builds, semi / anti / mark builds the 64x "
+        "density cap alone would have refused",
     "mesh/exchange_rows/*":
         "(dynamic) rows fed to a mesh exchange, by exchange kind and the "
         "device that held them (mesh/exchange_rows/<kind>/dev<id>)",

@@ -288,13 +288,15 @@ def test_batch_wait_is_a_span_and_a_phase_on_leader_and_follower(
     assert spans["batch-wait"]["leader"] is True
     # the leader's own phases stay what they are, disjoint from the wait
     assert {"admission_ms", "dispatch_ms", "device_ms", "queue_ms",
-            "readout_ms"} <= set(stats.phases)
+            "readout_ms", "prepare_ms", "param_upload_ms",
+            "bookkeeping_ms"} <= set(stats.phases)
     d = spans["device-dispatch-batched"]
     assert d["b"] == 4 and d["reserved_mb"] >= 0 and d["temp_mb"] >= 0
     assert spans["admission-wait"]["waited"] is False
     for stats, spans in followers:
         assert spans["batch-wait"]["leader"] is False
-        assert set(stats.phases) == {"batch_wait_ms"}
+        # and its own analysis after its trace closed
+        assert set(stats.phases) == {"batch_wait_ms", "bookkeeping_ms"}
         assert "device-dispatch-batched" not in spans
     text = "\n".join(leaders[0][0].render().splitlines())
     assert "sealed by full" in text and "4 member slots (0 pad)" in text

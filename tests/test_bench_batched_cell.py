@@ -176,8 +176,10 @@ def test_the_cell_reports_the_sf10_scan_cells_metrics_and_its_four():
     assert of(CELL, "end_to_end") == of("tpch-sf10.scan", "end_to_end") \
         == {"queries_per_s", "latency_p50_ms", "setup_s"}
     new = [m for m in BENCHMARK["per_layer"] if m["name"] in NEW_METRICS]
-    assert [m["name"] for m in BENCHMARK["per_layer"][-4:]] \
-        == list(NEW_METRICS)
+    # appended together, in this order (later PRs append after them)
+    names = [m["name"] for m in BENCHMARK["per_layer"]]
+    at = names.index(NEW_METRICS[0])
+    assert names[at:at + 4] == list(NEW_METRICS)
     for m in new:
         assert m["workloads"][0] == CELL     # later cells may join
         assert set(m) == {"name", "unit", "better", "source", "layer",

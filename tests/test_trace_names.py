@@ -46,9 +46,13 @@ SCOPES = {
 NO_SCOPES = {"q1": ("jit_lineitem_gs_", ("latemat[",)),
              "q6": ("jit_lineitem_g_", ("latemat[", "compact"))}
 PROGRAM_SPANS = ("statement", "parse", "plan", "admission-wait", "execute",
-                 "fused-attempt", "join-builds", "superblock-upload",
-                 "device-dispatch", "device-execute", "readout-transfer",
-                 "readout")
+                 "fused-attempt", "join-builds", "fused-prepare",
+                 "superblock-upload", "param-upload", "device-dispatch",
+                 "device-execute", "readout-transfer", "readout")
+# the engine call's own phases: (phase, its span or None)
+ENGINE_PHASES = (("prepare_ms", "fused-prepare"),
+                 ("param_upload_ms", "param-upload"),
+                 ("bookkeeping_ms", None))
 
 
 def other_literals(q: str) -> str:
@@ -173,6 +177,149 @@ def test_admission_wait_is_a_phase(eng):
     eng.query(QUERIES["q6"])
     assert "admission_ms" in eng.last_stats.phases
     assert tracing.PHASE_SPANS["admission-wait"] == "admission_ms"
+
+
+# -- (a2) the engine call's own time, named ---------------------------------
+
+
+@pytest.mark.parametrize("q", ["q1", "q6"])
+def test_every_phase_of_the_engine_call_is_named(eng, q):
+    import time
+    for _ in range(2):
+        eng.execute(QUERIES[q])             # compiled, planned, cached
+    t0 = time.perf_counter()
+    eng.execute(QUERIES[q])
+    wall = (time.perf_counter() - t0) * 1e3
+    st = eng.last_stats
+    ph = st.phases
+    for phase, span in ENGINE_PHASES:
+        assert ph[phase] >= 0
+        if span is not None:
+            assert tracing.PHASE_SPANS[span] == phase
+    assert ph["bookkeeping_ms"] > 0
+    assert st.parse_ms + st.plan_ms + sum(ph.values()) <= wall
+    prep = next(s for s in eng.last_trace if s.name == "fused-prepare")
+    assert prep.attrs["hit"] is True and prep.attrs["compact"] == 0
+    up = next(s for s in eng.last_trace if s.name == "param-upload")
+    assert up.attrs["n"] >= 0 and up.attrs["bytes"] >= 0
+    by_id = {s.span_id: s for s in eng.last_trace}
+    assert by_id[up.parent_id] is prep
+    assert by_id[prep.parent_id].name == "fused-attempt"
+
+
+def test_bookkeeping_is_counted_and_annotated(eng, annotations):
+    eng.query(QUERIES["q6"])
+    b0 = GLOBAL.get("engine/bookkeeping_ms")
+    del annotations[:]
+    eng.query(QUERIES["q6"])
+    assert GLOBAL.get("engine/bookkeeping_ms") - b0 == pytest.approx(
+        eng.last_stats.phases["bookkeeping_ms"], abs=0.01)
+    assert annotations.count("bookkeeping") == 1
+    # no span: it runs after the statement's trace closed
+    assert not any(s.name == "bookkeeping" for s in eng.last_trace)
+
+
+@pytest.mark.parametrize("cache", ["miss", "hit"])
+def test_plan_span_times_the_plan_lap_on_a_hit_too(eng, cache):
+    if cache == "miss":
+        eng._plan_cache.clear()
+    else:
+        eng.query(QUERIES["q6"])
+    eng.query(QUERIES["q6"])
+    assert eng.last_stats.plan_cache_hit is (cache == "hit")
+    plan, = [s for s in eng.last_trace if s.name == "plan"]
+    assert plan.attrs["cache"] == cache
+    # the span opens after the lap starts and closes where it is taken
+    # (round the read's registration, the path checks, the fingerprint
+    # and the lookup or the planner)
+    assert 0 < plan.dur_ms <= eng.last_stats.plan_ms + 1e-3
+    assert "plan_ms" not in tracing.PHASE_SPANS.values()
+
+
+def test_a_host_lane_ends_the_plan_span_before_it_runs(eng):
+    eng.query("select 1 + 1 as two")
+    assert eng.executor.last_path == "literal"
+    plan, = [s for s in eng.last_trace if s.name == "plan"]
+    assert "cache" not in plan.attrs
+    assert eng.last_stats.plan_ms == 0.0
+
+
+@pytest.fixture
+def analyses(monkeypatch):
+    """How many times each of a statement's analyses runs."""
+    from ydb_tpu.utils import critpath, memledger
+    seen = {"extract": 0, "phase_breakdown": 0, "summary": 0}
+
+    def counting(name, real):
+        def f(*a, **kw):
+            seen[name] += 1
+            return real(*a, **kw)
+        return f
+    monkeypatch.setattr(critpath, "extract",
+                        counting("extract", critpath.extract))
+    monkeypatch.setattr(tracing, "phase_breakdown",
+                        counting("phase_breakdown", tracing.phase_breakdown))
+    monkeypatch.setattr(memledger.MemLedger, "summary", counting(
+        "summary", memledger.MemLedger.summary))
+    return seen
+
+
+def test_an_outermost_statement_is_analysed_once(eng, analyses):
+    eng.query(QUERIES["q6"])
+    for k in analyses:
+        analyses[k] = 0
+    eng.query(QUERIES["q6"])
+    assert analyses == {"extract": 1, "phase_breakdown": 1, "summary": 1}
+    st = eng.last_stats
+    prof = eng.profiles[-1]
+    # the one analysis feeds the statement, its profile and the ring
+    assert st.critical_path == {k: v for k, v in prof["critical_path"]
+                                .items() if k != "segments"}
+    assert eng.critpath_stats[-1]["wall_ms"] == st.critical_path["wall_ms"]
+    assert st.memory["peak_bytes"] > 0
+
+
+def test_explain_analyze_still_prints_the_critical_path(eng, analyses):
+    df = eng.query("explain analyze " + QUERIES["q6"])
+    text = "\n".join(df["plan"])
+    assert "-- critical path: " in text and "coverage" in text
+    assert "-- phases: " in text and "prepare " in text
+    # the inner statement's own analysis, then the outer one's close
+    assert analyses["extract"] == 2 and analyses["phase_breakdown"] == 2
+
+
+@pytest.mark.parametrize("span,cls", [("fused-prepare", "host_lane"),
+                                      ("param-upload", "host_transfer"),
+                                      ("plan", "host_lane")])
+def test_critical_path_classes_the_new_spans(span, cls):
+    from ydb_tpu.utils import critpath
+    assert critpath.CLASS_BY_NAME[span] == cls
+
+
+def _reader(name):
+    import importlib.util
+    from pathlib import Path
+    path = Path(__file__).resolve().parent.parent / "benchmark" \
+        / "metrics" / f"{name}.py"
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.mark.parametrize("phase", [p for p, _span in ENGINE_PHASES])
+def test_the_phases_reader_takes_the_median(phase):
+    from types import SimpleNamespace as NS
+    read = _reader(phase)
+
+    def ctx(*phases):
+        return {"samples": [NS(call=None if p is None else NS(phases=p))
+                            for p in phases]}
+    assert read(ctx({phase: 3.0}, {phase: 1.0}, {"device_ms": 9.0},
+                    None, {phase: 2.0, "device_ms": 7.0})) == 2.0
+    # a program without the phase (the parent), or no statement sampled
+    assert read(ctx({"device_ms": 9.0}, {}, None)) is None
+    assert read(ctx()) is None
 
 
 # -- (b) programs and IR commands named on the device -----------------------
@@ -314,8 +461,10 @@ def test_one_stream_waits_for_nothing(eng):
         pytest.approx(span.attrs["queue_ms"], abs=0.01)
     assert st.programs["device_ms"] == pytest.approx(ph["device_ms"],
                                                      abs=0.01)
-    # disjoint: parse, plan and the phases fit inside the engine's wall
-    assert st.parse_ms + st.plan_ms + sum(ph.values()) <= st.total_ms + 0.5
+    # disjoint: parse, plan and the phases fit inside the engine's wall,
+    # `total_ms` and then the statement's own bookkeeping
+    assert st.parse_ms + st.plan_ms + sum(ph.values()) \
+        <= st.total_ms + ph["bookkeeping_ms"] + 0.5
 
 
 def test_phase_breakdown_counts_an_unsplit_span_whole():

@@ -209,7 +209,7 @@ class DqTaskRunner:
                 self.mem_summary = led.summary()
                 rm = getattr(self.engine, "_record_memory", None)
                 if rm is not None:
-                    rm(f"dq-graph:{graph.tag}", "dq", led)
+                    rm(f"dq-graph:{graph.tag}", "dq", self.mem_summary)
             self._cleanup(graph)
             ring = getattr(self.engine, "dq_stage_stats", None)
             if ring is not None:

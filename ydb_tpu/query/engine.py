@@ -12,6 +12,8 @@ from __future__ import annotations
 
 import logging
 import os
+import time
+from contextlib import ExitStack, nullcontext
 from contextlib import contextmanager as _contextmanager
 from typing import Optional
 
@@ -472,7 +474,6 @@ class QueryEngine:
             GLOBAL.inc("engine/throttled")
             raise QueryError("query rate limit exceeded (quoter: the "
                              "'queries' resource bucket is empty)")
-        from contextlib import nullcontext
         session = session or self._default_session
         # per-session statement serialization (SESSION_BUSY analog —
         # concurrency comes from many sessions, not one)
@@ -501,22 +502,67 @@ class QueryEngine:
             ok = True
             return block
         finally:
-            if pst is not None:
-                progstats.close_statement(pst)
-            if led is not None:
-                memledger.close_statement(led)
-                self._record_memory(sql, kind_box[0] if kind_box else "",
-                                    led)
             self.last_trace = self.tracer.end_trace()
-            # profiles record USER statements: a DQ stage program run
-            # through a legacy (context-free) caller is still internal
-            if outermost and self.last_trace \
-                    and not self.executor.dq_stage_depth:
-                self._record_profile(sql, self.last_trace,
-                                     memory=led.summary()
-                                     if led is not None else None)
-            if not _internal:
-                self._audit(sql, ok, kind_box[0] if kind_box else "")
+            kind = kind_box[0] if kind_box else ""
+            # the statement's own analysis and records, after its trace
+            # closed: an outermost read's phase `bookkeeping_ms` (from
+            # `_finish_stats` to here), the `bookkeeping` annotation on
+            # the profiler's clock
+            with self.tracer.annotate("bookkeeping") if outermost \
+                    else nullcontext():
+                self._close_statement(sql, kind, pst, led, outermost)
+                if not _internal:
+                    self._audit(sql, ok, kind)
+            if outermost:
+                self._note_bookkeeping()
+
+    def _close_statement(self, sql: str, kind: str, pst, led,
+                         outermost: bool) -> None:
+        """Close the program accumulator and the resource ledger the
+        statement owns, and analyse an OUTERMOST statement once, over its
+        closed trace: the phases, the ledger and program roll-ups and the
+        critical path land on its `QueryStats` (a nested statement had
+        them from `_finish_stats`) and, the same figures, in its profile."""
+        from ydb_tpu.utils import memledger, progstats
+        if pst is not None:
+            progstats.close_statement(pst)
+        memory = None
+        if led is not None:
+            memledger.close_statement(led)
+            memory = led.summary()
+            self._record_memory(sql, kind, memory)
+        if not outermost:
+            return
+        st = self.last_stats
+        phases = cp = None
+        if getattr(st, "_book_t0", None) is not None:
+            # latency histograms and slow-query notes count USER
+            # statements: not a worker's DQ stage program
+            cp = self._analyse(st, self.last_trace, memory, pst,
+                               user=not self.executor.dq_stage_depth)
+            phases = st.phases
+        # profiles record USER statements: a DQ stage program run
+        # through a legacy (context-free) caller is still internal
+        if self.last_trace and not self.executor.dq_stage_depth:
+            self._record_profile(sql, self.last_trace, memory=memory,
+                                 phases=phases, cp=cp)
+
+    def _note_bookkeeping(self) -> None:
+        """Close an outermost read's `bookkeeping_ms`: the time from
+        `_finish_stats` (where `total_ms` stops) to the end of its
+        `execute`, counted in `engine/bookkeeping_ms` and, for a sampled
+        statement, written into its `phases` (a new dict: the profile
+        keeps the spans' own)."""
+        st = self.last_stats
+        t0 = getattr(st, "_book_t0", None)
+        if t0 is None:
+            return
+        st._book_t0 = None
+        ms = round((time.perf_counter() - t0) * 1000.0, 3)
+        from ydb_tpu.utils.metrics import GLOBAL
+        GLOBAL.inc("engine/bookkeeping_ms", ms)
+        if self.last_trace:
+            st.phases = {**st.phases, "bookkeeping_ms": ms}
 
     def _sample_decision(self, sql: str) -> bool:
         """Admission-time trace sampling: rate-based, with forced-on for
@@ -541,12 +587,11 @@ class QueryEngine:
                 return True
         return False
 
-    def _record_memory(self, sql: str, kind: str, led) -> None:
-        """Append one closed ledger to the `.sys/query_memory` ring.
-        Statements that never touched the device (DDL, constant
+    def _record_memory(self, sql: str, kind: str, s: dict) -> None:
+        """Append one closed ledger's summary to the `.sys/query_memory`
+        ring. Statements that never touched the device (DDL, constant
         SELECTs) are skipped — a ring of zero rows would bury the
         queries this view exists to rank."""
-        s = led.summary()
         if not (s["peak_bytes"] or s["transfers"] or s["padded_bytes"]):
             return
         self.memory_stats.append({
@@ -567,7 +612,8 @@ class QueryEngine:
     def _record_profile(self, sql: str, spans: list,
                         stage_stats: list = None, total_ms: float = None,
                         rows_out: int = None, kind: str = None,
-                        memory: dict = None) -> None:
+                        memory: dict = None, phases: dict = None,
+                        cp: dict = None) -> None:
         """Append one assembled profile to the last-N ring
         (`.sys/query_profiles`): the span tree plus its device-timeline
         rollup. `stage_stats`: the DQ runner's per-(stage, worker) rows
@@ -575,8 +621,12 @@ class QueryEngine:
         router passes the DQ wall explicitly — for a distributed query
         `last_stats` holds only the router-MERGE statement's numbers
         (or a previous statement's, when the final stage had no merge
-        SQL), not the graph's."""
+        SQL), not the graph's. `phases` / `cp`: the statement's closing
+        analysis (`_analyse`) of these spans, computed here without it."""
         from ydb_tpu.utils.tracing import phase_breakdown
+        if phases is None:
+            phases = phase_breakdown(spans)
+            cp = self._critical_path(spans, memory)
         st = self.last_stats
         # last_stats is only trustworthy when it belongs to THIS
         # statement and finished: a statement that raised before (or
@@ -596,37 +646,46 @@ class QueryEngine:
                   else round(spans[0].dur_ms, 3)),
             "rows_out": rows_out if rows_out is not None
             else (int(st.rows_out) if mine else 0),
-            "phases": phase_breakdown(spans),
+            "phases": phases,
             "n_spans": len(spans),
             "spans": [s.to_dict() for s in spans],
             "stages": list(stage_stats or []),
         }
-        # critical-path extraction (utils/critpath.py): which chain of
-        # segments actually bounded this query's wall — classified,
-        # counted (`crit/*`), ringed (`.sys/query_critical_path`), and
-        # stored on the profile for the `/trace/<id>` timeline export.
-        # Lever-gated: YDB_TPU_CRITPATH=0 freezes all of it.
-        from ydb_tpu.utils import critpath
-        if critpath.enabled():
-            try:
-                cp = critpath.extract(spans, memory=memory)
-                rec["critical_path"] = cp
-                critpath.record_counters(cp)
-                self.critpath_stats.append({
-                    "trace_id": rec["trace_id"], "sql": sql,
-                    "kind": rec["kind"], "wall_ms": cp["wall_ms"],
-                    "coverage": cp["coverage"],
-                    "connected": cp["connected"],
-                    "non_device_ms": cp["non_device_ms"],
-                    "dominant_span": cp["dominant_span"],
-                    "dominant_class": cp["dominant_class"],
-                    "dominant_ms": cp["dominant_ms"],
-                    **{f"{cls}_ms": cp["classes"].get(cls, 0.0)
-                       for cls in critpath.CLASSES},
-                })
-            except Exception:                # noqa: BLE001 — analysis
-                pass                         # must never fail a query
+        # the critical path (utils/critpath.py): which chain of segments
+        # actually bounded this query's wall — classified, counted
+        # (`crit/*`), ringed (`.sys/query_critical_path`), and stored on
+        # the profile for the `/trace/<id>` timeline export. Lever-gated:
+        # YDB_TPU_CRITPATH=0 freezes all of it (`cp` is then None)
+        if cp is not None:
+            from ydb_tpu.utils import critpath
+            rec["critical_path"] = cp
+            critpath.record_counters(cp)
+            self.critpath_stats.append({
+                "trace_id": rec["trace_id"], "sql": sql,
+                "kind": rec["kind"], "wall_ms": cp["wall_ms"],
+                "coverage": cp["coverage"],
+                "connected": cp["connected"],
+                "non_device_ms": cp["non_device_ms"],
+                "dominant_span": cp["dominant_span"],
+                "dominant_class": cp["dominant_class"],
+                "dominant_ms": cp["dominant_ms"],
+                **{f"{cls}_ms": cp["classes"].get(cls, 0.0)
+                   for cls in critpath.CLASSES},
+            })
         self.profiles.append(rec)
+
+    @staticmethod
+    def _critical_path(spans, memory) -> Optional[dict]:
+        """`critpath.extract` over a statement's spans, joined with its
+        ledger's bytes; None under YDB_TPU_CRITPATH=0 or where the
+        analysis fails (it must never fail a query)."""
+        from ydb_tpu.utils import critpath
+        if not critpath.enabled():
+            return None
+        try:
+            return critpath.extract(spans, memory=memory)
+        except Exception:                    # noqa: BLE001 — analysis
+            return None
 
     def _audit(self, sql: str, ok: bool, kind: str) -> None:
         """Audit trail for mutating statements (the ydb/core/audit sink):
@@ -714,27 +773,38 @@ class QueryEngine:
             if isinstance(stmt, ast.Explain):
                 return self._explain_stmt(stmt, session)
             if isinstance(stmt, (ast.SetOp, ast.Select)):
-                # read locks FIRST — every select path (fused, windowed,
-                # set-op, materialized) must register conflicts
-                names = self._referenced_tables(stmt)
-                stats.tables = sorted(names)
-                if tx is not None:
-                    for name in names:
-                        if self.catalog.has(name):
-                            tx.lock(self.catalog.table(name))
-                # register the snapshot: auto-compaction must not restamp
-                # portions this lock-free read still scans. Autocommit
-                # reads re-take the snapshot ATOMICALLY with registration;
-                # tx snapshots are already coordinator-pinned, so their
-                # registration has no gap to race.
-                if tx is None:
-                    snap = self._register_read()
-                else:
-                    self._enter_read(snap.plan_step)
-                try:
-                    return self._execute_read(stmt, sql, snap, stats, t)
-                finally:
-                    self._exit_read(snap.plan_step)
+                # the `plan` span runs with the `plan_ms` lap, from here to
+                # the plan in hand (a cached one too: attr `cache`);
+                # `_execute_read` ends it there, or where a host lane takes
+                # the statement
+                with ExitStack() as planning:
+                    plan_sp = planning.enter_context(self.tracer.span("plan"))
+
+                    def end_plan(**attrs):
+                        plan_sp.attrs.update(attrs)
+                        planning.close()
+                    # read locks FIRST — every select path (fused, windowed,
+                    # set-op, materialized) must register conflicts
+                    names = self._referenced_tables(stmt)
+                    stats.tables = sorted(names)
+                    if tx is not None:
+                        for name in names:
+                            if self.catalog.has(name):
+                                tx.lock(self.catalog.table(name))
+                    # register the snapshot: auto-compaction must not restamp
+                    # portions this lock-free read still scans. Autocommit
+                    # reads re-take the snapshot ATOMICALLY with registration;
+                    # tx snapshots are already coordinator-pinned, so their
+                    # registration has no gap to race.
+                    if tx is None:
+                        snap = self._register_read()
+                    else:
+                        self._enter_read(snap.plan_step)
+                    try:
+                        return self._execute_read(stmt, sql, snap, stats, t,
+                                                  end_plan)
+                    finally:
+                        self._exit_read(snap.plan_step)
             # everything below mutates shared state — one writer at a time
             # (readers above run lock-free over their MVCC snapshots)
             with self.lock:   # noqa: SIM117
@@ -819,28 +889,34 @@ class QueryEngine:
         except (BindError, PlanError) as e:
             raise QueryError(str(e)) from e
 
-    def _execute_read(self, stmt, sql: str, snap, stats, t) -> HostBlock:
-        """SELECT / set-op execution — lock-free, runs concurrently."""
+    def _execute_read(self, stmt, sql: str, snap, stats, t,
+                      end_plan) -> HostBlock:
+        """SELECT / set-op execution — lock-free, runs concurrently.
+        `end_plan(**attrs)` ends the statement's `plan` span."""
         from ydb_tpu.utils.metrics import GLOBAL
         # collect this read's view-serving decisions (thread-local:
         # reads run concurrently) for QueryStats / EXPLAIN ANALYZE
         self._view_tls.notes = []
         if isinstance(stmt, ast.SetOp):
+            end_plan()
             block = self._execute_set_op(stmt, snap)
             self.executor.last_path = "set-op"
             self._finish_stats(stats, t, block)
             return block
         from ydb_tpu.query import window as W
         if W.has_window(stmt):
+            end_plan()
             block = self._execute_windowed(stmt, snap)
             self._finish_stats(stats, t, block)
             return block
         if stmt.relation is None:
+            end_plan()
             block = self._select_without_from(stmt, snap)
             self.executor.last_path = "literal"
             self._finish_stats(stats, t, block)
             return block
         if self._needs_materialize(stmt):
+            end_plan()
             block = self._execute_materialized(stmt, snap)
             self._finish_stats(stats, t, block)
             return block
@@ -858,11 +934,11 @@ class QueryEngine:
             stats.plan_cache_hit = True
             GLOBAL.inc("engine/plan_cache_hits")
         else:
-            with self.tracer.span("plan"):
-                plan = self.planner.plan_select(stmt)
+            plan = self.planner.plan_select(stmt)
             if self.config.flag("enable_plan_cache"):
                 self._plan_cache[sql] = (fp, plan)
             GLOBAL.inc("engine/plan_cache_misses")
+        end_plan(cache="hit" if stats.plan_cache_hit else "miss")
         stats.plan_ms = t.lap()
         # memory admission (kqp_rm_service analog): reserve the
         # scan+build estimate; oversubscribed queries queue here
@@ -1055,8 +1131,7 @@ class QueryEngine:
 
     def _finish_stats(self, stats, t, block) -> None:
         from ydb_tpu.ops.xla_exec import groupby_trace_delta
-        from ydb_tpu.utils.metrics import GLOBAL, GLOBAL_HIST
-        from ydb_tpu.utils.tracing import phase_breakdown
+        from ydb_tpu.utils.metrics import GLOBAL
         stats.execute_ms = t.lap()
         stats.total_ms = stats.parse_ms + stats.plan_ms + stats.execute_ms
         stats.rows_out = block.length
@@ -1071,36 +1146,27 @@ class QueryEngine:
                         if k.startswith("bounds_")}
         stats.groupby = {k: v for k, v in delta.items()
                          if not k.startswith("bounds_")}
+        GLOBAL.inc("engine/rows_out", block.length)
+        GLOBAL.inc("engine/queries")
+        self.query_history.append(stats)
+        if self.tracer._state().depth <= 1:
+            # an outermost statement is analysed once, at its close
+            # (`_close_statement`), over its whole trace: its
+            # `bookkeeping_ms` runs from here, where `total_ms` stops
+            stats._book_t0 = t.t0
+            return
+        # a nested statement (EXPLAIN ANALYZE's inner one, the DQ router
+        # merge) now, over its own span window: it shares the trace with
+        # spans that are not its own. The window is rooted under a CLOSED
+        # copy of its still-open statement span: un-spanned statement-
+        # interior time (binder work, dictionary predicate evaluation,
+        # CTE/derived-table materialization — the q13 host lane) then
+        # classifies as the statement's host_lane self-time instead of
+        # vanishing into a virtual-root scheduler gap
+        from ydb_tpu.utils import memledger, progstats
+        window = []
         if self.tracer.sampled:
-            stats.phases = phase_breakdown(
-                self.tracer.spans[getattr(stats, "_span_mark", 0):])
-        # resource-ledger rollup as of NOW (the ledger closes in
-        # execute() after this statement returns; EXPLAIN ANALYZE and
-        # bench read stats.memory, so the live summary attaches here)
-        from ydb_tpu.utils import memledger
-        led = memledger.current()
-        if led is not None:
-            stats.memory = led.summary()
-        # program roofline rollup (utils/progstats.py): which compiled
-        # programs this statement executed, their measured device ms
-        # joined to the compiler's cost model — the `-- programs:` block
-        from ydb_tpu.utils import progstats
-        ps = progstats.current()
-        if ps is not None:
-            stats.programs = ps.summary()
-        # per-statement critical path over the same span window (the
-        # EXPLAIN ANALYZE `-- critical path:` source, joined with the
-        # live ledger's bytes); the full-tree extraction with counters
-        # and the sysview ring happens once in _record_profile
-        from ydb_tpu.utils import critpath
-        if self.tracer.sampled and critpath.enabled():
             window = self.tracer.spans[getattr(stats, "_span_mark", 0):]
-            # root the window under a CLOSED copy of the still-open
-            # statement span: un-spanned statement-interior time (binder
-            # work, dictionary predicate evaluation, CTE/derived-table
-            # materialization — the q13 host lane) then classifies as
-            # the statement's host_lane self-time instead of vanishing
-            # into a virtual-root scheduler gap
             stk = self.tracer._stack
             if stk:
                 import dataclasses as _dc
@@ -1108,21 +1174,39 @@ class QueryEngine:
                     stk[-1],
                     dur_ms=self.tracer._now() - stk[-1].start_ms)] \
                     + window
-            if window:
-                try:
-                    stats.critical_path = critpath.summarize(
-                        critpath.extract(window, memory=stats.memory))
-                except Exception:            # noqa: BLE001 — analysis
-                    pass                     # must never fail a query
-        # latency histograms count USER statements once: a nested
-        # internal statement (EXPLAIN ANALYZE's re-entrant execute, the
-        # DQ router-merge SELECT — its trace depth is >1) must not add a
-        # second, cheaper sample that drags p50 down and doubles count.
-        # Worker-side DQ stage programs are excluded via dq_stage_depth,
-        # NOT trace depth — an unsampled task opens no trace, and the
-        # histogram contents must not depend on the sampling rate
-        if self.tracer._state().depth <= 1 \
-                and not self.executor.dq_stage_depth:
+        # the resource ledger as of NOW (the enclosing statement's, or
+        # its own, which closes in execute() after this returns)
+        led = memledger.current()
+        self._analyse(stats, window,
+                      led.summary() if led is not None else None,
+                      progstats.current(), user=False)
+
+    def _analyse(self, stats, spans: list, memory, programs,
+                 user: bool) -> Optional[dict]:
+        """A finished statement's own analysis: `phases` (the phase
+        spans' own times), the resource-ledger and program roll-ups
+        (`programs`: the statement's `progstats` accumulator, the
+        `-- programs:` block), and the critical path over `spans` (the
+        EXPLAIN ANALYZE `-- critical path:` source), which it returns:
+        None where the statement was unsampled or the lever is off.
+        `user`: a user statement, counted once in the latency histograms
+        and the slow-query notes (not a nested internal statement, whose
+        second, cheaper sample would drag p50 down and double count, nor
+        a worker's DQ stage program)."""
+        from ydb_tpu.utils import critpath
+        from ydb_tpu.utils.metrics import GLOBAL_HIST
+        from ydb_tpu.utils.tracing import phase_breakdown
+        if memory is not None:
+            stats.memory = memory
+        if programs is not None:
+            stats.programs = programs.summary()
+        cp = None
+        if spans:
+            stats.phases = phase_breakdown(spans)
+            cp = self._critical_path(spans, stats.memory)
+            if cp is not None:
+                stats.critical_path = critpath.summarize(cp)
+        if user:
             GLOBAL_HIST.observe("query/latency_ms", stats.total_ms)
             GLOBAL_HIST.observe("query/parse_ms", stats.parse_ms)
             GLOBAL_HIST.observe("query/plan_ms", stats.plan_ms)
@@ -1133,9 +1217,7 @@ class QueryEngine:
             # the bounded forced-trace set and inflate slow_query/*
             self._note_slow(stats.sql, stats.total_ms, stats.kind)
             self._note_host_slow(stats)
-        GLOBAL.inc("engine/rows_out", block.length)
-        GLOBAL.inc("engine/queries")
-        self.query_history.append(stats)
+        return cp
 
     def _note_slow(self, sql: str, total_ms: float, kind: str) -> None:
         """Slow-query log counter family + the forced-sampling set: a

@@ -12,6 +12,7 @@ not a host round-trip per stage.
 
 from __future__ import annotations
 
+from contextlib import ExitStack
 from functools import partial as _partial
 from typing import Optional
 
@@ -319,12 +320,32 @@ class Executor:
         GLOBAL.inc("prog/queue_ms", queue_ms)
         progstats.record_exec(prog_kid, run_ms, fresh=fresh)
 
+    def _upload_params(self, params: dict, builds: list):
+        """The `param-upload` span: the device puts made before every
+        fused or stacked dispatch, the statement's array literals (IN
+        lists, rank LUTs, members' stacked literals; scalars ride the
+        call) and each build's three scalar inputs. Attrs `n` (arrays
+        put) and `bytes`. -> (device params, build inputs)."""
+        from ydb_tpu.ops import fused as F
+        with self._span("param-upload") as sp:
+            dev_params = {k: (jnp.asarray(v) if isinstance(v, np.ndarray)
+                              else v) for k, v in params.items()}
+            build_inputs = [F.build_traced_inputs(bt) for bt in builds]
+            puts = [dev_params[k] for k, v in params.items()
+                    if isinstance(v, np.ndarray)] \
+                + [bi[k] for bi in build_inputs
+                   for k in ("lut_base", "n", "has_null")]
+            sp.attrs["n"] = len(puts)
+            sp.attrs["bytes"] = sum(int(a.nbytes) for a in puts)
+        return dev_params, build_inputs
+
     def _await_stage(self, blocks: list, t_enqueued: float) -> None:
-        """The wait for a mesh lane's per-device prefix programs, which
-        run on every device at once: ONE `device-execute` for the stage,
-        enqueued when its first program was. They are the per-block
-        programs of `ops/xla_exec.ProgramCache`, many to a stage, so the
-        run is joined to no single program's inventory entry."""
+        """The wait for programs that ran block by block, enqueued since
+        `t_enqueued`: a mesh lane's per-device prefix programs, which run
+        on every device at once, or a shuffle join's build pipeline. ONE
+        `device-execute` for them all. They are the per-block programs of
+        `ops/xla_exec.ProgramCache`, many to a stage, so the run is
+        joined to no single program's inventory entry."""
         self._await_device([(b.arrays, b.valids, b.length) for b in blocks],
                            t_enqueued, None, False)
 
@@ -489,129 +510,139 @@ class Executor:
                 return builds   # partitioned / expanding probe
 
         plan0 = plan            # pre-rewrite plan (the overflow-rerun input)
-        (plan, pipe, scan_cols, schema, partial_schema, dicts,
-         join_metas, late_scan) = self._fused_plan_setup(plan, builds)
+        # `fused-prepare`: the host's own work from the builds to the
+        # dispatch, phase `prepare_ms` (the superblock's and the literals'
+        # uploads nest under it as phases of their own); `hit`: the program
+        # was cached, `compact`: the Compact's capacity or 0. The tiled
+        # lane's run is no preparation: it leaves the span first
+        with ExitStack() as preparing:
+            prep = preparing.enter_context(self._span("fused-prepare"))
+            (plan, pipe, scan_cols, schema, partial_schema, dicts,
+             join_metas, late_scan) = self._fused_plan_setup(plan, builds)
 
-        storage_names = [s for (s, _i) in pipe.scan.columns]
-        rename = {s: i for (s, i) in pipe.scan.columns}
+            storage_names = [s for (s, _i) in pipe.scan.columns]
+            rename = {s: i for (s, i) in pipe.scan.columns}
 
-        # HBM admission: a scan whose stacked superblock would not fit the
-        # budget streams through the tiled path instead of OOMing the chip
-        from ydb_tpu.storage.device_cache import (
-            enumerate_scan_sources, estimate_scan_bytes,
-        )
-        sources, src_ids = enumerate_scan_sources(table, snapshot,
-                                                  pipe.scan.prune or None)
-        # shape buckets: quantize the source count so a growing table
-        # reuses the bucket's program (zero-length pad rows, masked out
-        # by the per-row length vector exactly like short real sources)
-        Kb = shape_buckets.bucket_sources(len(sources))
-        if sources and estimate_scan_bytes(sources, storage_names,
-                                           pad_to=Kb) \
-                > self.fused_scan_budget_bytes:
-            return self._execute_fused_tiled(
-                plan, params, pipe, sources, scan_cols, builds, join_metas,
-                dicts, partial_schema)
+            # HBM admission: a scan whose stacked superblock would not fit the
+            # budget streams through the tiled path instead of OOMing the chip
+            from ydb_tpu.storage.device_cache import (
+                enumerate_scan_sources, estimate_scan_bytes,
+            )
+            sources, src_ids = enumerate_scan_sources(table, snapshot,
+                                                      pipe.scan.prune or None)
+            # shape buckets: quantize the source count so a growing table
+            # reuses the bucket's program (zero-length pad rows, masked out
+            # by the per-row length vector exactly like short real sources)
+            Kb = shape_buckets.bucket_sources(len(sources))
+            if sources and estimate_scan_bytes(sources, storage_names,
+                                               pad_to=Kb) \
+                    > self.fused_scan_budget_bytes:
+                preparing.close()
+                return self._execute_fused_tiled(
+                    plan, params, pipe, sources, scan_cols, builds,
+                    join_metas, dicts, partial_schema)
 
-        sb = self._superblock(table, storage_names, rename, snapshot,
-                              pipe.scan.prune or None, sources, src_ids, Kb)
-        if sb is None:
-            return builds or None          # empty scan → portioned path
-        arrays, valids, lengths, K, CAP, sb_dicts = sb
-        sb_valid_names = frozenset(valids.keys())
-        dicts.update(sb_dicts)
-        # resource ledger: the scan's device working set is the stacked
-        # (K, CAP) superblock; live rows come from the host-side source
-        # blocks (no device sync)
-        from ydb_tpu.utils import memledger
-        memledger.record_padded_buffers(
-            "superblock", "superblock",
-            int(sum(b.length for b in sources)) if sources else 0,
-            K * CAP, arrays, valids)
+            sb = self._superblock(table, storage_names, rename, snapshot,
+                                  pipe.scan.prune or None, sources, src_ids,
+                                  Kb)
+            if sb is None:
+                return builds or None          # empty scan → portioned path
+            arrays, valids, lengths, K, CAP, sb_dicts = sb
+            sb_valid_names = frozenset(valids.keys())
+            dicts.update(sb_dicts)
+            # resource ledger: the scan's device working set is the stacked
+            # (K, CAP) superblock; live rows come from the host-side source
+            # blocks (no device sync)
+            from ydb_tpu.utils import memledger
+            memledger.record_padded_buffers(
+                "superblock", "superblock",
+                int(sum(b.length for b in sources)) if sources else 0,
+                K * CAP, arrays, valids)
 
-        sort_params, sort_spec, rank_assigns = self._sort_setup_fused(
-            plan, schema, dicts)
-        all_params = {**params, **sort_params}
+            sort_params, sort_spec, rank_assigns = self._sort_setup_fused(
+                plan, schema, dicts)
+            all_params = {**params, **sort_params}
 
-        # lifted LIMIT (paramlift plans only): the clamp rides in as the
-        # __lim2 device input and the program keys on the limit's
-        # capacity bucket — `limit 3` and `limit 5` share one executable
-        lift_limit, lim_key = self._lift_limit_setup(plan, all_params)
+            # lifted LIMIT (paramlift plans only): the clamp rides in as the
+            # __lim2 device input and the program keys on the limit's
+            # capacity bucket — `limit 3` and `limit 5` share one executable
+            lift_limit, lim_key = self._lift_limit_setup(plan, all_params)
 
-        builds_sig = tuple(F.build_inputs_sig(bt) for bt in builds)
-        base_key = F.fused_cache_key(plan, scan_cols, K, CAP,
-                                     sb_valid_names, builds_sig, sort_spec,
-                                     rank_assigns,
-                                     tuple(sorted(all_params)),
-                                     lim_key=lim_key)
-        # bound-sized device compaction: when the filters/joins provably
-        # collapse the live count, an `ir.Compact` shrinks the pipeline
-        # from scan capacity to a ladder-quantized bound directly after
-        # the last reducing join, and every later probe, deferred
-        # late-mat gather and the partial group-by compile at the small
-        # shape; none where what follows reads its rows in place (a
-        # keyless aggregate: `latemat.tail_reads_in_place`). Sized from
-        # CBO + FK selectivities plus the
-        # measured-live memo; an underestimate trips the device overflow
-        # flag in `fetch` and the statement reruns WITHOUT the compact —
-        # loud and counted, never a silent truncation.
-        declined: dict = {}
-        compact_cap, compact_at = (None, None) if _no_compact else \
-            self._compact_sizing(base_key, pipe, builds, sources, K * CAP,
-                                 join_metas, declined)
-        compact_prog = None
-        key = base_key
-        if compact_cap:
-            compact_prog = ir.Program([ir.Compact(compact_cap)])
-            key = F.fused_cache_key(plan, scan_cols, K, CAP,
-                                    sb_valid_names, builds_sig, sort_spec,
-                                    rank_assigns,
-                                    tuple(sorted(all_params)),
-                                    lim_key=lim_key,
-                                    compact_cap=compact_cap,
-                                    compact_at=compact_at)
-        from ydb_tpu.utils.metrics import GLOBAL
-        ndeferred = len(late_scan) + sum(
-            len(m["payload_names"]) for m in join_metas if m["late"])
-        if ndeferred:
-            GLOBAL.inc("latemat/deferred_cols", ndeferred)
-        if compact_cap:
-            GLOBAL.inc("latemat/compact_plans")
-            GLOBAL.inc("latemat/compact_capacity_rows", compact_cap)
-            if compact_at < len(pipe.steps):
-                GLOBAL.inc("latemat/compact_early_plans")
-            if attempt is not None:
-                attempt.attrs.update(compact_cap=compact_cap,
-                                     compact_at=compact_at)
-        elif declined:
-            GLOBAL.inc("latemat/compact_skipped_plans")
-            if attempt is not None:
-                attempt.attrs.update(declined)
+            builds_sig = tuple(F.build_inputs_sig(bt) for bt in builds)
+            base_key = F.fused_cache_key(plan, scan_cols, K, CAP,
+                                         sb_valid_names, builds_sig, sort_spec,
+                                         rank_assigns,
+                                         tuple(sorted(all_params)),
+                                         lim_key=lim_key)
+            # bound-sized device compaction: when the filters/joins provably
+            # collapse the live count, an `ir.Compact` shrinks the pipeline
+            # from scan capacity to a ladder-quantized bound directly after
+            # the last reducing join, and every later probe, deferred
+            # late-mat gather and the partial group-by compile at the small
+            # shape; none where what follows reads its rows in place (a
+            # keyless aggregate: `latemat.tail_reads_in_place`). Sized from
+            # CBO + FK selectivities plus the
+            # measured-live memo; an underestimate trips the device overflow
+            # flag in `fetch` and the statement reruns WITHOUT the compact —
+            # loud and counted, never a silent truncation.
+            declined: dict = {}
+            compact_cap, compact_at = (None, None) if _no_compact else \
+                self._compact_sizing(base_key, pipe, builds, sources, K * CAP,
+                                     join_metas, declined)
+            compact_prog = None
+            key = base_key
+            if compact_cap:
+                compact_prog = ir.Program([ir.Compact(compact_cap)])
+                key = F.fused_cache_key(plan, scan_cols, K, CAP,
+                                        sb_valid_names, builds_sig, sort_spec,
+                                        rank_assigns,
+                                        tuple(sorted(all_params)),
+                                        lim_key=lim_key,
+                                        compact_cap=compact_cap,
+                                        compact_at=compact_at)
+            from ydb_tpu.utils.metrics import GLOBAL
+            ndeferred = len(late_scan) + sum(
+                len(m["payload_names"]) for m in join_metas if m["late"])
+            if ndeferred:
+                GLOBAL.inc("latemat/deferred_cols", ndeferred)
+            if compact_cap:
+                GLOBAL.inc("latemat/compact_plans")
+                GLOBAL.inc("latemat/compact_capacity_rows", compact_cap)
+                if compact_at < len(pipe.steps):
+                    GLOBAL.inc("latemat/compact_early_plans")
+                if attempt is not None:
+                    attempt.attrs.update(compact_cap=compact_cap,
+                                         compact_at=compact_at)
+            elif declined:
+                GLOBAL.inc("latemat/compact_skipped_plans")
+                if attempt is not None:
+                    attempt.attrs.update(declined)
 
-        def _builder():
-            fn, layout_box = F.build_fused_fn(
-                pipe, plan.final_program, scan_cols, K, CAP, sb_valid_names,
-                join_metas, rank_assigns, sort_spec, plan.limit, plan.offset,
-                tuple(dict.fromkeys(n for (n, _lbl) in plan.output)),
-                lift_limit=lift_limit, late_scan=late_scan,
-                compact_prog=compact_prog, compact_at=compact_at)
-            keep = list(dict.fromkeys(n for (n, _lbl) in plan.output))
-            out_cols = [c for c in schema.columns if c.name in keep] \
-                or list(schema.columns)
-            return fn, layout_box, Schema(out_cols)
+            def _builder():
+                fn, layout_box = F.build_fused_fn(
+                    pipe, plan.final_program, scan_cols, K, CAP,
+                    sb_valid_names, join_metas, rank_assigns, sort_spec,
+                    plan.limit, plan.offset,
+                    tuple(dict.fromkeys(n for (n, _lbl) in plan.output)),
+                    lift_limit=lift_limit, late_scan=late_scan,
+                    compact_prog=compact_prog, compact_at=compact_at)
+                keep = list(dict.fromkeys(n for (n, _lbl) in plan.output))
+                out_cols = [c for c in schema.columns if c.name in keep] \
+                    or list(schema.columns)
+                return fn, layout_box, Schema(out_cols)
 
-        entry = self._fused_cache.get(key)
-        fresh_compile = entry is None
-        if entry is not None:
-            fn, layout_box, out_schema = entry
-            progstats.record_hit(getattr(fn, "key_id", None))
-            self._consume_trace_debt("fused", key)
-        else:
-            fn = layout_box = out_schema = None
+            entry = self._fused_cache.get(key)
+            fresh_compile = entry is None
+            if entry is not None:
+                fn, layout_box, out_schema = entry
+                progstats.record_hit(getattr(fn, "key_id", None))
+                self._consume_trace_debt("fused", key)
+            else:
+                fn = layout_box = out_schema = None
 
-        dev_params = {k: (jnp.asarray(v) if isinstance(v, np.ndarray) else v)
-                      for k, v in all_params.items()}
-        build_inputs = [F.build_traced_inputs(bt) for bt in builds]
+            prep.attrs.update(hit=entry is not None, compact=compact_cap or 0)
+            dev_params, build_inputs = self._upload_params(all_params,
+                                                           builds)
         with self._span("device-dispatch", k=K, cap=CAP) as dsp:
             import time as _time
             t_disp = _time.perf_counter()
@@ -1415,124 +1446,126 @@ class Executor:
                     not bt.unique and step.kind in ("inner", "left",
                                                     "mark")):
                 return None
-        lift_sig = getattr(plan, "lift_sig", None)
-        (plan, pipe, scan_cols, schema, partial_schema, dicts,
-         join_metas, late_scan) = self._fused_plan_setup(plan, builds)
+        # `fused-prepare` and `param-upload`: the same phases as the
+        # per-query path's (`_try_execute_fused`), the builds excepted
+        with self._span("fused-prepare", compact=0) as prep:
+            lift_sig = getattr(plan, "lift_sig", None)
+            (plan, pipe, scan_cols, schema, partial_schema, dicts,
+             join_metas, late_scan) = self._fused_plan_setup(plan, builds)
 
-        storage_names = [s for (s, _i) in pipe.scan.columns]
-        rename = {s: i for (s, i) in pipe.scan.columns}
-        sources, src_ids = enumerate_scan_sources(table, snapshot, None)
-        Kb = shape_buckets.bucket_sources(len(sources))
-        if not sources or estimate_scan_bytes(sources, storage_names,
-                                              pad_to=Kb) \
-                > self.fused_scan_budget_bytes:
-            return None                  # empty / tiled-class scan
-        sb = self._superblock(table, storage_names, rename, snapshot, None,
-                              sources, src_ids, Kb)
-        if sb is None:
-            return None
-        arrays, valids, lengths, K, CAP, sb_dicts = sb
-        sb_valid_names = frozenset(valids.keys())
-        dicts.update(sb_dicts)
-        from ydb_tpu.utils import memledger
-        memledger.record_padded_buffers(
-            "superblock", "superblock",
-            int(sum(b.length for b in sources)), K * CAP, arrays, valids)
+            storage_names = [s for (s, _i) in pipe.scan.columns]
+            rename = {s: i for (s, i) in pipe.scan.columns}
+            sources, src_ids = enumerate_scan_sources(table, snapshot, None)
+            Kb = shape_buckets.bucket_sources(len(sources))
+            if not sources or estimate_scan_bytes(sources, storage_names,
+                                                  pad_to=Kb) \
+                    > self.fused_scan_budget_bytes:
+                return None                  # empty / tiled-class scan
+            sb = self._superblock(table, storage_names, rename, snapshot, None,
+                                  sources, src_ids, Kb)
+            if sb is None:
+                return None
+            arrays, valids, lengths, K, CAP, sb_dicts = sb
+            sb_valid_names = frozenset(valids.keys())
+            dicts.update(sb_dicts)
+            from ydb_tpu.utils import memledger
+            memledger.record_padded_buffers(
+                "superblock", "superblock",
+                int(sum(b.length for b in sources)), K * CAP, arrays, valids)
 
-        sort_params, sort_spec, rank_assigns = self._sort_setup_fused(
-            plan, schema, dicts)
+            sort_params, sort_spec, rank_assigns = self._sort_setup_fused(
+                plan, schema, dicts)
 
-        # per-member param dicts (sort params are batch-invariant; a
-        # LIMIT always lifts here — see _lift_limit_setup — so each
-        # member clamps to ITS OWN limit+offset, not the leader's)
-        lift_limit, lim_key = self._lift_limit_setup(plan, force=True)
-        mem_params = []
-        for (mp, prms) in members:
-            p = {**prms, **sort_params}
-            if lift_limit:
-                p[F.LIMIT_PARAM] = np.int32(mp.limit + (mp.offset or 0))
-            mem_params.append(p)
-        names = sorted(mem_params[0])
-        for p in mem_params[1:]:
-            if sorted(p) != names:
-                return None              # shape drift — lane sig was stale
+            # per-member param dicts (sort params are batch-invariant; a
+            # LIMIT always lifts here — see _lift_limit_setup — so each
+            # member clamps to ITS OWN limit+offset, not the leader's)
+            lift_limit, lim_key = self._lift_limit_setup(plan, force=True)
+            mem_params = []
+            for (mp, prms) in members:
+                p = {**prms, **sort_params}
+                if lift_limit:
+                    p[F.LIMIT_PARAM] = np.int32(mp.limit + (mp.offset or 0))
+                mem_params.append(p)
+            names = sorted(mem_params[0])
+            for p in mem_params[1:]:
+                if sorted(p) != names:
+                    return None          # shape drift — lane sig was stale
 
-        # WHICH params ride the batch axis is a property of the shape,
-        # not of this batch's values: once any member differs, every
-        # numeric SCALAR literal is stacked, whether or not its values
-        # happen to agree here (a herd whose sixteen all ask `quantity <
-        # 24` must not meet a program of its own, compiled inside the
-        # window); arrays (rank LUTs, shared pool arrays, IN lists) stack
-        # only where they differ — B device copies of a LUT are not free
-        # — and broadcast via in_axes=None otherwise
-        B = len(members)
-        column = {n: [p[n] for p in mem_params] for n in names}
-        differs = {n: not all(_param_values_equal(v[0], x) for x in v[1:])
-                   for n, v in column.items()}
-        stack_scalars = bool(ahead_bb) or any(differs.values())
-        axes, stacked = {}, {}
-        for n in names:
-            vals = column[n]
+            # WHICH params ride the batch axis is a property of the shape,
+            # not of this batch's values: once any member differs, every
+            # numeric SCALAR literal is stacked, whether or not its values
+            # happen to agree here (a herd whose sixteen all ask `quantity <
+            # 24` must not meet a program of its own, compiled inside the
+            # window); arrays (rank LUTs, shared pool arrays, IN lists) stack
+            # only where they differ — B device copies of a LUT are not free
+            # — and broadcast via in_axes=None otherwise
+            B = len(members)
+            column = {n: [p[n] for p in mem_params] for n in names}
+            differs = {n: not all(_param_values_equal(v[0], x) for x in v[1:])
+                       for n, v in column.items()}
+            stack_scalars = bool(ahead_bb) or any(differs.values())
+            axes, stacked = {}, {}
+            for n in names:
+                vals = column[n]
+                if ahead_bb:
+                    vals = vals * ahead_bb
+                v0 = np.asarray(vals[0])
+                if differs[n] or (stack_scalars and v0.ndim == 0
+                                  and v0.dtype.kind in "biuf"):
+                    arrs = [np.asarray(v) for v in vals]
+                    if any(a.shape != arrs[0].shape or a.dtype != arrs[0].dtype
+                           for a in arrs[1:]):
+                        # array params whose SHAPES vary with the literal
+                        # (integer IN lists) — Param fingerprints carry no
+                        # shape, so the sig can't split these; decline
+                        return None
+                    axes[n] = 0
+                    stacked[n] = np.stack(arrs)
+                else:
+                    axes[n] = None
+                    stacked[n] = vals[0]
+            mapped = tuple(n for n in names if axes[n] == 0)
             if ahead_bb:
-                vals = vals * ahead_bb
-            v0 = np.asarray(vals[0])
-            if differs[n] or (stack_scalars and v0.ndim == 0
-                              and v0.dtype.kind in "biuf"):
-                arrs = [np.asarray(v) for v in vals]
-                if any(a.shape != arrs[0].shape or a.dtype != arrs[0].dtype
-                       for a in arrs[1:]):
-                    # array params whose SHAPES vary with the literal
-                    # (integer IN lists) — Param fingerprints carry no
-                    # shape, so the sig can't split these; decline
-                    return None
-                axes[n] = 0
-                stacked[n] = np.stack(arrs)
+                Bb, member_rows = ahead_bb, []
+            elif mapped:
+                Bb = batch_bucket(B)
+                if Bb > B:
+                    pad = Bb - B             # pad by repeating the last member
+                    for n in mapped:
+                        stacked[n] = np.concatenate(
+                            [stacked[n]] + [stacked[n][-1:]] * pad)
+                member_rows = list(range(B))
             else:
-                axes[n] = None
-                stacked[n] = vals[0]
-        mapped = tuple(n for n in names if axes[n] == 0)
-        if ahead_bb:
-            Bb, member_rows = ahead_bb, []
-        elif mapped:
-            Bb = batch_bucket(B)
-            if Bb > B:
-                pad = Bb - B             # pad by repeating the last member
-                for n in mapped:
-                    stacked[n] = np.concatenate(
-                        [stacked[n]] + [stacked[n][-1:]] * pad)
-            member_rows = list(range(B))
-        else:
-            # every member identical (a same-text storm): one execution,
-            # every member unpacks row 0
-            Bb = 1
-            member_rows = [0] * B
+                # every member identical (a same-text storm): one execution,
+                # every member unpacks row 0
+                Bb = 1
+                member_rows = [0] * B
 
-        builds_sig = tuple(F.build_inputs_sig(bt) for bt in builds)
-        base_key = F.fused_cache_key(plan, scan_cols, K, CAP,
-                                     sb_valid_names, builds_sig, sort_spec,
-                                     rank_assigns, tuple(names),
-                                     lim_key=lim_key)
-        keep = tuple(dict.fromkeys(n for (n, _lbl) in plan.output))
+            builds_sig = tuple(F.build_inputs_sig(bt) for bt in builds)
+            base_key = F.fused_cache_key(plan, scan_cols, K, CAP,
+                                         sb_valid_names, builds_sig, sort_spec,
+                                         rank_assigns, tuple(names),
+                                         lim_key=lim_key)
+            keep = tuple(dict.fromkeys(n for (n, _lbl) in plan.output))
 
-        def _builder():
-            bfn, box = F.build_fused_batched_fn(
-                pipe, plan.final_program, scan_cols, K, CAP, sb_valid_names,
-                join_metas, rank_assigns, sort_spec, plan.limit,
-                plan.offset, keep, dict(axes), Bb, lift_limit=lift_limit,
-                late_scan=late_scan)
-            out_cols = [c for c in schema.columns if c.name in keep] \
-                or list(schema.columns)
-            return bfn, box, Schema(out_cols)
+            def _builder():
+                bfn, box = F.build_fused_batched_fn(
+                    pipe, plan.final_program, scan_cols, K, CAP,
+                    sb_valid_names, join_metas, rank_assigns, sort_spec,
+                    plan.limit, plan.offset, keep, dict(axes), Bb,
+                    lift_limit=lift_limit, late_scan=late_scan)
+                out_cols = [c for c in schema.columns if c.name in keep] \
+                    or list(schema.columns)
+                return bfn, box, Schema(out_cols)
 
-        dev_params = {k: (jnp.asarray(v) if isinstance(v, np.ndarray)
-                          else v) for k, v in stacked.items()}
-        build_inputs = [F.build_traced_inputs(bt) for bt in builds]
-        return SimpleNamespace(
-            plan=plan, dicts=dicts, K=K, CAP=CAP, Bb=Bb,
-            member_rows=member_rows, builder=_builder,
-            key=("batched", base_key, Bb, mapped),
-            args=(arrays, valids, lengths, build_inputs, dev_params),
-            bytes_key=(lift_sig, table.uid, table.data_version, Bb))
+            key = ("batched", base_key, Bb, mapped)
+            prep.attrs["hit"] = key in self._fused_cache
+            dev_params, build_inputs = self._upload_params(stacked, builds)
+            return SimpleNamespace(
+                plan=plan, dicts=dicts, K=K, CAP=CAP, Bb=Bb,
+                member_rows=member_rows, builder=_builder, key=key,
+                args=(arrays, valids, lengths, build_inputs, dev_params),
+                bytes_key=(lift_sig, table.uid, table.data_version, Bb))
 
     # -- what a stacked program holds ---------------------------------------
 
@@ -2328,13 +2361,18 @@ class Executor:
         capacity, build rows), or None where the exchange does not cover
         the shape; every decline hands the block to the broadcast path
         via `prebuilt` so it is never executed twice."""
+        import time as _time
+
         from ydb_tpu.parallel import shuffle_join as SJ
         if isinstance(step.build, QueryPlan):
             built = self.execute(step.build, snapshot)
         else:
-            built = HostBlock.concat(
-                [to_host(d) for d in
-                 self._run_pipeline(step.build, params, snapshot)])
+            # the chip's run of the build pipeline is a `device-execute`
+            # (`device_ms`), not the host's partitioning time
+            t_enq = _time.perf_counter()
+            pending = self._run_pipeline(step.build, params, snapshot)
+            self._await_stage(pending, t_enq)
+            built = HostBlock.concat([to_host(d) for d in pending])
         if prebuilt is not None:
             prebuilt[j] = built
         if step.build_hash_keys:

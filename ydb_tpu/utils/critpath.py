@@ -53,6 +53,7 @@ CLASS_BY_NAME = {
     "spill-merge": "device_execute",
     "compile": "compile",
     "superblock-upload": "host_transfer",
+    "param-upload": "host_transfer",
     "readout-transfer": "host_transfer",
     # the engine's drain phase: on the fused path its device-execute /
     # readout-transfer children carry the time (self ~0); on the
@@ -62,6 +63,7 @@ CLASS_BY_NAME = {
     "parse": "host_lane",
     "plan": "host_lane",
     "join-builds": "host_lane",
+    "fused-prepare": "host_lane",
     "task-exec": "host_lane",
     "window-device": "device_execute",
     "window-host-lane": "host_lane",

@@ -213,6 +213,10 @@ COUNTER_REGISTRY = {
     "engine/rows_out": "result rows returned",
     "engine/plan_cache_hits": "text-keyed plan cache hits",
     "engine/plan_cache_misses": "text-keyed plan cache misses",
+    "engine/bookkeeping_ms":
+        "outermost reads' own analysis and records after `total_ms` "
+        "(phases, ledger and program roll-ups, critical path, "
+        "histograms, profile, audit), summed",
     "engine/plan_cache_size": "(derived) live plan-cache entries",
     "engine/throttled": "statements rejected by the quoter",
     "engine/ttl_evicted": "rows dropped by TTL sweeps",
@@ -611,12 +615,15 @@ class QueryStats:
     # execution); empty when the lane is off or the shape was ineligible
     batching: dict = field(default_factory=dict)
     # device-timeline attribution (`utils/tracing.phase_breakdown` over
-    # this statement's spans): {admission_ms, build_ms, upload_ms,
-    # dispatch_ms, queue_ms, device_ms, readout_ms, compile_ms}, on a
-    # mesh lane also {mesh_build_ms, stage_ms, exchange_ms, merge_ms}
-    # (the host's own time in each step), disjoint; device_ms is the
-    # programs' run, queue_ms the wait behind another statement's —
-    # empty when the statement was unsampled or never touched the device
+    # this statement's spans): {admission_ms, build_ms, prepare_ms,
+    # upload_ms, param_upload_ms, dispatch_ms, queue_ms, device_ms,
+    # readout_ms, compile_ms}, on a mesh lane also {mesh_build_ms,
+    # stage_ms, exchange_ms, merge_ms} (the host's own time in each
+    # step), disjoint; device_ms is the programs' run, queue_ms the wait
+    # behind another statement's. An outermost read adds
+    # `bookkeeping_ms`: its own analysis and records, from where
+    # `total_ms` stops to the end of `QueryEngine.execute` (no span: the
+    # trace has closed). Empty when the statement was unsampled
     phases: dict = field(default_factory=dict)
     # resource-ledger rollup (`utils/memledger.MemLedger.summary`):
     # peak/alloc device bytes, padding live-vs-padded account, host
@@ -690,8 +697,9 @@ class QueryStats:
             out += ("\n-- phases: " + " | ".join(
                 f"{k.removesuffix('_ms')} {p[k]:.1f}ms"
                 for k in ("batch_wait_ms", "admission_ms", "compile_ms",
-                          "build_ms", "upload_ms", "dispatch_ms",
-                          "queue_ms", "device_ms", "readout_ms")
+                          "build_ms", "prepare_ms", "upload_ms",
+                          "param_upload_ms", "dispatch_ms", "queue_ms",
+                          "device_ms", "readout_ms")
                 if k in p))
         if self.memory and (self.memory.get("peak_bytes")
                             or self.memory.get("transfers")):

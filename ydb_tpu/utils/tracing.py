@@ -5,7 +5,7 @@ phases in `TSpan`s uploaded via OTLP (`ydb/library/actors/wilson/
 wilson_span.h`, `wilson_uploader.cpp`), with per-request sampling decided
 at admission (`ydb/core/jaeger_tracing/`). Here the span tree covers a
 statement's phases (parse → plan → execute, with executor sub-spans for
-build/upload/dispatch/device-execute/readout, and on a mesh lane
+build/prepare/upload/dispatch/device-execute/readout, and on a mesh lane
 mesh-build/mesh-stage/mesh-exchange/mesh-merge), and the SAME tree spans
 processes: a DQ task runner forwards `(trace_id, parent_span_id,
 sampled)` over the `DqRunTask` RPC, workers record their task spans
@@ -89,6 +89,13 @@ PHASE_SPANS = {
     # a follower the leader's whole execution
     "batch-wait": "batch_wait_ms",
     "join-builds": "build_ms",
+    # the executor's host preparation of a fused or stacked program,
+    # from the builds to the dispatch (`query/executor.py`): the plan
+    # walk, scan sources, sort and limit setup, the program key and the
+    # Compact's sizing, the cache lookup; the superblock's upload and
+    # the literals' puts nest under it as phases of their own
+    "fused-prepare": "prepare_ms",
+    "param-upload": "param_upload_ms",
     "superblock-upload": "upload_ms",
     "device-dispatch": "dispatch_ms",
     "device-dispatch-batched": "dispatch_ms",
@@ -239,9 +246,10 @@ class Tracer:
         return _SpanCtx(self, name, attrs)
 
     def annotate(self, name: str):
-        """A profiler annotation and no span: for work a front does for
-        the thread's LAST statement after its trace closed (encoding the
-        answer). Follows that statement's sampling decision."""
+        """A profiler annotation and no span: for work done for the
+        thread's LAST statement after its trace closed (the engine's
+        bookkeeping, a front encoding the answer). Follows that
+        statement's sampling decision."""
         return _Annotation(name) if self._state().last_sampled \
             else nullcontext()
 

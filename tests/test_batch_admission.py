@@ -389,8 +389,10 @@ def test_members_that_agree_on_a_literal_meet_no_new_program(monkeypatch):
 # -- lane off: the accepted cells' programs ----------------------------------
 
 # digests of `repr(fused cache key)` of Q1's and Q6's programs at sf 0.002
-# as the parent commit 04e90d5 gives them (lane off, as every accepted cell)
-PARENT_KEYS = ["0a1bede3b81e", "589d6e537b2a"]
+# as the parent commit 04e90d5 gives them (lane off, as every accepted
+# cell), since `ir.GroupBy.key_nullable` joined each program's fingerprint
+# (Q1's two NOT NULL keys: six buckets where that commit had twelve)
+PARENT_KEYS = ["4576c477e0fc", "512d396bc789"]
 
 
 @pytest.mark.parametrize("lane", ["off", "on"])

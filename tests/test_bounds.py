@@ -124,6 +124,18 @@ def test_groupby_domain_product():
     assert BD.groupby_bound(ir.GroupBy((), ())) == 1
 
 
+def test_groupby_domain_product_without_null_slots():
+    # a key that cannot be NULL takes no NULL slot: 3 x 4, where a
+    # nullable pair takes 4 x 5
+    aggs = (ir.Agg("c", "count_all"),)
+    assert BD.groupby_bound(ir.GroupBy(("x", "y"), aggs, key_domains=(3, 4),
+                                       key_nullable=(False, False))) == 12
+    assert BD.groupby_bound(ir.GroupBy(("x", "y"), aggs, key_domains=(3, 4),
+                                       key_nullable=(True, True))) == 20
+    assert BD.groupby_bound(ir.GroupBy(("x", "y"), aggs, key_domains=(3, 4),
+                                       key_nullable=(True, False))) == 16
+
+
 def test_unknown_groupby_is_capacity():
     gb = ir.GroupBy(("x",), (ir.Agg("c", "count_all"),))
     assert BD.groupby_bound(gb) == 0

@@ -525,7 +525,7 @@ def test_end_position_is_the_program_it_was(tpch, monkeypatch, q, early):
     tpch.query(sql)                      # builds cached, sizing settled
     if q in ("q1", "q6"):
         # Q1 and Q6 compact on a forged bound only: their tails (a
-        # 12-bucket one-hot, a keyless sum) decline the Compact (PR 34)
+        # 6-bucket one-hot, a keyless sum) decline the Compact
         _forge_lineitem(tpch.executor, monkeypatch, 2048)
     tpch.executor._fused_cache.clear()
     spy = _BuildSpy(tpch.executor, monkeypatch)
@@ -724,6 +724,21 @@ def _tail_cases() -> dict:
             "small-domain-tail"),
         "small-domain-65-buckets": (_tail_case(
             partial=over("b", ["k"], key_domains=(64,))), None),
+        # no NULL slot for a key that cannot be NULL: the product shrinks,
+        # and with it the route (`xla_exec.bucket_layout`)
+        "small-domain-49-buckets-non-null": (_tail_case(
+            partial=over("b", ["k", "j"], key_domains=(7, 7),
+                         key_nullable=(False, False))),
+            "small-domain-tail"),
+        "small-domain-72-buckets-nullable": (_tail_case(
+            partial=over("b", ["k", "j"], key_domains=(7, 8))), None),
+        "small-domain-64-buckets-one-non-null": (_tail_case(
+            partial=over("b", ["k", "j"], key_domains=(7, 8),
+                         key_nullable=(True, False))),
+            "small-domain-tail"),
+        "small-domain-non-null-from-medium": (_tail_case(
+            partial=over("b", ["k", "j"], key_domains=(16, 32),
+                         key_nullable=(False, False))), None),
         "groupby-in-a-step": (_tail_case(
             [over("b", ["k"])], keyless, at=0), None),
         "rows-out": (_tail_case(), None),
@@ -747,6 +762,25 @@ def test_tail_decision_table(case):
             or (route == "small-domain" and nb <= X._INPLACE_BUCKETS))
 
 
+@pytest.mark.parametrize("domains,nullable,non_null", [
+    ((7, 8), ("small-domain", 72), ("small-domain", 56)),
+    ((16, 32), ("medium-domain", 561), ("small-domain", 512)),
+    ((255, 256), ("sorted", 0), ("medium-domain", 65280)),
+], ids=["in-place", "medium-to-small", "sorted-to-medium"])
+def test_null_slots_move_the_route(domains, nullable, non_null):
+    """Keys that cannot be NULL take no NULL slot, and the smaller bucket
+    product may move the group-by to a cheaper lowering by the route's own
+    bounds (`_INPLACE_BUCKETS`, `_SMALL_DOMAIN_BUCKETS`,
+    `_SCATTER_MAX_BUCKETS`)."""
+    from ydb_tpu.ops import ir, xla_exec as X
+    aggs = (ir.Agg("s", "sum", "x"),)
+    for key_nullable, want in (((), nullable), ((False, False), non_null)):
+        cmd = ir.GroupBy(("k", "j"), aggs, key_domains=domains,
+                         key_nullable=key_nullable)
+        assert X.groupby_route(cmd) == want
+    assert (X.reads_in_place(cmd) is not None) == (domains == (7, 8))
+
+
 @pytest.fixture(scope="module")
 def tpch_fresh():
     """An engine no other test has forged, sized or warmed."""
@@ -762,15 +796,16 @@ _Q6_ROWS = QUERIES["q6"].replace(
 
 PLANNED = {
     # id: (statement, compact_skipped or None, digest of the statement's
-    # own `fused_cache_key` as the parent commit 7665ebc gives it)
+    # own `fused_cache_key` as the parent commit 7665ebc gives it, with
+    # `ir.GroupBy.key_nullable` in each program's fingerprint)
     "q6": (QUERIES["q6"], "keyless-tail", None),
     "q1-twelve-buckets": (QUERIES["q1"], "small-domain-tail", None),
     "q14-keyless-over-late-payload": (QUERIES["q14"], None, None),
     "sorted-groupby": (_Q6_BY_ORDER, None, None),
     "rows-out-order-by": (_Q6_ROWS, None, None),
-    "q3": (QUERIES["q3"], None, "0f3e96776936"),
-    "q9": (QUERIES["q9"], None, "c0dd02a128e9"),
-    "q18": (QUERIES["q18"], None, "8183ef615f10"),
+    "q3": (QUERIES["q3"], None, "cd24aefe4ef3"),
+    "q9": (QUERIES["q9"], None, "cd2e623d2b93"),
+    "q18": (QUERIES["q18"], None, "1b758b1b7498"),
 }
 
 
@@ -778,7 +813,7 @@ PLANNED = {
 def test_compact_is_planned_by_the_tail(tpch_fresh, monkeypatch, case):
     """Real plans through `_compact_sizing`: every statement's estimate
     qualifies (under half the scan capacity); only the keyless tail with
-    no deferred join payload, and Q1's 12-bucket one-hot, decline. Q3 /
+    no deferred join payload, and Q1's 6-bucket one-hot, decline. Q3 /
     Q9 / Q18 (the join cell) keep
     their cache keys to the byte: the rule changed no plan there."""
     import hashlib

@@ -124,6 +124,12 @@ class GroupBy:
     # A FALSE dependency silently merges groups, so only runtime-verified
     # sources may populate this. Part of the structural fingerprint.
     carry_keys: tuple = ()
+    # per-key: may the key be NULL in a live row? A bounded key that
+    # cannot gets no NULL slot in the mixed-radix bucket id
+    # (`xla_exec.bucket_layout`). Empty = every key nullable (the layout
+    # of a GroupBy built without it). Part of the structural fingerprint,
+    # so a program traced for a non-null key never serves a nullable one.
+    key_nullable: tuple = ()
 
 
 @dataclass(frozen=True)
@@ -168,10 +174,11 @@ class Program:
 
     def group_by(self, keys: list[str], aggs: list[Agg],
                  key_domains: tuple = (), out_bound: int = 0,
-                 carry_keys: tuple = ()) -> "Program":
+                 carry_keys: tuple = (),
+                 key_nullable: tuple = ()) -> "Program":
         self.commands.append(GroupBy(tuple(keys), tuple(aggs),
                                      tuple(key_domains), out_bound,
-                                     tuple(carry_keys)))
+                                     tuple(carry_keys), tuple(key_nullable)))
         return self
 
     def project(self, names: list[str]) -> "Program":

@@ -360,8 +360,15 @@ def groupby_trace_mark() -> dict:
     reset/snapshot: the thread-local is never cleared mid-statement, so
     a NESTED statement on the same thread (the DQ router's merge stage
     re-enters `engine.query`) cannot wipe the outer statement's window —
-    its traces simply fold into the outer delta."""
-    return dict(_TRACE.stats)
+    its traces simply fold into the outer delta. The `*_max` watermarks
+    restart with the window (the delta puts the earlier ones back), so
+    a window reports the widest shape IT traced, not only one wider
+    than every earlier statement's on this thread."""
+    st = _TRACE.stats
+    mark = dict(st)
+    for k in [k for k in st if k.endswith("_max")]:
+        del st[k]
+    return mark
 
 
 def groupby_trace_fold(delta: dict) -> None:
@@ -382,18 +389,22 @@ def groupby_trace_fold(delta: dict) -> None:
 
 
 def groupby_trace_delta(mark: dict) -> dict:
-    """Trace activity since `mark`: counters subtract; `*_max` high
-    watermarks report their current value only if raised inside the
-    window (a statement that traced nothing yields {})."""
+    """Trace activity since `mark`: counters subtract; `*_max`
+    watermarks report the highest value set inside the window (a
+    statement that traced nothing yields {}), then take back the
+    window's enclosing value where that is higher."""
+    st = _TRACE.stats
     out = {}
-    for k, v in _TRACE.stats.items():
+    for k, v in st.items():
         if k.endswith("_max"):
-            if v > mark.get(k, -1):
-                out[k] = v
+            out[k] = v
         else:
             d = v - mark.get(k, 0)
             if d:
                 out[k] = d
+    for k, v in mark.items():
+        if k.endswith("_max") and v > st.get(k, -1):
+            st[k] = v
     return out
 
 
@@ -489,22 +500,48 @@ def _groupby_global(cmd: ir.GroupBy, env, active, iota):
     return new_env, jnp.int32(1)
 
 
+def bucket_layout(cmd: ir.GroupBy) -> Optional[tuple]:
+    """(buckets, strides, offsets): the mixed-radix bucket id of a
+    bounded-domain group-by, or None where a key is unbounded. A key
+    that can be NULL (`ir.GroupBy.key_nullable`; all, where the field is
+    empty) takes `domain + 1` slots, NULL first (offset 1); one that
+    cannot takes `domain` (offset 0). The one place that says it: the
+    lowering (`_bucket_ids`, `_emit_bucket_groups`), the route
+    (`groupby_route`) and every bound sized from it call this."""
+    doms = cmd.key_domains
+    if not cmd.keys or not doms or not all(d > 0 for d in doms):
+        return None
+    nullable = cmd.key_nullable or (True,) * len(doms)
+    nb, strides, offsets = 1, [], []
+    for dom, null in zip(doms, nullable):
+        strides.append(nb)
+        offsets.append(int(bool(null)))
+        nb *= dom + offsets[-1]
+    return nb, tuple(strides), tuple(offsets)
+
+
 def _bucket_ids(cmd: ir.GroupBy, env, cap):
-    """Mixed-radix bucket id per row for bounded key domains (+1 slot per
-    key for NULL)."""
+    """(bucket id per row, buckets): the mixed-radix id `bucket_layout`
+    says; counts the NULL slots the layout left out."""
+    nbuckets, strides, offsets = bucket_layout(cmd)
     kid = jnp.zeros((cap,), jnp.int32)
-    stride = 1
-    strides = []
-    for kname, dom in zip(cmd.keys, cmd.key_domains):
+    for kname, dom, st, off in zip(cmd.keys, cmd.key_domains, strides,
+                                   offsets):
         d, v = env[kname]
-        code = d.astype(jnp.int32) + 1          # -1 (null string code) → 0
-        if v is not None:
-            code = jnp.where(v, code, 0)        # SQL: one NULL group
-        code = jnp.clip(code, 0, dom)
-        kid = kid + code * stride
-        strides.append(stride)
-        stride *= dom + 1
-    return kid, stride, strides
+        if off:
+            code = d.astype(jnp.int32) + 1      # -1 (null string code) → 0
+            if v is not None:
+                code = jnp.where(v, code, 0)    # SQL: one NULL group
+            code = jnp.clip(code, 0, dom)
+        else:
+            # NULL in no live row: a mask the key may still carry (the
+            # all-true one `Executor._finalize` gives every merged column,
+            # an inner join payload's matched flag) is true wherever a
+            # row is live, so the code alone places the row
+            code = jnp.clip(d.astype(jnp.int32), 0, dom - 1)
+            _t_inc("null_slots_elided")
+        kid = kid + code * st
+    return kid, nbuckets
 
 
 def _groupby_small_domain(cmd: ir.GroupBy, env, schema: Schema, sel,
@@ -515,7 +552,8 @@ def _groupby_small_domain(cmd: ir.GroupBy, env, schema: Schema, sel,
     into the reduction; nothing materializes, nothing scatters)."""
     iota = jnp.arange(cap, dtype=jnp.int32)
     active = (iota < length) if sel is None else ((iota < length) & sel)
-    kid, nbuckets, strides = _bucket_ids(cmd, env, cap)
+    kid, nbuckets = _bucket_ids(cmd, env, cap)
+    _t_max("onehot_buckets_max", nbuckets)
 
     chunks: dict[str, list] = {a.out: [] for a in cmd.aggs}
     valid_chunks: dict[str, list] = {}
@@ -562,26 +600,28 @@ def _groupby_small_domain(cmd: ir.GroupBy, env, schema: Schema, sel,
         new_env[a.out] = (data, jnp.concatenate(v) if v is not None else None)
     present = jnp.concatenate(present_chunks)
     firstpos = jnp.concatenate(first_chunks) if first_chunks else None
-    return _emit_bucket_groups(cmd, env, schema, new_env, present, nbuckets,
-                               strides, cap, firstpos)
+    return _emit_bucket_groups(cmd, env, schema, new_env, present, cap,
+                               firstpos)
 
 
 def _emit_bucket_groups(cmd: ir.GroupBy, env, schema: Schema, new_env,
-                        present, nbuckets, strides, cap, firstpos=None):
+                        present, cap, firstpos=None):
     """Shared bounded-domain epilogue: rebuild key columns from bucket ids,
     then compact non-empty buckets to the front of a SMALL capacity bucket
     (compress sorts; doing it over the scan capacity would cost a full
     cap-sized argsort for a handful of groups). `firstpos`: leader row id
     per bucket, required when the command carries functionally-determined
     keys (their per-group value gathers from the leader row)."""
+    nbuckets, strides, offsets = bucket_layout(cmd)
     _b_inc("proven_rows", bucket_capacity(nbuckets, minimum=128))
     _b_inc("capacity_rows", cap)
     _b_inc("bounded_groupbys")
     if cmd.carry_keys:
         _b_inc("carried_keys", len(cmd.carry_keys))
     bucket_ids = jnp.arange(nbuckets, dtype=jnp.int32)
-    for kname, dom, st in zip(cmd.keys, cmd.key_domains, strides):
-        code = (bucket_ids // st) % (dom + 1) - 1
+    for kname, dom, st, off in zip(cmd.keys, cmd.key_domains, strides,
+                                   offsets):
+        code = (bucket_ids // st) % (dom + off) - off
         d, _v = env[kname]
         kd = code.astype(jnp.int32).astype(d.dtype)
         kv = code >= 0
@@ -619,7 +659,7 @@ def _groupby_medium_domain(cmd: ir.GroupBy, env, schema: Schema, sel,
     post-readout scatter tax exactly once per aggregate)."""
     iota = jnp.arange(cap, dtype=jnp.int32)
     active = (iota < length) if sel is None else ((iota < length) & sel)
-    kid, nbuckets, strides = _bucket_ids(cmd, env, cap)
+    kid, nbuckets = _bucket_ids(cmd, env, cap)
     seg_safe = jnp.where(active, kid, nbuckets)
     nseg = nbuckets + 1                         # +1 garbage bucket
 
@@ -663,8 +703,8 @@ def _groupby_medium_domain(cmd: ir.GroupBy, env, schema: Schema, sel,
     if cmd.carry_keys:
         pos = jnp.where(active, iota, cap)
         firstpos = jax.ops.segment_min(pos, seg_safe, nseg)[:nbuckets]
-    return _emit_bucket_groups(cmd, env, schema, new_env, present, nbuckets,
-                               strides, cap, firstpos)
+    return _emit_bucket_groups(cmd, env, schema, new_env, present, cap,
+                               firstpos)
 
 
 def _gather_sorted(cols: dict, perm, cap: int, tiles: int,
@@ -970,10 +1010,9 @@ def groupby_route(cmd: ir.GroupBy) -> tuple:
     pipeline's tail by it (`reads_in_place`)."""
     if not cmd.keys:
         return "keyless", 1
-    if cmd.key_domains and all(d > 0 for d in cmd.key_domains):
-        nb = 1
-        for d in cmd.key_domains:
-            nb *= d + 1
+    layout = bucket_layout(cmd)
+    if layout is not None:
+        nb = layout[0]
         if nb <= _SMALL_DOMAIN_BUCKETS:
             return "small-domain", nb
         if nb + 1 <= _SCATTER_MAX_BUCKETS:

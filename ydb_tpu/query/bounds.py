@@ -25,26 +25,24 @@ Two trust tiers, deliberately distinct:
 from __future__ import annotations
 
 from ydb_tpu.ops import ir
+from ydb_tpu.ops.xla_exec import bucket_layout
 
 _BIG = 1 << 62
 
 
 def groupby_bound(gb: ir.GroupBy) -> int:
     """Static group-count bound of one GroupBy: a stamped proven
-    out_bound, else the mixed-radix key-domain product (0 = unbounded)."""
+    out_bound, else its bucket count (`xla_exec.bucket_layout`; 0 =
+    unbounded)."""
     if not gb.keys:
         return 1
     if gb.out_bound:
         return int(gb.out_bound)
-    if gb.key_domains and all(d > 0 for d in gb.key_domains) \
-            and len(gb.key_domains) == len(gb.keys):
-        nb = 1
-        for d in gb.key_domains:
-            nb *= d + 1
-            if nb > (1 << 40):
-                return 0
-        return nb
-    return 0
+    layout = bucket_layout(gb)
+    if layout is None or len(gb.key_domains) != len(gb.keys) \
+            or layout[0] > (1 << 40):
+        return 0
+    return layout[0]
 
 
 def program_bound(prog, rows: int) -> int:

@@ -1810,15 +1810,19 @@ class Executor:
             return plan, pipe
 
         kept = tuple(k for k in gb.keys if k not in carry)
-        domains = gb.key_domains
+        domains, nullable = gb.key_domains, gb.key_nullable
         if carry and domains and len(domains) == len(gb.keys):
             domains = tuple(d for k, d in zip(gb.keys, domains)
                             if k not in carry)
         elif carry:
             domains = ()
+        if carry and nullable:
+            nullable = tuple(n for k, n in zip(gb.keys, nullable)
+                             if k not in carry)
         new_carry = tuple(gb.carry_keys) + tuple(carry)
         gb2 = _dc.replace(gb, keys=kept, key_domains=domains,
-                          out_bound=bound, carry_keys=new_carry)
+                          key_nullable=nullable, out_bound=bound,
+                          carry_keys=new_carry)
         pipe = _dc.replace(pipe, partial=ir.Program(
             list(pipe.partial.commands[:-1]) + [gb2]))
         fp = plan.final_program
@@ -1829,7 +1833,7 @@ class Executor:
             # keys — the bound and the carry set transfer verbatim
             fgb0 = fp.commands[0]
             fgb = _dc.replace(
-                fgb0, keys=kept, key_domains=domains,
+                fgb0, keys=kept, key_domains=domains, key_nullable=nullable,
                 carry_keys=tuple(fgb0.carry_keys) + tuple(carry),
                 out_bound=bound if (not fgb0.out_bound
                                     or (bound and int(fgb0.out_bound)
@@ -1912,7 +1916,7 @@ class Executor:
 
         from ydb_tpu.ops import fused as F
         from ydb_tpu.ops import spill as SP
-        from ydb_tpu.ops.xla_exec import _SCATTER_MAX_BUCKETS
+        from ydb_tpu.ops.xla_exec import groupby_route
         from ydb_tpu.utils.metrics import GLOBAL
 
         CAP = max(bucket_capacity(max(b.length, 1)) for b in sources)
@@ -1939,14 +1943,11 @@ class Executor:
         last = pipe.partial.commands[-1] \
             if pipe.partial is not None and pipe.partial.commands else None
         if isinstance(last, ir.GroupBy):
-            if not last.keys:
+            route, nb = groupby_route(last)
+            if route == "keyless":
                 tile_out_cap = 1
-            elif last.key_domains and all(d > 0 for d in last.key_domains):
-                nb = 1
-                for d in last.key_domains:
-                    nb *= d + 1
-                if nb + 1 <= _SCATTER_MAX_BUCKETS:
-                    tile_out_cap = bucket_capacity(nb, minimum=128)
+            elif route != "sorted":
+                tile_out_cap = bucket_capacity(nb, minimum=128)
             if last.out_bound:
                 # proven ngroups bound (join-derived or an out-of-scatter-
                 # range domain product): the sorted lowering emits its

@@ -1659,11 +1659,14 @@ class Planner:
             # dedup per block, then globally; sort expressions are computed
             # after the final dedup (they would be dropped by the GroupBy)
             domains = self._key_domains(uniq_outs)
-            dbound = self._groups_bound(domains)
-            prog.group_by(uniq_outs, [], domains, out_bound=dbound)
+            nullable = self._key_nullable(uniq_outs)
+            dbound = self._groups_bound(domains, nullable)
+            prog.group_by(uniq_outs, [], domains, out_bound=dbound,
+                          key_nullable=nullable)
             plan.pipeline.partial = prog
             final = ir.Program().group_by(uniq_outs, [], domains,
-                                          out_bound=dbound)
+                                          out_bound=dbound,
+                                          key_nullable=nullable)
             sort_keys, _extra = self._bind_sort(sel, binder.bind, out_names,
                                                 final, alias_deref=deref)
             plan.final_program = final
@@ -1848,33 +1851,39 @@ class Planner:
             register(call)
 
         domains = self._key_domains(key_names)
-        gbound = self._groups_bound(domains)
+        nullable = self._key_nullable(key_names)
+        gbound = self._groups_bound(domains, nullable)
         sealed[0] = True
         if dcol is None:
             partial.group_by(key_names, partial_aggs, domains,
-                             out_bound=gbound)
+                             out_bound=gbound, key_nullable=nullable)
             plan.pipeline.partial = partial
             # -- final stage: merge aggs, having, outputs, sort -----------
             final = ir.Program().group_by(key_names, final_aggs, domains,
-                                          out_bound=gbound)
+                                          out_bound=gbound,
+                                          key_nullable=nullable)
             for (dec, expr) in string_agg_decodes:
                 final.assign(dec, expr)
         else:
             ddom = self._key_domains([dcol])
-            dbound = self._groups_bound(domains + ddom)
+            dnull = nullable + self._key_nullable([dcol])
+            dbound = self._groups_bound(domains + ddom, dnull)
             partial.group_by(key_names + [dcol], partial_aggs,
-                             domains + ddom, out_bound=dbound)
+                             domains + ddom, out_bound=dbound,
+                             key_nullable=dnull)
             plan.pipeline.partial = partial
             # first final GroupBy completes the global dedup by
             # (keys + arg); the second collapses to the group keys, counting
             # the deduplicated arg and re-merging the regular aggregates
             # (associative, so the double merge is exact)
             final = ir.Program().group_by(key_names + [dcol], final_aggs,
-                                          domains + ddom, out_bound=dbound)
+                                          domains + ddom, out_bound=dbound,
+                                          key_nullable=dnull)
             final.group_by(
                 key_names,
                 [ir.Agg(a.out, a.func, a.out) for a in final_aggs]
-                + final2_aggs, domains, out_bound=gbound)
+                + final2_aggs, domains, out_bound=gbound,
+                key_nullable=nullable)
             for (dec, expr) in string_agg_decodes:
                 final.assign(dec, expr)
 
@@ -1964,22 +1973,26 @@ class Planner:
         return None
 
     @staticmethod
-    def _groups_bound(domains: tuple) -> int:
+    def _groups_bound(domains: tuple, nullable: tuple) -> int:
         """Guaranteed ngroups upper bound from bounded key domains: the
-        mixed-radix bucket count prod(domain+1) (each key contributes its
-        domain plus the NULL slot). Feeds `ir.GroupBy.out_bound` so the
-        sorted lowering late-materializes per-group gathers at output
-        cardinality when the bounded product overflows the scatter paths
-        (multi-string-key group-bys, q16-class). 0 = no guarantee (any
-        unbounded key, or a product too large to ever matter)."""
-        bound = 1
-        for d in domains:
-            if d <= 0:
-                return 0
-            bound *= d + 1
-            if bound > (1 << 40):
-                return 0
-        return bound
+        mixed-radix bucket count (`xla_exec.bucket_layout`: a key's
+        domain, plus a NULL slot where it can be NULL). Feeds
+        `ir.GroupBy.out_bound` so the sorted lowering late-materializes
+        per-group gathers at output cardinality when the bounded product
+        overflows the scatter paths (multi-string-key group-bys,
+        q16-class). 0 = no guarantee (any unbounded key, or a product too
+        large to ever matter)."""
+        from ydb_tpu.query.bounds import groupby_bound
+        return groupby_bound(ir.GroupBy(
+            ("",) * len(domains), (), domains, key_nullable=nullable))
+
+    def _key_nullable(self, key_names: list) -> tuple:
+        """Per key: can it be NULL in a live row? The bound column's
+        declared nullability: a left-joined relation's columns are
+        nullable in scope, an inner join's payload is NULL only on the
+        rows the join drops. A computed key is taken as nullable."""
+        return tuple(b is None or b.dtype.nullable
+                     for b in map(self.scope.by_internal, key_names))
 
     def _key_domains(self, key_names: list) -> tuple:
         """Static key-domain sizes for the scatter aggregation path:

@@ -383,6 +383,10 @@ COUNTER_REGISTRY = {
     "groupby/sort_rows_max": "[viz] (dynamic) group-by sort row watermark",
     "groupby/value_gather_rows_max":
         "[viz] (dynamic) value-column gather row watermark",
+    "groupby/null_slots_elided":
+        "[viz] (dynamic) bounded keys traced without a NULL bucket slot",
+    "groupby/onehot_buckets_max":
+        "[viz] (dynamic) small-domain one-hot bucket watermark",
     # -- bounds lattice (query/bounds.py) ----------------------------------
     "bounds/plans": "[viz] plans annotated by the bounds lattice",
     "bounds/finite_plans": "[viz] plans whose result bound is finite",
@@ -663,6 +667,10 @@ class QueryStats:
                     f"sort rows max {g.get('sort_rows_max', 0)} | "
                     f"value gather rows max "
                     f"{g.get('value_gather_rows_max', 0)}")
+            if "onehot_buckets_max" in g or "null_slots_elided" in g:
+                out += (f" | one-hot buckets max "
+                        f"{g.get('onehot_buckets_max', 0)} | null slots "
+                        f"elided {g.get('null_slots_elided', 0)}")
         if self.bounds:
             bd = self.bounds
             proven = bd.get("proven_rows", 0)
